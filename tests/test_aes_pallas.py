@@ -218,26 +218,29 @@ class TestForcedPathCrosscheck:
         np.testing.assert_array_equal(got, expected)
 
 
-def test_preflight_failure_degrades_to_xla_circuit(monkeypatch):
-    """A Mosaic lowering/runtime failure must disable the kernel, not raise:
-    the unattended round-end bench warms this path and an exception there
-    costs the whole artifact."""
+def test_preflight_failure_raises_instead_of_degrading(monkeypatch):
+    """A Mosaic lowering/runtime failure must RAISE: the XLA circuit never
+    takes the kernel's place in silence, and the failure is not memoized as
+    a quiet "unavailable" (ops/_preflight.py)."""
     from tieredstorage_tpu.ops import aes_bitsliced, aes_pallas
+    from tieredstorage_tpu.ops._preflight import KernelPreflightError
 
     def boom(*a, **k):
         raise RuntimeError("mosaic lowering failed")
 
     monkeypatch.setattr(aes_pallas, "aes_encrypt_planes_pallas", boom)
     monkeypatch.setattr(aes_bitsliced, "_PALLAS_PREFLIGHT", [])
-    assert aes_bitsliced._pallas_preflight_ok() is False
-    # Memoized: the second call must not retry (and not raise either).
-    assert aes_bitsliced._pallas_preflight_ok() is False
+    with pytest.raises(KernelPreflightError, match="mosaic lowering failed"):
+        aes_bitsliced._pallas_preflight_ok()
+    assert aes_bitsliced._PALLAS_PREFLIGHT == []
+    with pytest.raises(KernelPreflightError):
+        aes_bitsliced._pallas_preflight_ok()
 
 
 def test_preflight_works_under_a_jit_trace(monkeypatch):
     """The gate is consulted while the caller's jit is TRACING; omnistaging
-    must not turn the verdict into a TracerBoolConversionError that the
-    except-clause memoizes as a permanent False on healthy TPUs."""
+    must not turn the verdict into a TracerBoolConversionError that fails
+    the gate on healthy TPUs."""
     from tieredstorage_tpu.ops import aes_bitsliced, aes_pallas
 
     # Stand-in "kernel" that is definitionally correct (the XLA circuit),

@@ -104,22 +104,27 @@ def test_gate_requires_tiled_shapes(monkeypatch):
     assert not use_pallas_ghash(ROWS_PER_STEP - 1, 2048)
 
 
-def test_preflight_failure_degrades_gracefully(monkeypatch):
+def test_preflight_failure_raises_instead_of_degrading(monkeypatch):
+    from tieredstorage_tpu.ops._preflight import KernelPreflightError
+
     monkeypatch.setattr(ghash_pallas, "_PREFLIGHT", [])
     monkeypatch.setattr(
         ghash_pallas,
         "ghash_level1_pallas",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("mosaic failed")),
     )
-    assert ghash_pallas._preflight_ok() is False
-    assert ghash_pallas._preflight_ok() is False  # memoized, no retry
+    with pytest.raises(KernelPreflightError, match="mosaic failed"):
+        ghash_pallas._preflight_ok()
+    assert ghash_pallas._PREFLIGHT == []  # never memoized as "unavailable"
+    with pytest.raises(KernelPreflightError):
+        ghash_pallas._preflight_ok()
 
 
 def test_level1_preflight_attempt_crosschecks_on_cpu(monkeypatch):
     """The preflight's own numpy reference is the on-chip correctness
     oracle, so the CPU suite must execute it for real: stand the kernel in
-    with `_numpy_level1` (itself kernel-validated above — interpret-mode
-    Pallas cannot run under the attempt's ensure_compile_time_eval) and
+    with `_numpy_level1` (itself kernel-validated above; the real kernel
+    runs through the attempt in tests/test_preflight.py) and
     the attempt must agree. Any operator flip in the reference fails the
     cross-check loudly instead of silently blinding the TPU gate."""
     monkeypatch.setattr(
@@ -275,15 +280,20 @@ class TestTreeKernel:
         monkeypatch.setenv("TIEREDSTORAGE_TPU_PALLAS_GHASH_TREE", "1")
         assert pallas_ghash_tree_available()
 
-    def test_tree_preflight_failure_degrades_gracefully(self, monkeypatch):
+    def test_tree_preflight_failure_raises_instead_of_degrading(self, monkeypatch):
+        from tieredstorage_tpu.ops._preflight import KernelPreflightError
+
         monkeypatch.setattr(ghash_pallas, "_TREE_PREFLIGHT", [])
         monkeypatch.setattr(
             ghash_pallas,
             "ghash_tree_pallas",
             lambda *a, **k: (_ for _ in ()).throw(RuntimeError("mosaic failed")),
         )
-        assert ghash_pallas._tree_preflight_ok() is False
-        assert ghash_pallas._tree_preflight_ok() is False  # memoized
+        with pytest.raises(KernelPreflightError, match="mosaic failed"):
+            ghash_pallas._tree_preflight_ok()
+        assert ghash_pallas._TREE_PREFLIGHT == []  # the ladder never steps in
+        with pytest.raises(KernelPreflightError):
+            ghash_pallas._tree_preflight_ok()
 
 
 class TestTreeComposite:

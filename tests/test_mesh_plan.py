@@ -1,6 +1,5 @@
 """parallel/mesh.py edge cases: padding math, degenerate meshes, MeshPlan
-spec parsing, and the `shard_map_compat` version shim (both jax spellings —
-the `check_rep`/`check_vma` mapping had no direct tests before)."""
+spec parsing, and `jax.shard_map` as the window programs spell it."""
 
 from __future__ import annotations
 
@@ -14,7 +13,6 @@ from tieredstorage_tpu.parallel.mesh import (  # noqa: E402
     MeshPlan,
     data_mesh,
     pad_batch,
-    shard_map_compat,
     shard_rows,
 )
 
@@ -99,69 +97,16 @@ class TestMeshPlanSpec:
         np.testing.assert_array_equal(np.asarray(placed), arr)
 
 
-class TestShardMapCompatShim:
-    """Both spellings: modern `jax.shard_map(..., check_vma=)` and the
-    experimental `jax.experimental.shard_map.shard_map(..., check_rep=)`."""
-
-    def _fake(self, calls):
-        def fake_shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-            calls.append(kwargs)
-            return f
-
-        return fake_shard_map
-
-    def test_modern_spelling_uses_check_vma(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(
-            jax, "shard_map", self._fake(calls), raising=False
-        )
-        mesh = data_mesh(2)
-        fn = shard_map_compat(
-            lambda x: x, mesh=mesh, in_specs=(None,), out_specs=None,
-            check_vma=False,
-        )
-        assert fn(1) == 1
-        assert calls == [{"check_vma": False}]
-
-    def test_old_spelling_maps_check_vma_to_check_rep(self, monkeypatch):
-        import jax.experimental.shard_map as esm
-
-        calls = []
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-        monkeypatch.setattr(esm, "shard_map", self._fake(calls))
-        mesh = data_mesh(2)
-        fn = shard_map_compat(
-            lambda x: x, mesh=mesh, in_specs=(None,), out_specs=None,
-            check_vma=False,
-        )
-        assert fn(2) == 2
-        assert calls == [{"check_rep": False}]
-
-    @pytest.mark.parametrize("modern", [True, False])
-    def test_default_omits_the_check_kwarg(self, monkeypatch, modern):
-        calls = []
-        if modern:
-            monkeypatch.setattr(
-                jax, "shard_map", self._fake(calls), raising=False
-            )
-        else:
-            import jax.experimental.shard_map as esm
-
-            monkeypatch.delattr(jax, "shard_map", raising=False)
-            monkeypatch.setattr(esm, "shard_map", self._fake(calls))
-        shard_map_compat(
-            lambda x: x, mesh=data_mesh(1), in_specs=(None,), out_specs=None
-        )
-        assert calls == [{}]
-
+class TestShardMapOnTheMesh:
     def test_real_shard_map_runs_on_the_mesh(self):
-        """End-to-end through whichever spelling this jax provides."""
+        """`jax.shard_map(..., check_vma=False)`, as the window programs
+        spell it, end to end on the virtual mesh."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = data_mesh(8)
         data = np.arange(16, dtype=np.int32).reshape(8, 2)
         fn = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 lambda x: x * 2, mesh=mesh,
                 in_specs=(P(DATA_AXIS, None),), out_specs=P(DATA_AXIS, None),
                 check_vma=False,

@@ -16,7 +16,6 @@ from tieredstorage_tpu.ops.huffman import encode_batch  # noqa: E402
 from tieredstorage_tpu.parallel.mesh import (  # noqa: E402
     DATA_AXIS,
     data_mesh,
-    shard_map_compat,
 )
 from tieredstorage_tpu.transform.thuff import (  # noqa: E402
     assemble_frame,
@@ -59,7 +58,7 @@ def _mesh_encode(mesh, data, n_sym, codes_rev, lengths, *, n_max, gather_sizes):
     row, row2 = P(DATA_AXIS), P(DATA_AXIS, None)
     out_specs = (row2, row, row2) + ((P(None),) if gather_sizes else ())
     step = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             shard_step,
             mesh=mesh,
             in_specs=(row2, row, row2, row2),

@@ -7,8 +7,8 @@ complexity — ONE device dispatch per window, nothing materializing device
 values mid-pipeline — is enforced today only by the runtime counters that
 ``make transform-demo``/``multichip-demo`` assert. A hidden ``np.asarray``
 or ``block_until_ready`` added anywhere on the hot path serializes the
-double-buffered pipeline and reintroduces the ~62 ms per-launch floor
-(PROFILE.md) *silently* until the next bench round. This checker closes
+double-buffered pipeline and pays the per-launch floor again
+*silently* until the next bench round. This checker closes
 that gap at the AST level:
 
 1. **Closure.** The static call closure of the hot window path — from
@@ -550,9 +550,9 @@ def _scan_interstage(fn: _Fn, findings: list[Finding]) -> None:
     """Host materializers/syncs inside the TRACED fused closure. Every
     value here is a tracer, so a materialization cannot be a cheap host
     peek: it cuts the one-program window into multiple programs with an
-    HBM round trip (and a relay sync) at the cut. The sanctioned set is
-    the trace-time host gates (memoized preflight cross-checks under
-    ensure_compile_time_eval)."""
+    HBM round trip (and a host sync) at the cut. The sanctioned set is
+    the trace-time host gates (memoized preflight cross-checks, run
+    outside the trace)."""
     if fn.key in SANCTIONED_MATERIALIZERS:
         return
     tainted = _trace_tainted_names(fn)

@@ -109,6 +109,11 @@ def load() -> Optional[ctypes.CDLL]:
             return None
 
 
+def load_error() -> Optional[str]:
+    """Why the last `load()` returned None (None while it has not failed)."""
+    return _load_error
+
+
 def available() -> bool:
     lib = load()
     return lib is not None and lib.ts_crypto_available() == 1

@@ -12,8 +12,7 @@ from tieredstorage_tpu.parallel.mesh import (
     MeshPlan,
     data_mesh,
     pad_batch,
-    shard_map_compat,
     shard_rows,
 )
 
-__all__ = ["MeshPlan", "data_mesh", "pad_batch", "shard_map_compat", "shard_rows"]
+__all__ = ["MeshPlan", "data_mesh", "pad_batch", "shard_rows"]

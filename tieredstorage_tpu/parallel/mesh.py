@@ -21,21 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """`jax.shard_map` across jax versions: older releases keep it under
-    `jax.experimental.shard_map` and spell `check_vma` as `check_rep`."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as experimental_shard_map
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return experimental_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
 def data_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over the first `n_devices` devices (default: all)."""
     devices = jax.devices()

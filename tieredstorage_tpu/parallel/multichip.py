@@ -38,7 +38,7 @@ from typing import Optional
 
 import numpy as np
 
-from tieredstorage_tpu.parallel.mesh import DATA_AXIS, MeshPlan, shard_map_compat
+from tieredstorage_tpu.parallel.mesh import DATA_AXIS, MeshPlan
 
 
 def _det_ivs(n: int) -> list:
@@ -74,7 +74,7 @@ def _index_collective(plan: MeshPlan, wire_sizes: list) -> dict:
         return all_sizes, total
 
     gathered, total = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(P(DATA_AXIS),),
             out_specs=(P(None), P()), check_vma=False,
         )

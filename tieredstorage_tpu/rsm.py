@@ -391,6 +391,13 @@ class RemoteStorageManager:
         return self._peer_cache
 
     @property
+    def transform_backend(self):
+        """The configured transform backend (`transform.backend.class`), or
+        None before `configure` — its `dispatch_stats` are what smoke runs
+        and benchmarks report per phase."""
+        return self._transform_backend
+
+    @property
     def device_hot_cache(self):
         """The device hot-window tier, or None when `cache.device.bytes`
         is 0 (fetch/cache/device_hot.py)."""

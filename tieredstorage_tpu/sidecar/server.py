@@ -283,10 +283,16 @@ def main(argv: Optional[list[str]] = None) -> None:
     )
     args = parser.parse_args(argv)
 
-    if args.virtual_cpu_devices is not None:
-        from tieredstorage_tpu.utils.platforms import pin_virtual_cpu
+    from tieredstorage_tpu.utils.platforms import (
+        enable_compile_cache,
+        pin_virtual_cpu,
+    )
 
+    if args.virtual_cpu_devices is not None:
         pin_virtual_cpu(args.virtual_cpu_devices)
+    # Every new window shape is a compile of tens of seconds on a TPU; a
+    # restarted sidecar should find them again.
+    enable_compile_cache()
 
     from tieredstorage_tpu.rsm import RemoteStorageManager
 

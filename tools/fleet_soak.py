@@ -354,6 +354,10 @@ def run(out_path: pathlib.Path) -> int:
 
     report: dict = {
         "instances": list(INSTANCES),
+        # A chip belongs to one process: three sidecars cannot share one, so
+        # each child is held to the CPU (Sidecar.launch). This parent never
+        # imports JAX.
+        "sidecar_platform": "cpu (JAX_PLATFORMS=cpu in every child)",
         "replication_factor": REPLICATION,
         "gossip": {
             "interval_ms": GOSSIP_INTERVAL_MS,
