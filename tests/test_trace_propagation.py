@@ -71,19 +71,24 @@ class TestHttpGatewayPropagation:
         manifest_span = _span_by_name(spans, "rsm.fetch_manifest")
         storage_span = _span_by_name(spans, "storage.fetch_chunks")
         detransform_span = _span_by_name(spans, "chunk.detransform")
+        stream_span = _span_by_name(spans, "gateway.reply_stream")
 
         # One shared trace across the process boundary...
         for s in (gateway_span, rsm_span, manifest_span, storage_span,
-                  detransform_span):
+                  detransform_span, stream_span):
             assert s.trace_id == client_span.trace_id, s.name
         # ...with correct parenting: client → gateway → rsm → storage; the
         # lazy chunk transfer happens while the gateway streams the response,
-        # so chunk-level spans parent under the gateway span.
+        # so chunk-level spans parent under the gateway's stream span.
         assert gateway_span.parent_id == client_span.span_id
         assert rsm_span.parent_id == gateway_span.span_id
         assert manifest_span.parent_id == rsm_span.span_id
-        assert storage_span.parent_id == gateway_span.span_id
-        assert detransform_span.parent_id == gateway_span.span_id
+        assert stream_span.parent_id == gateway_span.span_id
+        assert storage_span.parent_id == stream_span.span_id
+        assert detransform_span.parent_id == stream_span.span_id
+        assert stream_span.attributes == {
+            "bytes": md.segment_size_in_bytes, "aborted": False,
+        }
         assert detransform_span.attributes["bytes_out"] > 0
 
     def test_fetch_without_traceparent_starts_fresh_trace(self, tmp_path, traced_rsm):

@@ -62,24 +62,154 @@ def test_tracing_disabled_records_nothing(tmp_path):
     assert rsm.tracer.enabled is False
 
 
-def test_jax_profiler_forwarding_smoke(tmp_path):
-    """use_jax_profiler must not break span recording (TraceAnnotations are
-    no-ops outside an active profiler trace but must still enter/exit)."""
-    tracer = Tracer(enabled=True, use_jax_profiler=True)
+class _Annotations:
+    """Stands in for `jax.profiler`: every TraceAnnotation opened and closed."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+        outer = self
+
+        class TraceAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.opened.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                outer.closed.append(self.name)
+
+        self.TraceAnnotation = TraceAnnotation
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    seen = _Annotations()
+    monkeypatch.setattr(jax, "profiler", seen)
+    return seen
+
+
+def test_jax_profiler_forwarding_smoke(annotations):
+    """An enabled tracer opens a TraceAnnotation around every span, with no
+    switch: outside a profiler session they are no-ops, inside one they put
+    the spans into the profiler's own trace."""
+    tracer = Tracer(enabled=True)
     with tracer.span("outer"):
         with tracer.span("inner"):
             pass
     assert [s.name for s in tracer.spans()] == ["inner", "outer"]
     assert tracer.spans("inner")[0].depth == 1
+    assert annotations.opened == ["outer", "inner"]
+    assert annotations.closed == ["inner", "outer"]
 
 
-def test_event_forwards_to_jax_profiler():
-    """tracer.event() must honor use_jax_profiler like span() does —
-    zero-duration annotations keep timeline parity with spans."""
-    tracer = Tracer(enabled=True, use_jax_profiler=True)
+def test_event_forwards_to_jax_profiler(annotations):
+    """tracer.event() is annotated like span() is: zero-duration
+    annotations keep timeline parity with spans."""
+    tracer = Tracer(enabled=True)
     s = tracer.event("breaker.trip", reason="threshold")
     assert s is not None and s.duration_s == 0.0
     assert tracer.spans("breaker.trip")[0].attributes["reason"] == "threshold"
+    assert annotations.opened == annotations.closed == ["breaker.trip"]
+
+
+def test_disabled_tracer_records_nothing_and_opens_no_annotation(annotations, monkeypatch):
+    import types
+
+    from tieredstorage_tpu.utils import tracing
+
+    def no_clock():
+        raise AssertionError("a disabled tracer read the clock")
+
+    tracer = Tracer(enabled=False)
+    # the module's own reference to `time`, not the interpreter's clock
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(perf_counter=no_clock))
+    with tracer.span("outer") as span:
+        assert span is None
+        assert tracer.event("leaf") is None
+    assert tracer.spans() == [] and tracer.summary() == {}
+    assert annotations.opened == []
+
+
+def test_a_process_without_jax_is_never_made_to_import_it():
+    """A client-side tracer (sidecar/client.py) records spans and leaves
+    `jax` alone."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from tieredstorage_tpu.utils.tracing import Tracer\n"
+        "t = Tracer(enabled=True)\n"
+        "with t.span('client.fetch_log_segment'):\n"
+        "    t.event('leaf')\n"
+        "assert [s.name for s in t.spans()] == ['leaf', 'client.fetch_log_segment']\n"
+        "assert 'jax' not in sys.modules, 'tracing imported jax'\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr
+
+
+def _span(tracer, name, start, end, parent=None):
+    from tieredstorage_tpu.utils.tracing import Span
+
+    s = Span(name=name, start_s=start, end_s=end, span_id=f"{name}@{start}",
+             parent_id=parent.span_id if parent else None, trace_id="t")
+    tracer._record(s)
+    return s
+
+
+class TestSelfTime:
+    def test_nested_tree(self):
+        tracer = Tracer(enabled=True)
+        root = _span(tracer, "root", 0.0, 10.0)
+        mid = _span(tracer, "mid", 1.0, 7.0, root)
+        _span(tracer, "leaf", 2.0, 4.0, mid)
+        _span(tracer, "leaf", 5.0, 6.0, mid)
+        _span(tracer, "other", 8.0, 9.5, root)
+        summary = tracer.summary()
+        assert summary["root"]["self_s"] == pytest.approx(10.0 - 6.0 - 1.5)
+        assert summary["mid"]["self_s"] == pytest.approx(6.0 - 3.0)
+        assert summary["leaf"]["self_s"] == pytest.approx(3.0)  # no children: all its own
+        assert summary["leaf"]["total_s"] == pytest.approx(3.0)
+        assert summary["other"]["self_s"] == pytest.approx(1.5)
+
+    def test_overlapping_children_are_merged_and_clipped(self):
+        """Pipelined windows: a parent's children overlap each other, and
+        one adopted across threads outlives it; self time never goes
+        negative and the overlap counts once."""
+        tracer = Tracer(enabled=True)
+        upload = _span(tracer, "storage.upload", 0.0, 10.0)
+        _span(tracer, "window", 1.0, 6.0, upload)
+        _span(tracer, "window", 4.0, 9.0, upload)   # overlaps the first
+        _span(tracer, "window", 5.0, 5.5, upload)   # inside both
+        _span(tracer, "late", 9.5, 14.0, upload)    # outlives the parent
+        _span(tracer, "early", -3.0, 0.5, upload)   # began before it
+        summary = tracer.summary()
+        # covered: [0, 0.5] + [1, 9] + [9.5, 10] = 9.0
+        assert summary["storage.upload"]["self_s"] == pytest.approx(1.0)
+        assert summary["window"]["total_s"] == pytest.approx(10.5)
+        assert all(row["self_s"] >= 0.0 for row in summary.values())
+
+    def test_children_that_cover_everything_leave_zero(self):
+        tracer = Tracer(enabled=True)
+        root = _span(tracer, "root", 0.0, 2.0)
+        _span(tracer, "child", 0.0, 1.5, root)
+        _span(tracer, "child", 1.0, 2.0, root)
+        assert tracer.summary()["root"]["self_s"] == 0.0
+
+    def test_live_spans_report_self_time(self):
+        tracer = Tracer(enabled=True)
+        with tracer.span("outer"):
+            with tracer.span("inner"):
+                pass
+        summary = tracer.summary()
+        outer, inner = summary["outer"], summary["inner"]
+        assert outer["self_s"] == pytest.approx(outer["total_s"] - inner["total_s"])
+        assert inner["self_s"] == inner["total_s"]
 
 
 class TestTraceIdentity:

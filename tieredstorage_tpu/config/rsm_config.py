@@ -157,13 +157,9 @@ def _base_def() -> ConfigDef:
         doc="Record spans around RSM operations and, on the TPU transform "
             "backend, compress/dispatch/finish/decrypt stages "
             "(utils/tracing.py); summaries are exposed via "
-            "RemoteStorageManager.tracer.",
-    ))
-    d.define(ConfigKey(
-        "tracing.jax.profiler.enabled", "bool", default=False, importance="low",
-        doc="Forward tracing spans into jax.profiler TraceAnnotations so "
-            "they appear next to device kernels in XProf timelines "
-            "(requires tracing.enabled).",
+            "RemoteStorageManager.tracer. While a jax.profiler session is "
+            "open the spans also appear in its trace, next to the device "
+            "kernels.",
     ))
     d.define(ConfigKey(
         "tracing.max.spans", "int", default=10_000,
@@ -845,10 +841,6 @@ class RemoteStorageManagerConfig:
     @property
     def tracing_enabled(self) -> bool:
         return self._values["tracing.enabled"]
-
-    @property
-    def tracing_jax_profiler_enabled(self) -> bool:
-        return self._values["tracing.jax.profiler.enabled"]
 
     @property
     def tracing_max_spans(self) -> int:

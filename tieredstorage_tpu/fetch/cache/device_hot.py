@@ -445,18 +445,32 @@ class DeviceHotCache(ChunkManager):
                 self.rejections += 1
                 note_mutation("device_hot.DeviceHotCache.rejections")
             return
-        window = self._build_window(wkey, file, chunk_ids, chunks, captured)
+        # What an admitting fetch pays on top of its decrypt: the window's
+        # host mirror (and device retention), then the insert.
+        with self.tracer.span("hot.admit", window=wkey) as span:
+            window = self._build_window(wkey, file, chunk_ids, chunks, captured)
+            admitted = self._admit_window(window, frequency)
+            if span is not None:
+                span.attributes.update(
+                    bytes=window.nbytes, device=window.device is not None,
+                    admitted=admitted,
+                )
+
+    def _admit_window(self, window: HotWindow, frequency: int) -> bool:
+        """Insert a built window, evicting colder ones; False where the
+        budget, a hotter victim or a racing admitter kept it out."""
+        wkey, file, chunk_ids = window.key, window.file, window.chunk_ids
         if window.nbytes > self.budget_bytes:
             with self._lock:
                 self.rejections += 1
                 note_mutation("device_hot.DeviceHotCache.rejections")
             self.tracer.event("hot.reject", window=wkey, bytes=window.nbytes)
-            return
+            return False
         evicted: list[str] = []
         with self._lock:
             if wkey in self._windows:  # racing admitter won; keep theirs
                 self._windows.move_to_end(wkey)
-                return
+                return False
             while self._bytes + window.nbytes > self.budget_bytes:
                 victim_key = next(iter(self._windows))
                 if self._sketch.estimate(victim_key) > frequency:
@@ -464,7 +478,7 @@ class DeviceHotCache(ChunkManager):
                     # candidate — a one-shot scan must not wash out the set.
                     self.rejections += 1
                     note_mutation("device_hot.DeviceHotCache.rejections")
-                    return
+                    return False
                 self._evict_locked(victim_key)
                 evicted.append(victim_key)
             self._windows[wkey] = window
@@ -479,10 +493,7 @@ class DeviceHotCache(ChunkManager):
             note_mutation("device_hot.DeviceHotCache.admissions")
         for victim_key in evicted:
             self.tracer.event("hot.evict", window=victim_key)
-        self.tracer.event(
-            "hot.admit", window=wkey, bytes=window.nbytes,
-            device=window.device is not None,
-        )
+        return True
 
     def _evict_locked(self, victim_key: str) -> None:
         """Drop the coldest window (caller holds ``_lock``). Index entries

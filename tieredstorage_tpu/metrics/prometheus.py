@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import re
+import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Iterable, Optional
@@ -32,6 +33,7 @@ from tieredstorage_tpu.metrics.core import (
     MetricsRegistry,
     Total,
 )
+from tieredstorage_tpu.utils.platforms import program_trace_stats
 
 _INVALID = re.compile(r"[^a-zA-Z0-9_:]")
 
@@ -185,7 +187,11 @@ class PrometheusExporter:
         recorder's ring-buffer state (empty when no tracer is wired), and —
         when a flight recorder is wired — its `flight` section: requests
         seen/failed, slow-ring occupancy, and the top-3 slowest requests
-        with their cache-tier breakdowns (utils/flightrecorder.py)."""
+        with their cache-tier breakdowns (utils/flightrecorder.py). Beside
+        them the exact counts of the work a span cannot see from inside:
+        `programs` (traced and lowered in this process; a cold one-row
+        program outlasts the index cache's default `get.timeout.ms`) and
+        `gcm` (context builds, duplicates among them, their seconds)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}
@@ -196,6 +202,11 @@ class PrometheusExporter:
                 "dropped_spans": tracer.dropped_spans,
                 "spans": tracer.summary(),
             }
+        out["programs"] = program_trace_stats()
+        # Only where the GCM path is loaded: a scrape never imports jax.
+        gcm = sys.modules.get("tieredstorage_tpu.ops.gcm")
+        if gcm is not None:
+            out["gcm"] = gcm.context_stats()
         recorder = self.flight_recorder
         out["flight"] = (
             recorder.summary() if recorder is not None else {"enabled": False}

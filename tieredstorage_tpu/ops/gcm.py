@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 import weakref
 
 import jax
@@ -56,6 +57,62 @@ class GcmContext:
     step_mat: np.ndarray = None
 
 
+# --- context-build accounting ---
+#
+# A context build runs only on an `lru_cache` miss, in pure Python (the
+# H-power matrices of gf128.ghash_agg_matrices), and the caches have no single
+# flight: concurrent first uses of one (key, aad, size) each build it, under
+# the interpreter lock, and each then takes as long as all of them together.
+# These counts see that exactly; they cost nothing on a hit. Guarded by
+# `_DISPATCH_MU`, with the launch counts further down.
+_CONTEXT_STATS = {
+    "context_builds": 0, "context_builds_duplicate": 0, "context_build_seconds": 0.0,
+}
+_BUILDS_IN_FLIGHT: dict = {}  # identity -> builds running; under _DISPATCH_MU
+_CONTEXT_TLS = threading.local()
+
+
+def context_stats() -> dict:
+    """`context_builds` (cache misses of `make_context` and
+    `make_varlen_context`), `context_builds_duplicate` (those that started
+    while another build of the same key, aad and size was running) and
+    `context_build_seconds` (summed over the builds, so a pile-up of four
+    counts its wall time four times)."""
+    with _DISPATCH_MU:
+        return dict(_CONTEXT_STATS)
+
+
+def thread_context_builds() -> int:
+    """Context builds run by the CALLING thread: the delta around a
+    `make_context` call says whether it was a miss."""
+    return getattr(_CONTEXT_TLS, "count", 0)
+
+
+def _counted_build(build):
+    """Count and time `build`, the body of a context cache."""
+
+    @functools.wraps(build)
+    def counted(*args):
+        identity = (build.__name__, *args)
+        with _DISPATCH_MU:
+            running = _BUILDS_IN_FLIGHT.get(identity, 0)
+            _BUILDS_IN_FLIGHT[identity] = running + 1
+            _CONTEXT_STATS["context_builds"] += 1
+            _CONTEXT_STATS["context_builds_duplicate"] += bool(running)
+        _CONTEXT_TLS.count = getattr(_CONTEXT_TLS, "count", 0) + 1
+        start = time.perf_counter()
+        try:
+            return build(*args)
+        finally:
+            seconds = time.perf_counter() - start
+            with _DISPATCH_MU:
+                if running := _BUILDS_IN_FLIGHT.pop(identity) - 1:
+                    _BUILDS_IN_FLIGHT[identity] = running
+                _CONTEXT_STATS["context_build_seconds"] += seconds
+
+    return counted
+
+
 @functools.lru_cache(maxsize=16)
 def _derive_h(key: bytes) -> tuple[np.ndarray, int]:
     """Round keys and the GHASH key H = E_K(0^128) for an AES-256 key."""
@@ -67,6 +124,7 @@ def _derive_h(key: bytes) -> tuple[np.ndarray, int]:
 
 
 @functools.lru_cache(maxsize=64)
+@_counted_build
 def _context_cached(key: bytes, aad: bytes, chunk_bytes: int) -> GcmContext:
     round_keys, h = _derive_h(key)
 
@@ -256,19 +314,27 @@ def _gcm_process_batch(
     batch = data.shape[0]
     padded_len = n_blocks * 16
 
-    ks = ctr_keystream_batch(round_keys, ivs, 1, n_blocks + 1)  # [B, n_blocks+1, 16]
-    tag_mask = ks[:, 0, :]
-    keystream = ks[:, 1:, :].reshape(batch, padded_len)[:, :chunk_bytes]
+    # The named scopes put every device operation of the program down to a
+    # stage in a profile (tools/profile_report.py sums device time by them).
+    with jax.named_scope("gcm.ctr"):
+        ks = ctr_keystream_batch(round_keys, ivs, 1, n_blocks + 1)  # [B, n_blocks+1, 16]
+        tag_mask = ks[:, 0, :]
+        keystream = ks[:, 1:, :].reshape(batch, padded_len)[:, :chunk_bytes]
 
-    output = data ^ keystream
+    with jax.named_scope("gcm.xor"):
+        output = data ^ keystream
 
-    ct = data if decrypt else output
-    if padded_len != chunk_bytes:
-        ct_padded = jnp.zeros((batch, padded_len), jnp.uint8).at[:, :chunk_bytes].set(ct)
-    else:
-        ct_padded = ct
-    ghash = _ghash_of_ct(ct_padded, agg_mats, final_mat, const_bits, step_mat)
-    tags = _bits_to_bytes(ghash) ^ tag_mask
+    with jax.named_scope("gcm.ghash"):
+        ct = data if decrypt else output
+        if padded_len != chunk_bytes:
+            ct_padded = (
+                jnp.zeros((batch, padded_len), jnp.uint8).at[:, :chunk_bytes].set(ct)
+            )
+        else:
+            ct_padded = ct
+        ghash = _ghash_of_ct(ct_padded, agg_mats, final_mat, const_bits, step_mat)
+    with jax.named_scope("gcm.tag"):
+        tags = _bits_to_bytes(ghash) ^ tag_mask
     return output, tags
 
 
@@ -488,6 +554,7 @@ class GcmVarlenContext:
 
 
 @functools.lru_cache(maxsize=64)
+@_counted_build
 def _varlen_context_cached(key: bytes, aad: bytes, max_bytes: int) -> GcmVarlenContext:
     round_keys, h = _derive_h(key)
     m_max = _ceil_div(max_bytes, 16)
@@ -545,47 +612,51 @@ def _gcm_varlen_batch(
     Returns (output uint8[B, max_bytes], tags uint8[B, 16])."""
     batch = data.shape[0]
 
-    ks = ctr_keystream_batch(round_keys, ivs, 1, m_max + 1)
-    tag_mask = ks[:, 0, :]
-    keystream = ks[:, 1:, :].reshape(batch, m_max * 16)[:, :max_bytes]
+    with jax.named_scope("gcm.ctr"):
+        ks = ctr_keystream_batch(round_keys, ivs, 1, m_max + 1)
+        tag_mask = ks[:, 0, :]
+        keystream = ks[:, 1:, :].reshape(batch, m_max * 16)[:, :max_bytes]
 
-    byte_mask = (
-        jnp.arange(max_bytes, dtype=jnp.int32)[None, :] < lengths[:, None]
-    ).astype(jnp.uint8)
-    output = (data ^ keystream) * byte_mask
+    with jax.named_scope("gcm.xor"):
+        byte_mask = (
+            jnp.arange(max_bytes, dtype=jnp.int32)[None, :] < lengths[:, None]
+        ).astype(jnp.uint8)
+        output = (data ^ keystream) * byte_mask
 
-    ct = data if decrypt else output  # ct is already masked in both directions
-    ct_blocks = ct.reshape(batch, m_max, 16)
+    with jax.named_scope("gcm.ghash"):
+        ct = data if decrypt else output  # ct is already masked in both directions
+        ct_blocks = ct.reshape(batch, m_max, 16)
 
-    n_blocks = _ceil_div_dev(lengths)  # int32[B] data blocks per row
-    seq = jnp.concatenate(
-        [
-            jnp.broadcast_to(aad_blocks, (batch, m_a, 16)).astype(jnp.uint8),
-            ct_blocks,
-            jnp.zeros((batch, m_cap - m_a - m_max, 16), jnp.uint8),
-        ],
-        axis=1,
-    )
-    # Place each row's length block right after its data blocks.
-    l_pos = m_a + n_blocks  # int32[B]
-    onehot = (
-        jnp.arange(m_cap, dtype=jnp.int32)[None, :] == l_pos[:, None]
-    ).astype(jnp.uint8)
-    seq = seq ^ (onehot[:, :, None] * len_blocks[:, None, :])
-    # Rotate right so the sequence ends at slot m_cap-1.
-    shift = m_cap - (l_pos + 1)
-    idx = (jnp.arange(m_cap, dtype=jnp.int32)[None, :] - shift[:, None]) % m_cap
-    seq = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
-
-    t = _ghash_grouped(seq.reshape(batch, -1), agg_mats, step_mat)
-    ghash = (
-        jax.lax.dot_general(
-            t.astype(jnp.int8), h_mat, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
+        n_blocks = _ceil_div_dev(lengths)  # int32[B] data blocks per row
+        seq = jnp.concatenate(
+            [
+                jnp.broadcast_to(aad_blocks, (batch, m_a, 16)).astype(jnp.uint8),
+                ct_blocks,
+                jnp.zeros((batch, m_cap - m_a - m_max, 16), jnp.uint8),
+            ],
+            axis=1,
         )
-        & 1
-    ).astype(jnp.uint8)
-    tags = _bits_to_bytes(ghash) ^ tag_mask
+        # Place each row's length block right after its data blocks.
+        l_pos = m_a + n_blocks  # int32[B]
+        onehot = (
+            jnp.arange(m_cap, dtype=jnp.int32)[None, :] == l_pos[:, None]
+        ).astype(jnp.uint8)
+        seq = seq ^ (onehot[:, :, None] * len_blocks[:, None, :])
+        # Rotate right so the sequence ends at slot m_cap-1.
+        shift = m_cap - (l_pos + 1)
+        idx = (jnp.arange(m_cap, dtype=jnp.int32)[None, :] - shift[:, None]) % m_cap
+        seq = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
+
+        t = _ghash_grouped(seq.reshape(batch, -1), agg_mats, step_mat)
+        ghash = (
+            jax.lax.dot_general(
+                t.astype(jnp.int8), h_mat, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+            & 1
+        ).astype(jnp.uint8)
+    with jax.named_scope("gcm.tag"):
+        tags = _bits_to_bytes(ghash) ^ tag_mask
     return output, tags
 
 
@@ -685,14 +756,16 @@ def _packed_fixed_impl(
     step_mat=None,
     *, chunk_bytes: int, n_blocks: int, decrypt: bool,
 ):
-    if ivs is None:  # trace-time branch: IVs ride the packed tail
-        ivs = data_packed[:, chunk_bytes : chunk_bytes + 12]
+    with jax.named_scope("gcm.pack"):
+        if ivs is None:  # trace-time branch: IVs ride the packed tail
+            ivs = data_packed[:, chunk_bytes : chunk_bytes + 12]
+        data = data_packed[:, :chunk_bytes]
     out, tags = _gcm_process_batch(
-        round_keys, ivs, data_packed[:, :chunk_bytes], agg_mats, final_mat,
-        const_bits, step_mat,
+        round_keys, ivs, data, agg_mats, final_mat, const_bits, step_mat,
         chunk_bytes=chunk_bytes, n_blocks=n_blocks, decrypt=decrypt,
     )
-    return jnp.concatenate([out, tags], axis=1)
+    with jax.named_scope("gcm.pack"):
+        return jnp.concatenate([out, tags], axis=1)
 
 
 def _device_len_blocks(lengths: jnp.ndarray, aad_bit_len: int) -> jnp.ndarray:
@@ -727,20 +800,23 @@ def _packed_varlen_impl(
     *, aad_bit_len: int, max_bytes: int, m_max: int, m_a: int,
     m_cap: int, decrypt: bool,
 ):
-    if ivs is None:
-        ivs = data_packed[:, max_bytes : max_bytes + 12]
-    if lengths is None:
-        lb = data_packed[:, max_bytes + 12 : max_bytes + 16].astype(jnp.int32)
-        lengths = lb[:, 0] | (lb[:, 1] << 8) | (lb[:, 2] << 16) | (lb[:, 3] << 24)
-    if len_blocks is None:
-        len_blocks = _device_len_blocks(lengths, aad_bit_len)
+    with jax.named_scope("gcm.pack"):
+        if ivs is None:
+            ivs = data_packed[:, max_bytes : max_bytes + 12]
+        if lengths is None:
+            lb = data_packed[:, max_bytes + 12 : max_bytes + 16].astype(jnp.int32)
+            lengths = lb[:, 0] | (lb[:, 1] << 8) | (lb[:, 2] << 16) | (lb[:, 3] << 24)
+        if len_blocks is None:
+            len_blocks = _device_len_blocks(lengths, aad_bit_len)
+        data = data_packed[:, :max_bytes]
     out, tags = _gcm_varlen_batch(
-        round_keys, ivs, data_packed[:, :max_bytes], lengths, len_blocks,
+        round_keys, ivs, data, lengths, len_blocks,
         aad_blocks, agg_mats, h_mat, step_mat,
         max_bytes=max_bytes, m_max=m_max,
         m_a=m_a, m_cap=m_cap, decrypt=decrypt,
     )
-    return jnp.concatenate([out, tags], axis=1)
+    with jax.named_scope("gcm.pack"):
+        return jnp.concatenate([out, tags], axis=1)
 
 
 def _require_tail_metadata(*side_args) -> None:

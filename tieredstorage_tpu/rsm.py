@@ -105,8 +105,8 @@ log = logging.getLogger(__name__)
 
 def _traced(name: str):
     """Span around an RSM operation, tagged with topic/partition (SURVEY §5:
-    the reference only has SLF4J boundary logs; these spans also forward
-    into jax.profiler timelines when tracing.jax.profiler.enabled).
+    the reference only has SLF4J boundary logs; an open jax.profiler session
+    gets these spans in its trace too, utils/tracing.py).
 
     Also the deadline entry point: the operation adopts the ambient
     end-to-end Deadline (installed by the sidecar boundary from the caller's
@@ -208,7 +208,6 @@ class RemoteStorageManager:
 
         self.tracer = Tracer(
             enabled=config.tracing_enabled,
-            use_jax_profiler=config.tracing_jax_profiler_enabled,
             max_spans=config.tracing_max_spans,
         )
 
@@ -1372,7 +1371,13 @@ class RemoteStorageManager:
         chaos run can fail/stall writes without corrupting partially-consumed
         uploads."""
         faults.fire("storage.write", str(key))
-        return self._storage.upload(stream, key)
+        # The store pulls the transform's stream, so the transform's spans
+        # are this span's children and its self time is the write.
+        with self.tracer.span("storage.upload", key=key.value) as span:
+            uploaded = self._storage.upload(stream, key)
+            if span is not None:
+                span.attributes["bytes"] = uploaded
+        return uploaded
 
     def _upload_segment_log(
         self, metadata, segment_data, requires_compression, data_key,

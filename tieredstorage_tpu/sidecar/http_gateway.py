@@ -130,6 +130,15 @@ class _Handler(BaseHTTPRequestHandler):
         tie chunked-transfer framing into the section parser, and a segment
         copy is a once-per-segment operation whose cost is dominated by the
         transform, not local disk."""
+        tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
+        with tracer.span("gateway.spool") as span:
+            out = self._spool_body()
+            if span is not None:
+                span.attributes["bytes"] = out.tell()
+        out.seek(0)
+        return out
+
+    def _spool_body(self):
         out = tempfile.SpooledTemporaryFile(max_size=_SPOOL_BYTES)
         total = 0
 
@@ -196,7 +205,6 @@ class _Handler(BaseHTTPRequestHandler):
             if length > MAX_BODY_BYTES:
                 raise _BodyTooLarge()
             take(length)
-        out.seek(0)
         return out
 
     def _reply(self, status: int, body: bytes = b"", headers=None) -> None:
@@ -217,7 +225,9 @@ class _Handler(BaseHTTPRequestHandler):
         truncated 200. A failure later mid-stream can only abort the
         connection (the shim surfaces that as a transport error, the same
         way a gRPC mid-stream abort lands)."""
-        with contextlib.closing(stream):
+        tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
+        with contextlib.closing(stream), \
+                tracer.span("gateway.reply_stream", bytes=0, aborted=False) as span:
             first = stream.read(_STREAM_BLOCK)
             self.send_response(200)
             self.send_header("Transfer-Encoding", "chunked")
@@ -226,9 +236,13 @@ class _Handler(BaseHTTPRequestHandler):
                 block = first
                 while block:
                     self.wfile.write(b"%x\r\n" % len(block) + block + b"\r\n")
+                    if span is not None:
+                        span.attributes["bytes"] += len(block)
                     block = stream.read(_STREAM_BLOCK)
                 self.wfile.write(b"0\r\n\r\n")
             except Exception as exc:
+                if span is not None:  # the reader had left, or the stream failed
+                    span.attributes["aborted"] = True
                 raise _StreamAborted() from exc
 
     def _fail(self, exc: Exception) -> None:
@@ -496,63 +510,68 @@ class _Handler(BaseHTTPRequestHandler):
                 admission.release(tenant=tenant)
 
     def _handle_admitted(self, handler, tracer) -> None:
-        try:
-            body = self._body()
-        except _BodyTooLarge:
-            self._reply(413, b"request body exceeds MAX_BODY_BYTES")
-            self.close_connection = True  # unread body left on the socket
-            return
-        except Exception as exc:  # noqa: BLE001 — body-framing failure
-            # The request body was only partially consumed: the remaining
-            # bytes would be parsed as the next request line, desyncing the
-            # keep-alive connection. Answer, then drop the connection.
-            self._fail(exc)
-            self.close_connection = True
-            return
         # Join the caller's trace (W3C traceparent header, sent by the JVM
-        # shim or a Python client) and record the gateway leg as one span —
-        # the span covers the streamed response too, so time-to-last-byte of
-        # a fetch is the gateway span's extent. The caller's deadline
-        # (x-deadline-ms, remaining budget) is adopted the same way; absent
-        # one, the RSM's configured default applies. The scope covers the
-        # streamed drain, so chunk fetches during the response also honor it.
-        wire_deadline = parse_deadline_ms(self.headers.get(shimwire.DEADLINE_HEADER))
-        recorder = getattr(self.rsm, "flight_recorder", NOOP_RECORDER)
-        try:
-            # The flight record spans the streamed drain too (like the span
-            # and the deadline scope), so chunk-tier outcomes during the
-            # response land on THIS request's record.
-            with contextlib.closing(body), \
-                    deadline_scope(wire_deadline), \
-                    ensure_deadline(getattr(self.rsm, "default_deadline_s", None)) as deadline, \
-                    tracer.continue_trace(
-                        self.headers.get(shimwire.TRACEPARENT_HEADER)), \
-                    tracer.span(
-                        "gateway" + self.path.replace("/v1/", "."),
-                        **(
-                            {"deadline_ms": round(deadline.remaining_s() * 1000.0, 1)}
-                            if deadline is not None else {}
-                        ),
-                    ) as span, \
-                    recorder.request(
-                        "gateway" + self.path.replace("/v1/", "."),
-                        trace_id=span.trace_id if span else None,
-                    ):
-                handler(body)
-        except _StreamAborted:
-            # Response already committed; the only safe move is dropping
-            # the connection so the client sees a truncated stream (the
-            # shim maps that to RemoteStorageException).
-            self.close_connection = True
-        except Exception as exc:  # noqa: BLE001 — boundary translation
-            self._fail(exc)
+        # shim or a Python client) and record the gateway leg as one span:
+        # the whole server-side handling, from the body's first byte read
+        # (`gateway.spool` is its first child) through the streamed response,
+        # so time-to-last-byte of a fetch is the gateway span's extent.
+        # One function on purpose: a process's first windows are traced by
+        # JAX under this frame, and on the v5e's host one more Python frame
+        # here made that tracing 8-13 s slower per process (PERF.md).
+        name = "gateway" + self.path.replace("/v1/", ".")
+        with tracer.continue_trace(
+                self.headers.get(shimwire.TRACEPARENT_HEADER)), \
+                tracer.span(name) as span:
+            try:
+                body = self._body()
+            except _BodyTooLarge:
+                self._reply(413, b"request body exceeds MAX_BODY_BYTES")
+                self.close_connection = True  # unread body left on the socket
+                return
+            except Exception as exc:  # noqa: BLE001 — body-framing failure
+                # The request body was only partially consumed: the remaining
+                # bytes would be parsed as the next request line, desyncing
+                # the keep-alive connection. Answer, then drop the connection.
+                self._fail(exc)
+                self.close_connection = True
+                return
+            # The caller's deadline (x-deadline-ms, remaining budget) is
+            # adopted once the body is in; absent one, the RSM's configured
+            # default applies. The scope covers the streamed drain, so chunk
+            # fetches during the response also honor it.
+            wire_deadline = parse_deadline_ms(self.headers.get(shimwire.DEADLINE_HEADER))
+            recorder = getattr(self.rsm, "flight_recorder", NOOP_RECORDER)
+            try:
+                # The flight record spans the streamed drain too (like the
+                # span and the deadline scope), so chunk-tier outcomes during
+                # the response land on THIS request's record.
+                with contextlib.closing(body), \
+                        deadline_scope(wire_deadline), \
+                        ensure_deadline(getattr(self.rsm, "default_deadline_s", None)) as deadline, \
+                        recorder.request(
+                            name, trace_id=span.trace_id if span else None,
+                        ):
+                    if span is not None and deadline is not None:
+                        span.attributes["deadline_ms"] = round(
+                            deadline.remaining_s() * 1000.0, 1
+                        )
+                    handler(body)
+            except _StreamAborted:
+                # Response already committed; the only safe move is dropping
+                # the connection so the client sees a truncated stream (the
+                # shim maps that to RemoteStorageException).
+                self.close_connection = True
+            except Exception as exc:  # noqa: BLE001 — boundary translation
+                self._fail(exc)
 
     def _copy(self, body) -> None:
-        md = shimwire.decode_metadata(body)
+        tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
         with tempfile.TemporaryDirectory(prefix="sidecar-http-copy-") as tmp:
             # Sections stream straight to files — a multi-GiB segment never
             # has to fit in sidecar RAM on top of the spooled request body.
-            paths = shimwire.decode_sections_to_dir(body, tmp)
+            with tracer.span("gateway.decode"):
+                md = shimwire.decode_metadata(body)
+                paths = shimwire.decode_sections_to_dir(body, tmp)
             for required in ("log_segment", "offset_index", "time_index",
                              "leader_epoch_index"):
                 if paths[required] is None:

@@ -44,6 +44,7 @@ from tieredstorage_tpu.ops.gcm import (
 from tieredstorage_tpu.parallel.mesh import MeshPlan
 from tieredstorage_tpu.security.aes import IV_SIZE, TAG_SIZE
 from tieredstorage_tpu.utils.locks import new_lock, note_mutation
+from tieredstorage_tpu.utils.platforms import thread_program_traces
 from tieredstorage_tpu.transform.api import (
     THUFF,
     TLZHUFF,
@@ -444,6 +445,23 @@ class TpuTransformBackend(TransformBackend):
             )
         return np.frombuffer(os.urandom(IV_SIZE * n), dtype=np.uint8).reshape(n, IV_SIZE)
 
+    def _window_context(self, enc, sizes: list[int]):
+        """The GCM context of a window of these row sizes, its row width and
+        whether it is the varlen form. A hit is a dictionary lookup; a miss
+        builds the (key, aad, size)'s constants on the host (`built`)."""
+        with self.tracer.span("transform.context") as span:
+            builds = gcm_ops.thread_context_builds()
+            varlen = len(set(sizes)) != 1
+            if varlen:
+                ctx = make_varlen_context(enc.data_key, enc.aad, max(sizes))
+                n_bytes = ctx.max_bytes
+            else:
+                ctx = make_context(enc.data_key, enc.aad, sizes[0])
+                n_bytes = ctx.chunk_bytes
+            if span is not None:
+                span.attributes["built"] = gcm_ops.thread_context_builds() > builds
+        return ctx, n_bytes, varlen
+
     def _build_packed(
         self, payloads: list, sizes: list[int], ivs: np.ndarray, n_bytes: int,
         varlen: bool,
@@ -453,14 +471,15 @@ class TpuTransformBackend(TransformBackend):
         per-row metadata the fused kernel reads from the tail columns
         ([iv 12 B][length u32 LE 4 B]), so the whole window crosses the
         host→device link as a single buffer."""
-        packed = np.zeros((len(payloads), n_bytes + TAG_SIZE), dtype=np.uint8)
-        for i, p in enumerate(payloads):
-            packed[i, : sizes[i]] = np.frombuffer(p, dtype=np.uint8)
-        packed[:, n_bytes : n_bytes + IV_SIZE] = ivs
-        if varlen:
-            packed[:, n_bytes + IV_SIZE :] = (
-                np.asarray(sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
-            )
+        with self.tracer.span("transform.pack"):
+            packed = np.zeros((len(payloads), n_bytes + TAG_SIZE), dtype=np.uint8)
+            for i, p in enumerate(payloads):
+                packed[i, : sizes[i]] = np.frombuffer(p, dtype=np.uint8)
+            packed[:, n_bytes : n_bytes + IV_SIZE] = ivs
+            if varlen:
+                packed[:, n_bytes + IV_SIZE :] = (
+                    np.asarray(sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
+                )
         return packed
 
     def _stage_packed(self, packed: np.ndarray, varlen: bool):
@@ -468,7 +487,8 @@ class TpuTransformBackend(TransformBackend):
         host→device transfer of the window path (h2d counter). The row
         axis lands sharded over the plan's mesh (replication-free: each
         chip holds only its rows), or on the one device on the fallback
-        plan."""
+        plan. The `transform.h2d` span is the host's time in the placement,
+        not the transfer's (the device plane of a profile has that)."""
         plan = self.mesh_plan()
         n_bytes = packed.shape[1] - TAG_SIZE
         pad = plan.pad_rows(packed.shape[0])
@@ -479,7 +499,8 @@ class TpuTransformBackend(TransformBackend):
                 # contract; padding rows carry one block like real callers.
                 pad_rows[:, n_bytes + IV_SIZE] = 16
             packed = np.concatenate([packed, pad_rows])
-        staged = plan.shard(packed)
+        with self.tracer.span("transform.h2d", bytes=packed.nbytes):
+            staged = plan.shard(packed)
         with self._stats_lock:
             self.dispatch_stats.h2d_transfers += 1
             self.dispatch_stats.mesh_size = plan.size
@@ -497,29 +518,37 @@ class TpuTransformBackend(TransformBackend):
         steady state regardless of mesh size; a genuinely mismatched
         sharding would be the only reason to skip, and no such case exists
         on this path. Starts the device→host copy immediately so the
-        result streams back while later windows compute."""
+        result streams back while later windows compute. The
+        `transform.launch` span is the host's enqueue time: placing the
+        context's constants on their first use, the jitted call (`traced`
+        when it traced a new program: seconds, where a launch is
+        milliseconds) and starting the copy back."""
         mesh = self.mesh_plan().mesh
-        before = gcm_ops.thread_dispatches()
-        rt_before = gcm_ops.thread_hbm_roundtrips()
-        if varlen:
-            out = gcm_varlen_window_packed(
-                ctx, None, staged, None, decrypt=decrypt, donate=True,
-                mesh=mesh,
-            )
-        else:
-            out = gcm_window_packed(
-                ctx, None, staged, decrypt=decrypt, donate=True, mesh=mesh,
-            )
-        delta = gcm_ops.thread_dispatches() - before
-        rt_delta = gcm_ops.thread_hbm_roundtrips() - rt_before
-        donated = staged.is_deleted()  # XLA consumed the staged allocation
-        with self._stats_lock:
-            self.dispatch_stats.dispatches += delta
-            self.dispatch_stats.hbm_roundtrips += rt_delta
-            if donated:
-                self.dispatch_stats.donated_buffers += 1
-            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
-        out.copy_to_host_async()
+        with self.tracer.span("transform.launch") as span:
+            traces = thread_program_traces()
+            before = gcm_ops.thread_dispatches()
+            rt_before = gcm_ops.thread_hbm_roundtrips()
+            if varlen:
+                out = gcm_varlen_window_packed(
+                    ctx, None, staged, None, decrypt=decrypt, donate=True,
+                    mesh=mesh,
+                )
+            else:
+                out = gcm_window_packed(
+                    ctx, None, staged, decrypt=decrypt, donate=True, mesh=mesh,
+                )
+            delta = gcm_ops.thread_dispatches() - before
+            rt_delta = gcm_ops.thread_hbm_roundtrips() - rt_before
+            donated = staged.is_deleted()  # XLA consumed the staged allocation
+            with self._stats_lock:
+                self.dispatch_stats.dispatches += delta
+                self.dispatch_stats.hbm_roundtrips += rt_delta
+                if donated:
+                    self.dispatch_stats.donated_buffers += 1
+                note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+            out.copy_to_host_async()
+            if span is not None:
+                span.attributes["traced"] = thread_program_traces() > traces
         return out
 
     @_spanned("transform.encrypt_dispatch")
@@ -531,13 +560,7 @@ class TpuTransformBackend(TransformBackend):
         sizes = [len(c) for c in chunks]
         ivs = self._make_ivs(len(chunks), opts)
 
-        varlen = len(set(sizes)) != 1
-        if varlen:
-            ctx = make_varlen_context(enc.data_key, enc.aad, max(sizes))
-            n_bytes = ctx.max_bytes
-        else:
-            ctx = make_context(enc.data_key, enc.aad, sizes[0])
-            n_bytes = ctx.chunk_bytes
+        ctx, n_bytes, varlen = self._window_context(enc, sizes)
         packed = self._build_packed(chunks, sizes, ivs, n_bytes, varlen)
         staged = self._stage_packed(packed, varlen)
         out = self._launch_packed(ctx, staged, varlen, decrypt=False)
@@ -554,7 +577,8 @@ class TpuTransformBackend(TransformBackend):
         device→host fetch) and materialize the wire format
         (IV || ct || tag per chunk)."""
         ivs, sizes, n_bytes, out = staged
-        host = np.asarray(out)
+        with self.tracer.span("transform.d2h_wait"):
+            host = np.asarray(out)
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
@@ -642,13 +666,7 @@ class TpuTransformBackend(TransformBackend):
         load — including the hot-tier retention hook, which only fires
         here: a merged buffer interleaves requests and is never offered
         for retention)."""
-        varlen = len(set(sizes)) != 1
-        if varlen:
-            ctx = make_varlen_context(enc.data_key, enc.aad, max(sizes))
-            n_bytes = ctx.max_bytes
-        else:
-            ctx = make_context(enc.data_key, enc.aad, sizes[0])
-            n_bytes = ctx.chunk_bytes
+        ctx, n_bytes, varlen = self._window_context(enc, sizes)
         packed = self._build_packed(payloads, sizes, ivs, n_bytes, varlen)
         staged = self._stage_packed(packed, varlen)
         out = self._launch_packed(ctx, staged, varlen, decrypt=True)
@@ -657,7 +675,8 @@ class TpuTransformBackend(TransformBackend):
             self.dispatch_stats.bytes_in += sum(sizes)
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
-        host = np.asarray(out)
+        with self.tracer.span("transform.d2h_wait"):
+            host = np.asarray(out)
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")

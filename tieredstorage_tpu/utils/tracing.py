@@ -8,8 +8,13 @@ RemoteStorageManager.java:218,549,598); this build adds a real span system:
 - W3C ``traceparent`` propagation (`current_traceparent` / `continue_trace`)
   so one request shows up as a single tree spanning
   client → sidecar gateway → RSM → storage backend;
-- optional forwarding into jax.profiler traces (so spans show up in
-  XProf/TensorBoard timelines next to the device kernels they launched);
+- every span and event of an enabled tracer is also a
+  ``jax.profiler.TraceAnnotation``, in a process that has imported ``jax``
+  already: a profiler session that happens to be open gets the program's
+  spans in the same trace as the device plane, on the profiler's clock
+  (``tools/profile_report.py`` checks one against the other); outside a
+  session an annotation costs nothing measurable, and a process without
+  ``jax`` (a client-side tracer) never imports it for this;
 - a bounded ring-buffer recorder (newest spans win; evictions are counted in
   `dropped_spans`) with per-name p50/p95/p99 summaries and a Chrome
   trace-event JSON exporter (loadable in Perfetto / ``chrome://tracing``,
@@ -32,6 +37,7 @@ import json
 import math
 import os
 import pathlib
+import sys
 import threading
 import time
 from typing import Iterator, Optional
@@ -79,6 +85,33 @@ def parse_traceparent(header: Optional[str]) -> Optional[tuple[str, str]]:
     return trace_id, span_id
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None in a process
+    that has not imported ``jax`` (tracing never imports it, and never fails
+    a request over it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation(name)
+    except Exception:  # noqa: BLE001 — e.g. jax still half-imported
+        return None
+
+
+def _self_seconds(span: "Span", children: list) -> float:
+    """``span``'s duration less the part its children cover. Children of
+    pipelined windows overlap, and one adopted across threads may outlive
+    its parent: they are clipped to the span and merged before they are
+    subtracted, so the result is never negative."""
+    covered, reach = 0.0, span.start_s
+    for start, end in sorted(children):
+        start, end = max(start, reach), min(end, span.end_s)
+        if end > start:
+            covered += end - start
+            reach = end
+    return max(0.0, span.duration_s - covered)
+
+
 @dataclasses.dataclass
 class Span:
     name: str
@@ -120,10 +153,8 @@ class Tracer:
     span is evicted (and counted in `dropped_spans`), so long soak runs keep
     the newest spans instead of silently freezing the recorder."""
 
-    def __init__(self, enabled: bool = False, *, use_jax_profiler: bool = False,
-                 max_spans: int = 10_000):
+    def __init__(self, enabled: bool = False, *, max_spans: int = 10_000):
         self.enabled = enabled
-        self.use_jax_profiler = use_jax_profiler
         self.max_spans = max_spans
         self._spans: collections.deque[Span] = collections.deque(maxlen=max_spans)
         #: Spans evicted from the ring buffer (exported as a counter metric).
@@ -205,15 +236,9 @@ class Tracer:
             parent_id=parent_id, thread_id=threading.get_ident(),
         )
         stack.append(s)
-        ctx = None
-        if self.use_jax_profiler:
-            try:
-                import jax.profiler
-
-                ctx = jax.profiler.TraceAnnotation(name)
-                ctx.__enter__()
-            except Exception:
-                ctx = None
+        ctx = _annotation(name)
+        if ctx is not None:
+            ctx.__enter__()
         try:
             yield s
         finally:
@@ -236,15 +261,11 @@ class Tracer:
             attributes=attributes, trace_id=trace_id, span_id=_gen_span_id(),
             parent_id=parent_id, thread_id=threading.get_ident(),
         )
-        if self.use_jax_profiler:
-            # Zero-duration annotation: timeline parity with span() so events
-            # land in XProf next to the kernels they interleave with.
-            try:
-                import jax.profiler
-
-                with jax.profiler.TraceAnnotation(name):
-                    pass
-            except Exception:
+        # Zero-duration annotation: timeline parity with span(), so events
+        # land in a profile next to the kernels they interleave with.
+        ctx = _annotation(name)
+        if ctx is not None:
+            with ctx:
                 pass
         self._record(s)
         return s
@@ -268,7 +289,9 @@ class Tracer:
             self.dropped_spans = 0
 
     def summary(self) -> dict[str, dict[str, float]]:
-        """Per-name count/total/avg/max plus p50/p95/p99 durations (seconds).
+        """Per-name count/total/avg/max plus p50/p95/p99 durations (seconds),
+        and ``self_s``: the name's total less what its spans' children
+        (found by ``parent_id`` among the recorded spans) cover.
 
         Degenerate-case contract (ISSUE 14): no recorded spans means an
         EMPTY dict — a name never appears with fabricated zero percentiles,
@@ -277,15 +300,23 @@ class Tracer:
         one span reports that span's duration as count=1, avg, max, and
         every percentile (nearest-rank: one sample is every quantile of
         itself)."""
+        spans = self.spans()
+        children: dict[str, list[tuple[float, float]]] = {}
+        for s in spans:
+            if s.parent_id is not None:
+                children.setdefault(s.parent_id, []).append((s.start_s, s.end_s))
         agg: dict[str, list[float]] = {}
-        for s in self.spans():
+        self_s: dict[str, float] = collections.defaultdict(float)
+        for s in spans:
             agg.setdefault(s.name, []).append(s.duration_s)
+            self_s[s.name] += _self_seconds(s, children.get(s.span_id, ()))
         out: dict[str, dict[str, float]] = {}
         for name, ds in agg.items():
             ds.sort()
             out[name] = {
                 "count": len(ds),
                 "total_s": sum(ds),
+                "self_s": self_s[name],
                 "avg_s": sum(ds) / len(ds),
                 "max_s": ds[-1],
                 "p50_s": _percentile(ds, 0.50),
