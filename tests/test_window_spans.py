@@ -13,6 +13,7 @@ import collections
 import http.client
 import os
 import threading
+import time
 
 import pytest
 
@@ -37,6 +38,17 @@ def _post(port: int, path: str, body: bytes, read: int | None = None):
         return response.status, response.read(read) if read else response.read()
     finally:
         conn.close()
+
+
+def _closed_spans(tracer, name: str, count: int) -> list:
+    """All spans, once `count` of `name` are in the ring. A `gateway.<op>`
+    span closes after its reply is written, so the client has its bytes
+    before the request's outermost span is recorded."""
+    deadline = time.monotonic() + 60
+    while len(tracer.spans(name)) < count:
+        assert time.monotonic() < deadline, f"{name}: {len(tracer.spans(name))} of {count}"
+        time.sleep(0.005)
+    return tracer.spans()
 
 
 @pytest.fixture(scope="module")
@@ -67,12 +79,12 @@ def served(tmp_path_factory):
         assert status in (200, 204)
         if custom:
             md = md.with_custom_metadata(custom)
-        copy_spans = rsm.tracer.spans()
+        copy_spans = _closed_spans(rsm.tracer, "gateway.copy", 1)
         rsm.tracer.clear()
         fetch_body = shimwire.encode_metadata(md) + shimwire.encode_fetch_tail(0, CHUNK - 1)
         replies = [_post(gateway.port, "/v1/fetch", fetch_body) for _ in range(3)]
         assert [r[1] for r in replies] == [segment[:CHUNK]] * 3
-        fetch_spans = rsm.tracer.spans()
+        fetch_spans = _closed_spans(rsm.tracer, "gateway.fetch", 3)
     finally:
         gateway.stop()
         rsm.close()
@@ -237,42 +249,43 @@ class TestContextBuildCounters:
         )
 
     @pytest.mark.parametrize("make", [gcm.make_context, gcm.make_varlen_context])
-    def test_two_threads_building_one_key_and_size_count_a_duplicate(self, make, monkeypatch):
-        """The caches have no single flight: the second thread's build starts
-        while the first is running, and is counted as what single flight
-        would have saved."""
-        both_inside = threading.Barrier(2, timeout=60)
-        real = gcm.gf128.ghash_agg_matrices
+    def test_two_threads_building_one_key_and_size_run_one_build(self, make, monkeypatch):
+        """Single flight (ISSUE 28): the second thread finds the first one's
+        build running and waits for it, so nothing is built twice and the
+        duplicate count, which counts builds that start beside another of the
+        same key, aad and size, stays where it was."""
+        first_inside, second_called = threading.Event(), threading.Event()
+        real = gcm.gf128.ghash_level_table
 
-        def slow(h, m):
-            both_inside.wait()  # neither build ends before both have begun
-            return real(h, m)
+        def slow(p):
+            first_inside.set()
+            second_called.wait(60)  # the build outlasts the second thread's arrival
+            return real(p)
 
-        monkeypatch.setattr(gcm.gf128, "ghash_agg_matrices", slow)
-        key, before = os.urandom(32), gcm.context_stats()
+        monkeypatch.setattr(gcm.gf128, "ghash_level_table", slow)
+        key, before, got = os.urandom(32), gcm.context_stats(), []
         threads = [
-            threading.Thread(target=make, args=(key, b"aad", 4096)) for _ in range(2)
+            threading.Thread(target=lambda: got.append(make(key, b"aad", 4096)))
+            for _ in range(2)
         ]
-        for t in threads:
-            t.start()
+        threads[0].start()
+        assert first_inside.wait(60)
+        threads[1].start()
+        time.sleep(0.05)  # the second is at the cache, or soon will be: a hit either way
+        second_called.set()
         for t in threads:
             t.join(60)
         after = gcm.context_stats()
-        assert after["context_builds"] == before["context_builds"] + 2
-        assert after["context_builds_duplicate"] == before["context_builds_duplicate"] + 1
+        assert len(got) == 2 and got[0] is got[1]
+        assert after["context_builds"] == before["context_builds"] + 1
+        assert after["context_builds_duplicate"] == before["context_builds_duplicate"]
         assert not gcm._BUILDS_IN_FLIGHT
-        # one after the other is no duplicate
-        monkeypatch.setattr(gcm.gf128, "ghash_agg_matrices", real)
-        make(os.urandom(32), b"aad", 4096)
-        assert gcm.context_stats()["context_builds_duplicate"] == (
-            after["context_builds_duplicate"]
-        )
 
     def test_a_build_that_raises_leaves_nothing_in_flight(self, monkeypatch):
-        def broken(h, m):
+        def broken(p):
             raise RuntimeError("no matrices")
 
-        monkeypatch.setattr(gcm.gf128, "ghash_agg_matrices", broken)
+        monkeypatch.setattr(gcm.gf128, "ghash_level_table", broken)
         with pytest.raises(RuntimeError):
             gcm.make_context(os.urandom(32), b"aad", 4096)
         assert not gcm._BUILDS_IN_FLIGHT
@@ -341,4 +354,5 @@ def test_varz_carries_the_counters():
     assert set(varz["programs"]) == {"program_traces", "program_trace_seconds"}
     assert set(varz["gcm"]) == {
         "context_builds", "context_builds_duplicate", "context_build_seconds",
+        "key_tables_built", "key_table_hits",
     }

@@ -122,9 +122,6 @@ SANCTIONED_MATERIALIZERS = {
     "tieredstorage_tpu/transform/tpu.py:TpuTransformBackend._decrypt_window":
         "decrypt finish half: one fetch of plaintext+expected tags, "
         "verified host-side (the launch half is still checked upstream)",
-    "tieredstorage_tpu/ops/gcm.py:_derive_h":
-        "once-per-key host precompute of the GHASH key H, lru_cached - "
-        "never on the per-window path",
     "tieredstorage_tpu/ops/aes_bitsliced.py:_forced_crosscheck_ok":
         "one-time forced-Pallas output cross-check at first use, memoized",
     "tieredstorage_tpu/transform/batcher.py:WindowBatcher._flush_group":
@@ -426,7 +423,7 @@ def _scan_retrace(fn: _Fn, findings: list[Finding]) -> None:
             ))
         elif (
             last in ("GcmContext", "GcmVarlenContext",
-                     "_context_cached", "_varlen_context_cached")
+                     "_build_context", "_build_varlen_context")
             and fn.rel_path != "tieredstorage_tpu/ops/gcm.py"
         ):
             findings.append(Finding(

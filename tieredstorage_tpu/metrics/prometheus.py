@@ -191,7 +191,9 @@ class PrometheusExporter:
         them the exact counts of the work a span cannot see from inside:
         `programs` (traced and lowered in this process; a cold one-row
         program outlasts the index cache's default `get.timeout.ms`) and
-        `gcm` (context builds, duplicates among them, their seconds)."""
+        `gcm` (context builds, duplicates among them, their seconds, and
+        `key_tables_built` / `key_table_hits`: the builds that made their
+        segment key's H-power table and those that found it there)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}

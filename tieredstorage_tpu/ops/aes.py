@@ -101,10 +101,27 @@ def key_expansion(key: bytes) -> np.ndarray:
     return flat
 
 
+def aes_encrypt_block_host(round_keys: np.ndarray, block: np.ndarray) -> np.ndarray:
+    """One block's FIPS-197 encryption in numpy (uint8[16] in and out), for
+    the single block a key's set-up needs (GCM's H = E_K(0^128)) without a
+    device program; `aes_encrypt_blocks` is the same cipher on the device."""
+    state = block ^ round_keys[0]
+    for rnd in range(1, _NR + 1):
+        state = SBOX[state][_SHIFT_ROWS]
+        if rnd < _NR:  # MixColumns: out_r = 2*s_r ^ 3*s_{r+1} ^ s_{r+2} ^ s_{r+3}
+            s = state.reshape(4, 4)  # [col, row]
+            rot1 = np.roll(s, -1, axis=1)
+            state = (
+                _xtime(s) ^ _xtime(rot1) ^ rot1 ^ np.roll(s, -2, axis=1) ^ np.roll(s, -3, axis=1)
+            ).reshape(16)
+        state = state ^ round_keys[rnd]
+    return state
+
+
 # --- device-side cipher ---
 
-def _xtime(x: jnp.ndarray) -> jnp.ndarray:
-    """GF(2^8) doubling on uint8 arrays."""
+def _xtime(x):
+    """GF(2^8) doubling on uint8 arrays (jax or numpy)."""
     return ((x << 1) & 0xFF) ^ ((x >> 7) * 0x1B)
 
 

@@ -31,8 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from tieredstorage_tpu.ops import _preflight, gf128
-from tieredstorage_tpu.ops.aes import aes_encrypt_blocks, key_expansion
+from tieredstorage_tpu.ops.aes import aes_encrypt_block_host, key_expansion
 from tieredstorage_tpu.ops.aes_bitsliced import ctr_keystream_batch
+from tieredstorage_tpu.utils.caching import LoadingCache
 from tieredstorage_tpu.utils.locks import new_lock
 
 TAG_SIZE = 16
@@ -59,14 +60,16 @@ class GcmContext:
 
 # --- context-build accounting ---
 #
-# A context build runs only on an `lru_cache` miss, in pure Python (the
-# H-power matrices of gf128.ghash_agg_matrices), and the caches have no single
-# flight: concurrent first uses of one (key, aad, size) each build it, under
-# the interpreter lock, and each then takes as long as all of them together.
-# These counts see that exactly; they cost nothing on a hit. Guarded by
-# `_DISPATCH_MU`, with the launch counts further down.
+# A context is built only on a miss of its cache, on the host, and no device
+# program runs in a build. A segment key's H-power tables are built once
+# (`_KeyTable`) and every size's context is assembled from slices of them;
+# concurrent first uses of one key, or of one (key, aad, size), run one build
+# and the others wait for it. These counts see all of that exactly; they cost
+# nothing on a hit. Guarded by `_DISPATCH_MU`, with the launch counts further
+# down.
 _CONTEXT_STATS = {
     "context_builds": 0, "context_builds_duplicate": 0, "context_build_seconds": 0.0,
+    "key_tables_built": 0, "key_table_hits": 0,
 }
 _BUILDS_IN_FLIGHT: dict = {}  # identity -> builds running; under _DISPATCH_MU
 _CONTEXT_TLS = threading.local()
@@ -75,9 +78,11 @@ _CONTEXT_TLS = threading.local()
 def context_stats() -> dict:
     """`context_builds` (cache misses of `make_context` and
     `make_varlen_context`), `context_builds_duplicate` (those that started
-    while another build of the same key, aad and size was running) and
-    `context_build_seconds` (summed over the builds, so a pile-up of four
-    counts its wall time four times)."""
+    while another build of the same key, aad and size was running: single
+    flight keeps it 0), `context_build_seconds` (summed over the builds,
+    the key's table included in the build that made it), `key_tables_built`
+    (builds that made their key's H-power table) and `key_table_hits`
+    (builds assembled from a table that was already there)."""
     with _DISPATCH_MU:
         return dict(_CONTEXT_STATS)
 
@@ -113,23 +118,107 @@ def _counted_build(build):
     return counted
 
 
-@functools.lru_cache(maxsize=16)
-def _derive_h(key: bytes) -> tuple[np.ndarray, int]:
-    """Round keys and the GHASH key H = E_K(0^128) for an AES-256 key."""
-    round_keys = key_expansion(key)
-    h_block = np.asarray(
-        aes_encrypt_blocks(jnp.asarray(round_keys), jnp.zeros((1, 16), jnp.uint8))
-    )[0]
-    return round_keys, int.from_bytes(h_block.tobytes(), "big")
+class _CallerRuns:
+    """The executor of this module's caches: a miss builds in the thread that
+    missed (its thread-local build count says so), the waiters block on the
+    future it fills."""
+
+    def submit(self, fn, *args):
+        fn(*args)
 
 
-@functools.lru_cache(maxsize=64)
+def _single_flight_lru(entries: int) -> LoadingCache:
+    """A bounded cache whose concurrent first uses of a key run one build; a
+    build that raises releases its waiters with the error and leaves no
+    entry."""
+    return LoadingCache(executor=_CallerRuns(), max_weight=entries)
+
+
+class _KeyTable:
+    """What a segment key's contexts share, whatever their size and AAD: the
+    round keys, H = E_K(0^128), and per aggregation level L the k = 128
+    operand for base P_L = H^(128^(L-1)) (`gf128.ghash_level_table`), built
+    at the level's first use. The arrays are read-only: contexts hold views."""
+
+    def __init__(self, key: bytes) -> None:
+        self.round_keys = key_expansion(key)
+        self.h = int.from_bytes(
+            aes_encrypt_block_host(self.round_keys, np.zeros(16, np.uint8)).tobytes(),
+            "big",
+        )
+        self._mu = new_lock("gcm._KeyTable._mu")
+        self._next_base = self.h
+        #: per level (operand, carry): level 1's operand in the byte-plane
+        #: order of `gf128.ghash_agg_matrices`, int8[8, 128*16, 128], the
+        #: others int8[128*128, 128]; carry int8[128,128], the transposed
+        #: multiply matrix of the next level's base.
+        self._levels: list[tuple[np.ndarray, np.ndarray]] = []
+
+    def _level(self, index: int) -> tuple[np.ndarray, np.ndarray]:
+        with self._mu:
+            while len(self._levels) <= index:
+                mats, self._next_base = gf128.ghash_level_table(self._next_base)
+                carry = mats[128].copy()
+                if self._levels:
+                    operand = mats[:128].reshape(128 * 128, 128)
+                else:  # [slot, byte, bitpos, out] -> the 8 byte-bit planes
+                    operand = np.ascontiguousarray(
+                        mats[:128].reshape(128, 16, 8, 128)[:, :, ::-1].transpose(2, 0, 1, 3)
+                    ).reshape(8, 128 * 16, 128)
+                operand.flags.writeable = carry.flags.writeable = False
+                self._levels.append((operand, carry))
+            return self._levels[index]
+
+    def agg_mats(self, m: int) -> tuple:
+        """`gf128.ghash_agg_matrices(h, m)`: each level's trailing k slots."""
+        mats = []
+        for index, (k, _padded) in enumerate(gf128.ghash_agg_plan(m)):
+            operand = self._level(index)[0]
+            if index == 0:
+                mats.append(np.ascontiguousarray(operand[:, (128 - k) * 16:]))
+            else:
+                mats.append(operand[(128 - k) * 128:])
+        return tuple(mats)
+
+    def power_mat(self, exponent: int) -> np.ndarray:
+        """int8[128,128] transposed multiply-by-H^exponent matrix, for an
+        exponent up to 128 (`gf128.mult_matrix(gcm_pow(h, e)).T`)."""
+        planes, carry = self._level(0)
+        if exponent == 128:
+            return carry
+        rows = slice((127 - exponent) * 16, (128 - exponent) * 16)
+        # planes[kbit, s*16 + p] is the matrix's row p*8 + 7 - kbit
+        return np.ascontiguousarray(
+            planes[::-1, rows].transpose(1, 0, 2).reshape(128, 128)
+        )
+
+
+_KEY_TABLES = _single_flight_lru(16)
+_CONTEXTS = _single_flight_lru(64)
+_VARLEN_CONTEXTS = _single_flight_lru(64)
+
+
+def _key_table(key: bytes) -> _KeyTable:
+    """The key's table, counted as built by the caller or as already there."""
+    built = []
+
+    def build():
+        built.append(True)
+        return _KeyTable(key)
+
+    table = _KEY_TABLES.get(key, build)
+    with _DISPATCH_MU:
+        _CONTEXT_STATS["key_tables_built" if built else "key_table_hits"] += 1
+    return table
+
+
 @_counted_build
-def _context_cached(key: bytes, aad: bytes, chunk_bytes: int) -> GcmContext:
-    round_keys, h = _derive_h(key)
+def _build_context(key: bytes, aad: bytes, chunk_bytes: int) -> GcmContext:
+    table = _key_table(key)
+    h = table.h
 
     m_c = _ceil_div(chunk_bytes, 16)
-    agg_mats = gf128.ghash_agg_matrices(h, m_c)
+    agg_mats = table.agg_mats(m_c)
 
     # T(A) = sum_i A_i H^(mA-i) over the AAD blocks (zero-padded).
     aad_blocks = [aad[i : i + 16] for i in range(0, len(aad), 16)]
@@ -146,16 +235,15 @@ def _context_cached(key: bytes, aad: bytes, chunk_bytes: int) -> GcmContext:
     const = gf128.gcm_mult(t_a, gf128.gcm_pow(h, m_c + 2)) ^ gf128.gcm_mult(
         len_block, h
     )
-    final_mat = gf128.mult_matrix(gf128.gcm_mult(h, h))  # H^2
 
     return GcmContext(
-        round_keys=round_keys,
+        round_keys=table.round_keys,
         agg_mats=agg_mats,
-        final_mat=np.ascontiguousarray(final_mat.T.astype(np.int8)),
+        final_mat=table.power_mat(2),
         const_bits=gf128.int_to_bitvec(const),
         chunk_bytes=chunk_bytes,
         n_blocks=m_c,
-        step_mat=gf128.ghash_step_matrix(h, agg_mats[0].shape[1] // 16),
+        step_mat=table.power_mat(agg_mats[0].shape[1] // 16),
     )
 
 
@@ -164,7 +252,10 @@ def make_context(key: bytes, aad: bytes, chunk_bytes: int) -> GcmContext:
         raise ValueError("AES-256 key required")
     if chunk_bytes <= 0:
         raise ValueError("chunk_bytes must be positive")
-    return _context_cached(bytes(key), bytes(aad), chunk_bytes)
+    key, aad = bytes(key), bytes(aad)
+    return _CONTEXTS.get(
+        (key, aad, chunk_bytes), lambda: _build_context(key, aad, chunk_bytes)
+    )
 
 
 # --- device-side helpers ---
@@ -446,7 +537,7 @@ def planned_hbm_roundtrips(ctx, rows: int) -> int:
 # Device-resident copies of each context's constant arrays, uploaded once
 # per (context, mesh) instead of once per window call (the round keys, GHASH
 # level matrices, and folded constants are identical for every window of a
-# segment). Weak keying lets evicted lru_cache contexts free their HBM.
+# segment). Weak keying lets evicted contexts free their HBM.
 _DEVICE_CONSTS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
@@ -553,27 +644,26 @@ class GcmVarlenContext:
     step_mat: np.ndarray = None
 
 
-@functools.lru_cache(maxsize=64)
 @_counted_build
-def _varlen_context_cached(key: bytes, aad: bytes, max_bytes: int) -> GcmVarlenContext:
-    round_keys, h = _derive_h(key)
+def _build_varlen_context(key: bytes, aad: bytes, max_bytes: int) -> GcmVarlenContext:
+    table = _key_table(key)
     m_max = _ceil_div(max_bytes, 16)
     m_a = _ceil_div(len(aad), 16)
     seq_len = m_a + m_max + 1
     aad_padded = np.frombuffer(
         aad + b"\x00" * (m_a * 16 - len(aad)), dtype=np.uint8
     ).reshape(m_a, 16) if m_a else np.zeros((0, 16), np.uint8)
-    agg_mats = gf128.ghash_agg_matrices(h, seq_len)
+    agg_mats = table.agg_mats(seq_len)
     return GcmVarlenContext(
-        round_keys=round_keys,
+        round_keys=table.round_keys,
         aad_blocks=aad_padded,
         agg_mats=agg_mats,
-        h_mat=np.ascontiguousarray(gf128.mult_matrix(h).T.astype(np.int8)),
+        h_mat=table.power_mat(1),
         aad_bit_len=len(aad) * 8,
         max_bytes=max_bytes,
         m_max=m_max,
         m_cap=seq_len,
-        step_mat=gf128.ghash_step_matrix(h, agg_mats[0].shape[1] // 16),
+        step_mat=table.power_mat(agg_mats[0].shape[1] // 16),
     )
 
 
@@ -596,7 +686,10 @@ def bucket_max_bytes(n: int) -> int:
 def make_varlen_context(key: bytes, aad: bytes, max_bytes: int) -> GcmVarlenContext:
     if len(key) != 32:
         raise ValueError("AES-256 key required")
-    return _varlen_context_cached(bytes(key), bytes(aad), bucket_max_bytes(max_bytes))
+    key, aad, max_bytes = bytes(key), bytes(aad), bucket_max_bytes(max_bytes)
+    return _VARLEN_CONTEXTS.get(
+        (key, aad, max_bytes), lambda: _build_varlen_context(key, aad, max_bytes)
+    )
 
 
 @functools.partial(

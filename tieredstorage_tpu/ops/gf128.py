@@ -4,9 +4,11 @@ GHASH multiplication by a FIXED field element C is linear over GF(2), so it
 is exactly a 128x128 bit-matrix apply. The device-side GHASH reduction
 (ops/gcm.py) is a grouped-power contraction — each level multiplies up to
 128 slots by precomputed powers of H in one MXU matmul; this module builds
-the stacked per-level operands (ghash_agg_matrices, per segment key) so the
-entire reduction becomes int8 matmuls (mod 2) — no carryless-multiply
-instruction needed, which TPUs don't have.
+the stacked per-level operands so the entire reduction becomes int8 matmuls
+(mod 2) — no carryless-multiply instruction needed, which TPUs don't have.
+`ghash_level_table` is what ops/gcm.py builds them from, once per segment
+key and vectorised; `ghash_agg_matrices` builds one block count's operands
+element by element and is the tests' reference for it.
 
 Conventions: a field element is a 128-bit Python int whose bit i (from the
 MSB end) is the coefficient of x^i — i.e. int.from_bytes(block, "big") with
@@ -86,6 +88,36 @@ def mult_matrix(c: int) -> np.ndarray:
         m[:, i] = int_to_bitvec(col)
         col = mult_by_x(col)
     return m
+
+
+def mult_matrices_t(elements: list[int]) -> np.ndarray:
+    """int8[n,128,128]: entry e is ``mult_matrix(elements[e]).T``, all built
+    together. Row i of an element's matrix is c * x^i, and the shift-reduce
+    step from one row to the next is independent across elements, so the
+    128 steps run once for all n, each element carried as two uint64 halves."""
+    hi = np.array([c >> 64 for c in elements], dtype=np.uint64)
+    lo = np.array([c & 0xFFFFFFFFFFFFFFFF for c in elements], dtype=np.uint64)
+    rows = np.empty((len(elements), 128, 2), dtype=">u8")
+    r_hi, one, top = np.uint64(_R >> 64), np.uint64(1), np.uint64(63)
+    for i in range(128):
+        rows[:, i, 0] = hi
+        rows[:, i, 1] = lo
+        reduce = (lo & one) * r_hi
+        lo = (lo >> one) | (hi << top)
+        hi = (hi >> one) ^ reduce
+    return np.unpackbits(rows.view(np.uint8), axis=-1).view(np.int8)
+
+
+def ghash_level_table(p: int) -> tuple[np.ndarray, int]:
+    """One aggregation level's widest operand, for base p: int8[129,128,128]
+    whose entry j < 128 is the transposed multiply matrix of p^(127-j), slot
+    j of `ghash_agg_matrices`' k = 128 level, and whose entry 128 is that of
+    p^128, the next level's base, returned beside it. A level of k < 128
+    slots is the trailing k of the first 128, whatever the block count."""
+    powers = [1 << 127]  # the multiplicative identity
+    for _ in range(128):
+        powers.append(gcm_mult(powers[-1], p))
+    return mult_matrices_t(powers[127::-1] + powers[128:]), powers[128]
 
 
 def ghash_agg_plan(m: int, max_k: int = 128) -> list[tuple[int, int]]:
