@@ -178,6 +178,25 @@ def test_one_chunk_decrypt_program_compiles(one_chip, kernels_on):
     assert kernel_calls(compiled) == 2
 
 
+@pytest.mark.parametrize("varlen", [False, True], ids=["two_full_rows", "full_and_ragged_row"])
+def test_prefetch_sub_window_decrypt_programs_compile(one_chip, kernels_on, varlen):
+    """What a prefetching chunk cache launches beside the one-row program
+    (`prefetch.window.chunks` 2): two full rows at a segment's entry, and
+    the varlen pair where a segment's last two chunks are new together."""
+    if varlen:
+        ctx, args, static = varlen_args(2, CHUNK, rows_on=one_chip, consts_on=one_chip)
+        assert ctx.max_bytes == CHUNK
+    else:
+        args, static = fixed_args(2, CHUNK, rows_on=one_chip, consts_on=one_chip)
+    compiled = gcm._packed_jit(varlen, True, None).lower(
+        *args, **static, decrypt=True
+    ).compile()
+    assert kernel_calls(compiled) == 2
+    # what the hot tier would retain of it, and the program's temporaries
+    # beside a 4 GiB hot tier and a handful of such windows in flight
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_varlen_window_program_compiles_one_bucket_down(one_chip, kernels_on):
     """A compressed window: the varlen program one ladder bucket below
     4 MiB."""

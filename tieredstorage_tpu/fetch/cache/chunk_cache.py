@@ -110,6 +110,18 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         self._inflight_lock = new_lock("chunk_cache.ChunkCache._inflight_lock")
         #: Readers that joined another reader's in-flight chunk load.
         self.inflight_joins = 0
+        #: What the foreground found, per chunk asked of `get_chunks` (the
+        #: prefetch tasks' own lookups are not reads): `reads` in all,
+        #: `read_hits` served from the cache with no wait, `read_joins`
+        #: that waited on a load in flight (the reader caught the
+        #: prefetcher); the rest owned their load. And what the prefetch
+        #: tasks loaded themselves: `prefetch_windows` delegate calls of
+        #: `prefetch_rows` chunks. Bumped under `_inflight_lock`, exact.
+        self.reads = 0
+        self.read_hits = 0
+        self.read_joins = 0
+        self.prefetch_windows = 0
+        self.prefetch_rows = 0
 
     # ------------------------------------------------------------------ setup
     def configure(self, configs: Mapping[str, Any]) -> None:
@@ -145,6 +157,24 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
     def executor(self) -> ThreadPoolExecutor:
         return self._executor
 
+    def counters(self) -> dict[str, int]:
+        """The `/varz` `chunk_cache` section: the foreground's reads by
+        outcome (`misses` joined a load in flight or owned one), every join
+        (prefetch tasks joining each other included), the failures that
+        were absorbed, and the prefetch tasks' own loads."""
+        with self._inflight_lock:
+            return {
+                "reads": self.reads,
+                "hits": self.read_hits,
+                "misses": self.reads - self.read_hits,
+                "read_joins": self.read_joins,
+                "inflight_joins": self.inflight_joins,
+                "degradations": self.degradations,
+                "prefetch_failures": self.prefetch_failures,
+                "prefetch_windows": self.prefetch_windows,
+                "prefetch_rows": self.prefetch_rows,
+            }
+
     def close(self) -> None:
         # Drain in-flight loads before returning: callers close the transform
         # backend right after, and a loader thread must not reach a closed
@@ -175,14 +205,15 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         if not chunk_ids:
             return []
         start = time.monotonic()
-        with self.tracer.span("cache.get_chunks", chunks=len(chunk_ids)):
-            out = self._get_chunks_timed(objects_key, manifest, chunk_ids)
+        with self.tracer.span("cache.get_chunks", chunks=len(chunk_ids)) as span:
+            out = self._get_chunks_timed(objects_key, manifest, chunk_ids, span)
         if self.on_get is not None:
             self.on_get((time.monotonic() - start) * 1000.0)
         return out
 
     def _get_chunks_timed(
-        self, objects_key: ObjectKey, manifest: SegmentManifestV1, chunk_ids: Sequence[int]
+        self, objects_key: ObjectKey, manifest: SegmentManifestV1,
+        chunk_ids: Sequence[int], span=None,
     ) -> list[bytes]:
         # The window wait is bounded by the tighter of `get.timeout.ms` and
         # the ambient end-to-end Deadline; an already-expired deadline fails
@@ -193,7 +224,12 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         if ambient is not None:
             deadline = min(deadline, time.monotonic() + ambient)
         self._start_prefetching(objects_key, manifest, chunk_ids[-1])
-        futures = self._populate_window(objects_key, manifest, chunk_ids, deadline)
+        futures, own = self._populate_window(objects_key, manifest, chunk_ids, deadline)
+        if span is not None:
+            joined = sum(kind == "bytes" for kind, _ in futures.values())
+            span.attributes.update(
+                hits=len(chunk_ids) - joined - len(own), joined=joined, owned=len(own),
+            )
         out: dict[int, bytes] = {}
         fallback: list[int] = []
         for cid in chunk_ids:
@@ -206,9 +242,10 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
                 # THIS read — degrade to a direct fetch, where the
                 # authoritative error (if any) surfaces on our own call.
                 try:
-                    out[cid] = self._await(future, deadline, cid, objects_key)
+                    with self.tracer.span("cache.join_wait", chunk=cid):
+                        out[cid] = self._await(future, deadline, cid, objects_key)
                 except ChunkCacheTimeoutException:
-                    self.degradations += 1
+                    self._degraded(chunk_key, "join_timeout")
                     fallback.append(cid)
                 except Exception:
                     fallback.append(cid)
@@ -219,7 +256,7 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
                 # Another reader's wedged population (the delegate fetch of
                 # THIS window is bounded separately in _populate_window) must
                 # not fail this read: degrade to a direct fetch.
-                self.degradations += 1
+                self._degraded(chunk_key, "load_timeout")
                 fallback.append(cid)
                 continue
             except OSError:
@@ -229,7 +266,7 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
                 log.warning("Chunk cache store failed for %s; bypassing cache",
                             chunk_key, exc_info=True)
                 self._cache.invalidate(chunk_key)
-                self.degradations += 1
+                self._degraded(chunk_key, "store_failed")
                 fallback.append(cid)
                 continue
             try:
@@ -237,7 +274,7 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
             except OSError:
                 log.warning("Chunk cache read failed for %s; bypassing cache",
                             chunk_key, exc_info=True)
-                self.degradations += 1
+                self._degraded(chunk_key, "read_failed")
                 data = None
             if data is None:  # evicted + unlinked between resolve and open
                 self._cache.invalidate(chunk_key)
@@ -253,6 +290,12 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
             refetched = self._delegate.get_chunks(objects_key, manifest, fallback)
             out.update(zip(fallback, refetched))
         return [out[cid] for cid in chunk_ids]
+
+    def _degraded(self, chunk_key: ChunkKey, cause: str) -> None:
+        """A cache failure bypassed by a direct fetch: counted, and an event
+        in the trace beside the read it slowed."""
+        self.degradations += 1
+        self.tracer.event("cache.degradation", chunk=chunk_key.path, cause=cause)
 
     def _await(self, future, deadline: float, cid: int, objects_key: ObjectKey) -> T:
         try:
@@ -275,7 +318,7 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         manifest: SegmentManifestV1,
         chunk_ids: Sequence[int],
         deadline: Optional[float],
-    ) -> dict[int, tuple[str, "concurrent.futures.Future"]]:
+    ) -> tuple[dict[int, tuple[str, "concurrent.futures.Future"]], list[int]]:
         """Batch-fetch every not-yet-cached, not-yet-in-flight chunk of the
         window with ONE delegate call, then register per-chunk cache loaders
         that only persist the already-fetched bytes (no network under an
@@ -283,7 +326,8 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         chunks and cid -> ("bytes", Future[bytes]) for chunks joined from
         another reader's in-flight load (single-flight: the prefetch and
         concurrent readers share one fetch+detransform per chunk; joiners
-        never wait on more than the owner's sub-window).
+        never wait on more than the owner's sub-window), and the chunk ids
+        whose load this call owned.
 
         With a deadline (synchronous reads) the delegate fetch runs on the
         pool and is awaited with the remaining budget, so `get.timeout.ms`
@@ -304,18 +348,24 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
         if len(chunk_ids) > len(missing):
             flight.note("tier.chunk_cache", len(chunk_ids) - len(missing))
         own: list[int] = []
-        if missing:
-            with self._inflight_lock:
-                for cid in missing:
-                    key = ChunkKey.of(objects_key, cid)
-                    in_flight = self._inflight.get(key)
-                    if in_flight is not None:
-                        futures[cid] = ("bytes", in_flight)
-                        self.inflight_joins += 1
-                    else:
-                        self._inflight[key] = concurrent.futures.Future()
-                        own.append(cid)
-        joined = len(missing) - len(own)
+        with self._inflight_lock:
+            for cid in missing:
+                key = ChunkKey.of(objects_key, cid)
+                in_flight = self._inflight.get(key)
+                if in_flight is not None:
+                    futures[cid] = ("bytes", in_flight)
+                    self.inflight_joins += 1
+                else:
+                    self._inflight[key] = concurrent.futures.Future()
+                    own.append(cid)
+            joined = len(missing) - len(own)
+            if deadline is not None:
+                self.reads += len(chunk_ids)
+                self.read_hits += len(chunk_ids) - len(missing)
+                self.read_joins += joined
+            elif own:
+                self.prefetch_windows += 1
+                self.prefetch_rows += len(own)
         if joined:
             flight.note("tier.inflight_join", joined)
         if own:
@@ -352,7 +402,7 @@ class ChunkCache(ChunkManager, Generic[T], abc.ABC):
                     raise ChunkCacheTimeoutException(
                         f"Fetching chunks {own} of {objects_key} timed out"
                     ) from None
-        return futures
+        return futures, own
 
     def _load_owned_bound(
         self, record, traceparent, work_class, speculative,

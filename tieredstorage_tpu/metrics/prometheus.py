@@ -137,10 +137,13 @@ class PrometheusExporter:
     """Serves /metrics, /healthz, and /varz for one or more registries on
     127.0.0.1:<port>; pass `tracer` to surface its latency summary on /varz
     and `flight_recorder` for the flight section (requests seen, slow-ring
-    occupancy, top-3 slowest with tier breakdown) next to it."""
+    occupancy, top-3 slowest with tier breakdown) next to it, and
+    `chunk_cache` (the RSM's chunk cache tier, if it has one) for that
+    tier's exact counts."""
 
     def __init__(self, registries: Iterable[MetricsRegistry], *, port: int = 0,
-                 host: str = "127.0.0.1", tracer=None, flight_recorder=None):
+                 host: str = "127.0.0.1", tracer=None, flight_recorder=None,
+                 chunk_cache=None):
         regs = list(registries)
         outer = self
 
@@ -176,6 +179,7 @@ class PrometheusExporter:
         self.registries = regs
         self.tracer = tracer
         self.flight_recorder = flight_recorder
+        self.chunk_cache = chunk_cache
         self._server = ThreadingHTTPServer((host, port), Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(
@@ -193,7 +197,11 @@ class PrometheusExporter:
         program outlasts the index cache's default `get.timeout.ms`) and
         `gcm` (context builds, duplicates among them, their seconds, and
         `key_tables_built` / `key_table_hits`: the builds that made their
-        segment key's H-power table and those that found it there)."""
+        segment key's H-power table and those that found it there), and
+        `chunk_cache` where the deployment has that tier (`ChunkCache.
+        counters()`: the foreground's reads, hits and misses, joins of
+        loads in flight, degradations, prefetch failures, and the prefetch
+        tasks' own windows and rows)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}
@@ -209,6 +217,11 @@ class PrometheusExporter:
         gcm = sys.modules.get("tieredstorage_tpu.ops.gcm")
         if gcm is not None:
             out["gcm"] = gcm.context_stats()
+        cache = self.chunk_cache
+        out["chunk_cache"] = (
+            {"enabled": True, **cache.counters()} if cache is not None
+            else {"enabled": False}
+        )
         recorder = self.flight_recorder
         out["flight"] = (
             recorder.summary() if recorder is not None else {"enabled": False}

@@ -397,6 +397,12 @@ class RemoteStorageManager:
         return self._transform_backend
 
     @property
+    def chunk_cache(self) -> Optional[ChunkCache]:
+        """The chunk cache tier, or None when `fetch.chunk.cache.class` is
+        unset; its `counters()` are `/varz`'s `chunk_cache` section."""
+        return self._chunk_cache_tier(self._chunk_manager)
+
+    @property
     def device_hot_cache(self):
         """The device hot-window tier, or None when `cache.device.bytes`
         is 0 (fetch/cache/device_hot.py)."""
@@ -746,7 +752,7 @@ class RemoteStorageManager:
                 ),
             ))
         floor = config.slo_cache_hit_floor_percent
-        chunk_cache = self._chunk_cache_tier(self._chunk_manager)
+        chunk_cache = self.chunk_cache
         if floor > 0 and chunk_cache is not None:
             stats = chunk_cache.stats
             specs.append(SloSpec(
@@ -949,7 +955,7 @@ class RemoteStorageManager:
         if inner is not None:
             inner.tracer = self.tracer
             inner.on_fetch = self._metrics.record_chunk_fetch
-        cache = self._chunk_cache_tier(cm)
+        cache = self.chunk_cache
         if cache is not None:
             cache.tracer = self.tracer
             cache.on_get = self._metrics.record_cache_get
@@ -1018,7 +1024,7 @@ class RemoteStorageManager:
         return storage
 
     def _register_resilience_metrics(self) -> None:
-        chunk_cache = self._chunk_cache_tier(self._chunk_manager)
+        chunk_cache = self.chunk_cache
         register_resilience_metrics(
             self._metrics.registry,
             breaker=self._breaker,
@@ -1061,7 +1067,7 @@ class RemoteStorageManager:
             size_supplier=lambda: self._indexes_cache.size,
             weight_supplier=lambda: self._indexes_cache.total_weight,
         )
-        chunk_cache = self._chunk_cache_tier(self._chunk_manager)
+        chunk_cache = self.chunk_cache
         if chunk_cache is not None and hasattr(chunk_cache, "stats"):
             register_cache_metrics(
                 registry, "chunk-cache", chunk_cache.stats,

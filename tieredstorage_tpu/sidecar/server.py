@@ -310,10 +310,12 @@ def main(argv: Optional[list[str]] = None) -> None:
         # loopback-only sidecar must not expose metrics network-wide.
         # The RSM's tracer rides along so /varz serves the span summary
         # (p50/p95/p99 per name) next to /metrics and /healthz; the flight
-        # recorder adds the per-request `flight` section (ISSUE 14).
+        # recorder adds the per-request `flight` section (ISSUE 14), the
+        # chunk cache tier its `chunk_cache` counts.
         exporter = PrometheusExporter(
             [rsm.metrics.registry], port=args.metrics_port, host=args.host,
             tracer=rsm.tracer, flight_recorder=rsm.flight_recorder,
+            chunk_cache=rsm.chunk_cache,
         ).start()
     gateway = None
     if args.http_port is not None:

@@ -117,6 +117,10 @@ class DispatchStats:
     thread's launches never land in this window's count."""
 
     windows: int = 0
+    #: Chunk rows of those windows, before any mesh padding: over `windows`
+    #: it is the mean window height (a prefetching chunk cache's decrypt
+    #: windows read 1-2 rows where an upload's read 16).
+    rows: int = 0
     dispatches: int = 0
     h2d_transfers: int = 0
     d2h_fetches: int = 0
@@ -275,12 +279,13 @@ class TpuTransformBackend(TransformBackend):
         batcher = self.batcher
         return (0, 0.0, 0) if batcher is None else batcher.thread_evidence()
 
-    def _note_batched_window(self, n_bytes: int) -> None:
+    def _note_batched_window(self, n_bytes: int, rows: int) -> None:
         """Window accounting for a batched window — either direction (the
         flusher launches; every coalesced window still counts, so
         `dispatches_per_window` reads `launches/windows <= 1/occupancy`)."""
         with self._stats_lock:
             self.dispatch_stats.windows += 1
+            self.dispatch_stats.rows += rows
             self.dispatch_stats.bytes_in += n_bytes
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
@@ -566,6 +571,7 @@ class TpuTransformBackend(TransformBackend):
         out = self._launch_packed(ctx, staged, varlen, decrypt=False)
         with self._stats_lock:
             self.dispatch_stats.windows += 1
+            self.dispatch_stats.rows += len(sizes)
             self.dispatch_stats.bytes_in += sum(sizes)
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
         return ivs, sizes, n_bytes, out
@@ -672,6 +678,7 @@ class TpuTransformBackend(TransformBackend):
         out = self._launch_packed(ctx, staged, varlen, decrypt=True)
         with self._stats_lock:
             self.dispatch_stats.windows += 1
+            self.dispatch_stats.rows += len(sizes)
             self.dispatch_stats.bytes_in += sum(sizes)
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
