@@ -471,6 +471,52 @@ class TestDeviceCapture:
         assert not w.device.is_deleted()
         assert np.asarray(w.device)[0, :ENC_CHUNK].tobytes() == chunks[0]
 
+    @pytest.mark.parametrize("aligned", [False, True])
+    def test_retained_window_survives_reuse_of_its_staging_buffer(self, aligned):
+        """The backend stages every window of a shape in one reused host
+        buffer (its staging ring). A window the hot tier retained must
+        still read back its plaintext after that buffer has been packed
+        with another chunk's ciphertext. `aligned` seeds the ring with a
+        64-byte-aligned buffer, which the CPU backend's `device_put`
+        places with no copy: the staged array then IS the host buffer, and
+        only the retained OUTPUT allocation keeps the tier safe."""
+        chunks, backend, default, manifest = encrypted_store()
+        hot = DeviceHotCache(
+            default, backend, innermost=default, budget_bytes=1 << 30,
+            admission_hits=1,
+        )
+        shape = (1, ENC_CHUNK + 16)
+        if aligned:
+            raw = np.empty(shape[1] + 64, np.uint8)
+            offset = (-raw.ctypes.data) % 64
+            backend._release_staging(raw[offset : offset + shape[1]].reshape(shape))
+        backend.reset_dispatch_stats()
+        real_stage, zero_copy = backend._stage_packed, []
+
+        def spy_stage(packed, varlen):
+            staged = real_stage(packed, varlen)
+            zero_copy.append(staged.unsafe_buffer_pointer() == packed.ctypes.data)
+            return staged
+
+        backend._stage_packed = spy_stage
+        assert hot.get_chunks(KEY, manifest, [0]) == chunks[:1]
+        retained = hot.window(KEY, 0)
+        assert retained.device is not None
+        for cid in range(1, 6):  # the same one-row shape, the same buffer
+            assert hot.get_chunks(KEY, manifest, [cid]) == [chunks[cid]]
+        stats = backend.dispatch_stats
+        assert stats.staging_acquired == 6
+        assert stats.staging_reused == (6 if aligned else 5)
+        assert len(backend._staging_free[shape]) == 1
+        if aligned and jax.default_backend() == "cpu":
+            assert zero_copy == [True] * 6  # the hazard was really there
+        assert not retained.device.is_deleted()
+        assert np.asarray(retained.device)[0, :ENC_CHUNK].tobytes() == chunks[0]
+        for cid in range(6):
+            (row,) = hot.device_rows(KEY, [cid])
+            assert np.asarray(row)[:ENC_CHUNK].tobytes() == chunks[cid]
+        assert hot.get_chunks(KEY, manifest, [0]) == chunks[:1]
+
     def test_device_rows_match_mirror(self):
         chunks, backend, default, manifest = encrypted_store()
         hot = DeviceHotCache(

@@ -4,6 +4,8 @@ sharding on the virtual CPU mesh, tag verification, full RSM lifecycle."""
 from __future__ import annotations
 
 import random
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -306,3 +308,241 @@ class TestPipelinedWindows:
             f"wall={wall:.3f}s vs serial={serial:.3f}s "
             f"(overlap nominal {overlapped:.3f}s)"
         )
+
+
+# --------------------------------------------------- host staging buffers
+def _rand(rng, size):
+    return bytes(rng.getrandbits(8) for _ in range(size))
+
+
+def _aligned_buffer(shape):
+    """A 64-byte-aligned staging buffer: what the CPU backend's `device_put`
+    places with no copy, so the staged array IS the host buffer."""
+    n = shape[0] * shape[1]
+    raw = np.empty(n + 64, np.uint8)
+    offset = (-raw.ctypes.data) % 64
+    return raw[offset : offset + n].reshape(shape)
+
+
+#: One use of a case: the `transform` calls it makes, each a list of chunk
+#: sizes. `use` is the 0-based repetition, so a case can stage rows shorter
+#: than the rows its reused buffer last held.
+STAGING_CASES = {
+    # (a) a fixed 16-row window
+    "fixed_16_rows": lambda use: [[CHUNK] * 16],
+    # (b) a ragged window; the odd uses' rows are shorter than the rows the
+    # even uses left in the buffer (same ladder bucket, so the same shape)
+    "ragged_rows": lambda use: [
+        [CHUNK, CHUNK - 7, CHUNK - 300, 900] if use % 2 == 0
+        else [CHUNK, 133, 17, 1]
+    ],
+    # (c) a one-row window
+    "one_row": lambda use: [[CHUNK]],
+    # (d) the four index rows of a segment: four one-row windows
+    "index_rows": lambda use: [[88], [1024], [40], [312]],
+}
+
+
+@pytest.mark.parametrize("case", list(STAGING_CASES))
+class TestStagingRingBytes:
+    USES = 5
+
+    def _windows(self, case, use, rng):
+        return [
+            [_rand(rng, size) for size in sizes]
+            for sizes in STAGING_CASES[case](use)
+        ]
+
+    def test_wire_identical_to_cpu_on_first_and_reused_buffer(self, key_pair, case):
+        rng = random.Random(30)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        for use in range(self.USES):
+            for window in self._windows(case, use, rng):
+                opts = TransformOptions(
+                    encryption=key_pair, ivs=det_ivs(len(window))
+                )
+                assert tpu.transform(window, opts) == cpu.transform(window, opts), (
+                    f"use {use}"
+                )
+            windows = len(STAGING_CASES[case](use))
+            stats = tpu.dispatch_stats
+            assert stats.staging_acquired == (use + 1) * windows
+            # the first use of a shape allocates, every later one reuses
+            assert stats.staging_reused == use * windows
+
+    def test_decrypt_round_trips_and_altered_tag_raises(self, key_pair, case):
+        rng = random.Random(31)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        d_opts = DetransformOptions(encryption=key_pair)
+        for use in range(self.USES):
+            for window in self._windows(case, use, rng):
+                wire = cpu.transform(window, TransformOptions(encryption=key_pair))
+                assert tpu.detransform(wire, d_opts) == window, f"use {use}"
+                bad = bytearray(wire[-1])
+                bad[-1] ^= 0x01
+                with pytest.raises(AuthenticationError):
+                    tpu.detransform(wire[:-1] + [bytes(bad)], d_opts)
+                # the refused window kept its buffer: the next is still right
+                assert tpu.detransform(wire, d_opts) == window
+        stats = tpu.dispatch_stats
+        assert stats.staging_acquired == stats.windows
+        assert 0 < stats.staging_reused < stats.staging_acquired
+
+
+class TestStagingRing:
+    def _window(self, rng, rows=4):
+        return [_rand(rng, CHUNK) for _ in range(rows)]
+
+    def test_windows_in_flight_hold_distinct_buffers(self, key_pair):
+        rng = random.Random(32)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        in_flight = tpu.pipeline_depth + 1
+        for round_ in range(2):
+            windows = [self._window(rng) for _ in range(in_flight)]
+            opts = TransformOptions(encryption=key_pair, ivs=det_ivs(4))
+            staged = [tpu._encrypt_dispatch(w, opts) for w in windows]
+            buffers = [s[4][0] for s in staged]
+            assert len({id(b) for b in buffers}) == in_flight
+            for i, a in enumerate(buffers):
+                assert not any(np.shares_memory(a, b) for b in buffers[i + 1:])
+            if round_:  # the second round's are the first round's, returned
+                assert {id(b) for b in buffers} == first
+            first = {id(b) for b in buffers}
+            for w, s in zip(windows, staged):
+                assert tpu._encrypt_finish(s) == cpu.transform(w, opts)
+        assert tpu.dispatch_stats.staging_acquired == 2 * in_flight
+        assert tpu.dispatch_stats.staging_reused == in_flight
+
+    def test_window_finished_twice_returns_its_buffer_once(self, key_pair):
+        tpu = TpuTransformBackend()
+        opts = TransformOptions(encryption=key_pair, ivs=det_ivs(4))
+        staged = tpu._encrypt_dispatch(self._window(random.Random(33)), opts)
+        assert tpu._encrypt_finish(staged) == tpu._encrypt_finish(staged)
+        assert [len(v) for v in tpu._staging_free.values()] == [1]
+
+    def test_abandoned_generator_leaks_nothing_into_a_later_window(self, key_pair):
+        rng = random.Random(34)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        windows = [self._window(rng) for _ in range(6)]
+        opts = TransformOptions(encryption=key_pair, ivs=det_ivs(24))
+        gen = tpu.transform_windows(iter(windows), opts)
+        first = next(gen)  # windows 0..3 dispatched, window 0 finished
+        gen.close()
+        assert first == cpu.transform(windows[0], opts)
+        # the three windows in flight never gave their buffers back
+        assert [len(v) for v in tpu._staging_free.values()] == [1]
+        assert tpu.dispatch_stats.staging_acquired == 4
+        for _ in range(3):
+            later = self._window(rng)
+            later_opts = TransformOptions(encryption=key_pair, ivs=det_ivs(4))
+            assert tpu.transform(later, later_opts) == cpu.transform(later, later_opts)
+        assert tpu.dispatch_stats.staging_reused == 3
+
+    def test_eight_threads_share_one_backend(self, key_pair):
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        d_opts = DetransformOptions(encryption=key_pair)
+        errors: list = []
+        start = threading.Barrier(8)
+
+        def work(seed):
+            rng = random.Random(seed)
+            try:
+                start.wait()
+                for use in range(6):
+                    sizes = [CHUNK] * 4 if use % 2 else [CHUNK, 500 + seed, 64, 9]
+                    window = [_rand(rng, s) for s in sizes]
+                    opts = TransformOptions(encryption=key_pair, ivs=det_ivs(4))
+                    wire = tpu.transform(window, opts)
+                    assert wire == cpu.transform(window, opts)
+                    assert tpu.detransform(wire, d_opts) == window
+            except Exception as exc:  # noqa: BLE001 - reported below
+                errors.append(exc)
+
+        threads = [threading.Thread(target=work, args=(s,)) for s in range(8)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)  # hand over inside acquire and release too
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        stats = tpu.dispatch_stats
+        assert stats.staging_acquired == stats.windows == 8 * 6 * 2
+        # at most eight windows of the one shape were ever in flight
+        assert stats.staging_acquired - stats.staging_reused <= 8
+        assert sum(len(v) for v in tpu._staging_free.values()) <= 8
+
+    def test_free_list_never_exceeds_its_byte_bound(self, key_pair):
+        tpu = TpuTransformBackend()
+        tpu.pipeline_depth, tpu.preferred_batch_bytes = 1, 4096
+        bound = tpu._staging_bound()
+        assert bound == 2 * 2 * 4096
+        for size in [3000, 2000, 1500, 1024, 3000, 2500, 4000, 600, 3000]:
+            # two windows of the shape in flight, returned one after the other
+            held = [tpu._acquire_staging((2, size + 16)) for _ in range(2)]
+            for packed in held:
+                tpu._release_staging(packed)
+                free = tpu._staging_free
+                assert tpu._staging_free_bytes == sum(
+                    b.nbytes for bufs in free.values() for b in bufs
+                )
+                assert tpu._staging_free_bytes <= bound
+                # the shape just returned stays; older shapes gave way
+                assert next(reversed(free)) == (2, size + 16)
+        assert len(tpu._staging_free) < 7
+        # a window larger than the whole bound is staged but not kept at all
+        huge = [_rand(random.Random(35), bound)]
+        wire = tpu.transform(huge, TransformOptions(encryption=key_pair))
+        assert tpu.detransform(wire, DetransformOptions(encryption=key_pair)) == huge
+        assert tpu._staging_free_bytes == 0 and not tpu._staging_free
+
+    def test_counters_in_as_dict_and_reset(self, key_pair):
+        tpu = TpuTransformBackend()
+        window = self._window(random.Random(36))
+        for _ in range(3):
+            tpu.transform(window, TransformOptions(encryption=key_pair))
+        as_dict = tpu.dispatch_stats.as_dict()
+        assert (as_dict["staging_acquired"], as_dict["staging_reused"]) == (3, 2)
+        retired = tpu.reset_dispatch_stats()
+        assert (retired.staging_acquired, retired.staging_reused) == (3, 2)
+        tpu.transform(window, TransformOptions(encryption=key_pair))
+        # fresh counters, the same ring
+        assert tpu.dispatch_stats.staging_acquired == 1
+        assert tpu.dispatch_stats.staging_reused == 1
+
+    @pytest.mark.parametrize("aligned", [False, True])
+    def test_dirty_buffer_of_a_longer_window_is_fully_rewritten(self, key_pair, aligned):
+        """A ring buffer full of 0xFF (every tail byte, the IV and length
+        columns dirty) has to give the bytes a zeroed one gives. `aligned`
+        is the zero-copy placement: the staged array then is the buffer."""
+        import jax
+
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        rng = random.Random(37)
+        real_stage, zero_copy = tpu._stage_packed, []
+
+        def spy_stage(packed, varlen):
+            staged = real_stage(packed, varlen)
+            zero_copy.append(staged.unsafe_buffer_pointer() == packed.ctypes.data)
+            return staged
+
+        tpu._stage_packed = spy_stage
+        for sizes in ([CHUNK] * 4, [CHUNK, 133, 17, 1], [700]):
+            n_bytes = CHUNK if len(sizes) > 1 else 700
+            shape = (len(sizes), n_bytes + 16)
+            dirty = _aligned_buffer(shape) if aligned else np.empty(shape, np.uint8)
+            dirty[:] = 0xFF
+            tpu._release_staging(dirty)
+            window = [_rand(rng, s) for s in sizes]
+            opts = TransformOptions(encryption=key_pair, ivs=det_ivs(len(sizes)))
+            reused = tpu.dispatch_stats.staging_reused
+            wire = tpu.transform(window, opts)
+            assert tpu.dispatch_stats.staging_reused == reused + 1
+            assert wire == cpu.transform(window, opts)
+            assert tpu.detransform(wire, DetransformOptions(encryption=key_pair)) == window
+        if aligned and jax.default_backend() == "cpu":
+            assert all(zero_copy)  # the staged arrays were the ring's buffers

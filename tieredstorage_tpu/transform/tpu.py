@@ -18,6 +18,7 @@ IV || ciphertext || tag.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import hmac
@@ -114,7 +115,15 @@ class DispatchStats:
     the guarded-by race checker infers and enforces the guard, and the
     RaceWitness cross-validates it under `make chaos`/`make fleet-demo`);
     launch deltas come from `ops.gcm.thread_dispatches()` so a sibling
-    thread's launches never land in this window's count."""
+    thread's launches never land in this window's count.
+
+    `staging_acquired` and `staging_reused` count the host side of the
+    staging transfer: every window `_build_packed` fills takes one host
+    buffer of its shape, from the backend's ring of reused buffers when it
+    holds one (`staging_reused`), freshly allocated otherwise. A buffer goes
+    back to the ring only when its window is over: in `_encrypt_finish` once
+    the wire chunks are built, at the end of `_decrypt_window` once the
+    plaintext is. A window that is abandoned never returns its buffer."""
 
     windows: int = 0
     #: Chunk rows of those windows, before any mesh padding: over `windows`
@@ -140,6 +149,10 @@ class DispatchStats:
     #: count — keeps the one-dispatch invariant testable at any mesh size.
     mesh_size: int = 1
     rows_per_device: int = 0
+    #: Host staging buffers handed to `_build_packed`, and those of them
+    #: that came from the ring, mapped and touched by an earlier window.
+    staging_acquired: int = 0
+    staging_reused: int = 0
 
     @property
     def dispatches_per_window(self) -> float:
@@ -200,6 +213,13 @@ class TpuTransformBackend(TransformBackend):
         self._pool: Optional[ThreadPoolExecutor] = None
         self._stats_lock = new_lock("tpu.TpuTransformBackend._stats_lock")
         self.dispatch_stats = DispatchStats()
+        #: The ring of host staging buffers: free `uint8[rows, n_bytes + 16]`
+        #: arrays by shape, the shape returned longest ago first. Guarded by
+        #: `_stats_lock`, bounded by `_staging_bound()` bytes.
+        self._staging_free: "collections.OrderedDict[tuple, list]" = (
+            collections.OrderedDict()
+        )
+        self._staging_free_bytes = 0
         #: Cross-request decrypt batcher (transform/batcher.py), built by
         #: `configure()` from `transform.batch.enabled` or explicitly via
         #: `enable_batching()`; None = every window dispatches unbatched.
@@ -352,9 +372,6 @@ class TpuTransformBackend(TransformBackend):
             for window in windows:
                 yield self.transform(window, opts)
             return
-        import collections
-        import dataclasses
-
         pending: "collections.deque" = collections.deque()
         iv_offset = 0
         for window in windows:
@@ -467,6 +484,51 @@ class TpuTransformBackend(TransformBackend):
                 span.attributes["built"] = gcm_ops.thread_context_builds() > builds
         return ctx, n_bytes, varlen
 
+    def _staging_bound(self) -> int:
+        """Bytes the ring may hold free: the `pipeline_depth + 1` windows a
+        stream keeps in flight, at the window byte cap, twice over for the
+        other shapes a deployment stages beside its full window (a ragged
+        or one-row window, the index rows)."""
+        return 2 * (self.pipeline_depth + 1) * self.preferred_batch_bytes
+
+    def _acquire_staging(self, shape: tuple) -> np.ndarray:
+        """A host buffer for one packed window: the ring's, already mapped
+        and dirty with an earlier window of this shape, or a new one that
+        the first pass over it has to fault in."""
+        packed = None
+        with self._stats_lock:
+            self.dispatch_stats.staging_acquired += 1
+            free = self._staging_free.get(shape)
+            if free:
+                packed = free.pop()
+                if not free:
+                    del self._staging_free[shape]
+                self._staging_free_bytes -= packed.nbytes
+                self.dispatch_stats.staging_reused += 1
+            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+            note_mutation("tpu.TpuTransformBackend._staging_free")
+        return np.empty(shape, dtype=np.uint8) if packed is None else packed
+
+    def _release_staging(self, packed: np.ndarray) -> None:
+        """Hand a finished window's buffer back to the ring. Only once the
+        program that read the staged window has run and the host has what
+        it needs of the output: until then the host->device copy may still
+        be reading the buffer (and where a placement is zero-copy, as the
+        CPU backend's is for an aligned array, the staged array IS the
+        buffer). Over the bound the shapes returned longest ago give way
+        first."""
+        bound = self._staging_bound()
+        with self._stats_lock:
+            self._staging_free.setdefault(packed.shape, []).append(packed)
+            self._staging_free.move_to_end(packed.shape)
+            self._staging_free_bytes += packed.nbytes
+            while self._staging_free_bytes > bound:
+                shape, oldest = next(iter(self._staging_free.items()))
+                self._staging_free_bytes -= oldest.pop(0).nbytes
+                if not oldest:
+                    del self._staging_free[shape]
+            note_mutation("tpu.TpuTransformBackend._staging_free")
+
     def _build_packed(
         self, payloads: list, sizes: list[int], ivs: np.ndarray, n_bytes: int,
         varlen: bool,
@@ -475,16 +537,20 @@ class TpuTransformBackend(TransformBackend):
         payload rows (zero tail — varlen GHASH requires it) with the
         per-row metadata the fused kernel reads from the tail columns
         ([iv 12 B][length u32 LE 4 B]), so the whole window crosses the
-        host→device link as a single buffer."""
+        host→device link as a single buffer. The buffer is the ring's
+        (`_acquire_staging`) and may be dirty: every byte the program reads
+        is written here, a short row's tail included; whoever ends the
+        window hands it back with `_release_staging`."""
         with self.tracer.span("transform.pack"):
-            packed = np.zeros((len(payloads), n_bytes + TAG_SIZE), dtype=np.uint8)
+            packed = self._acquire_staging((len(payloads), n_bytes + TAG_SIZE))
             for i, p in enumerate(payloads):
                 packed[i, : sizes[i]] = np.frombuffer(p, dtype=np.uint8)
+                packed[i, sizes[i] : n_bytes] = 0
             packed[:, n_bytes : n_bytes + IV_SIZE] = ivs
-            if varlen:
-                packed[:, n_bytes + IV_SIZE :] = (
-                    np.asarray(sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
-                )
+            packed[:, n_bytes + IV_SIZE :] = (
+                np.asarray(sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
+                if varlen else 0
+            )
         return packed
 
     def _stage_packed(self, packed: np.ndarray, varlen: bool):
@@ -574,26 +640,33 @@ class TpuTransformBackend(TransformBackend):
             self.dispatch_stats.rows += len(sizes)
             self.dispatch_stats.bytes_in += sum(sizes)
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
-        return ivs, sizes, n_bytes, out
+        return ivs, sizes, n_bytes, out, [packed]
 
     @_spanned("transform.encrypt_finish", count=lambda staged: len(staged[1]),
               n_bytes=lambda staged: sum(staged[1]))
     def _encrypt_finish(self, staged) -> list[bytes]:
         """Block on a staged window's single packed device buffer (one
         device→host fetch) and materialize the wire format
-        (IV || ct || tag per chunk)."""
-        ivs, sizes, n_bytes, out = staged
+        (IV || ct || tag per chunk): one allocation and one copy of the
+        payload per chunk. The window is over then, and its host staging
+        buffer goes back to the ring."""
+        ivs, sizes, n_bytes, out, staging = staged
         with self.tracer.span("transform.d2h_wait"):
             host = np.asarray(out)
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
-        return [
-            ivs[i].tobytes()
-            + host[i, : sizes[i]].tobytes()
-            + host[i, n_bytes:].tobytes()
+        wire = [
+            b"".join((
+                ivs[i].tobytes(),
+                memoryview(host[i, : sizes[i]]),
+                memoryview(host[i, n_bytes:]),
+            ))
             for i in range(len(sizes))
         ]
+        if staging:  # a window finished twice returns its buffer once
+            self._release_staging(staging.pop())
+        return wire
 
     # ----------------------------------------------------------- detransform
     def detransform(self, chunks: Sequence[bytes], opts: DetransformOptions) -> list[bytes]:
@@ -654,7 +727,9 @@ class TpuTransformBackend(TransformBackend):
         )
         received_tags = [c[-TAG_SIZE:] for c in chunks]
         sizes = [len(c) - IV_SIZE - TAG_SIZE for c in chunks]
-        payloads = [c[IV_SIZE:-TAG_SIZE] for c in chunks]
+        # Views, not slices: the one copy of a stored chunk's payload is the
+        # one `_build_packed` makes into the staged window.
+        payloads = [memoryview(c)[IV_SIZE:-TAG_SIZE] for c in chunks]
         batcher = self.batcher
         if batcher is not None and min(sizes) > 0:
             # Zero-length rows are excluded by the varlen window contract
@@ -699,7 +774,9 @@ class TpuTransformBackend(TransformBackend):
         hook = self.on_decrypt_window
         if hook is not None:
             hook(out, sizes, n_bytes, self.mesh_plan().size)
-        return [host[i, : sizes[i]].tobytes() for i in range(len(sizes))]
+        plain = [host[i, : sizes[i]].tobytes() for i in range(len(sizes))]
+        self._release_staging(packed)
+        return plain
 
 
 def _definition():

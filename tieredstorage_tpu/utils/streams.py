@@ -111,13 +111,19 @@ def read_exactly(stream: BinaryIO, n: int) -> bytes:
     """Read exactly n bytes or raise EOFError (reference:
     BaseDetransformChunkEnumeration.fillChunkIfNeeded errors on short streams,
     core/.../transform/BaseDetransformChunkEnumeration.java:78-113)."""
+    data = stream.read(n)
+    if data is not None and len(data) >= n:
+        # One read had it all (a file, a ranged GET's body): hand its bytes
+        # on as they are. Gathering them below costs two more copies of a
+        # 4 MiB chunk, both under the interpreter lock.
+        return data if isinstance(data, bytes) else bytes(data)
     out = bytearray()
-    while len(out) < n:
-        data = stream.read(n - len(out))
-        if not data:
-            raise EOFError(f"Stream has fewer than expected bytes: wanted {n}, got {len(out)}")
+    while data:
         out += data
-    return bytes(out)
+        if len(out) >= n:
+            return bytes(out)
+        data = stream.read(n - len(out))
+    raise EOFError(f"Stream has fewer than expected bytes: wanted {n}, got {len(out)}")
 
 
 class ClosableStreamHolder:
