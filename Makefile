@@ -1,7 +1,7 @@
 # Developer entry points (counterpart of /root/reference/Makefile).
 PYTHON ?= python
 
-.PHONY: test test-e2e chaos chaos-matrix bench demo trace-demo scrub-demo tail-demo failover-demo fleet-demo fleet-soak transform-demo multichip-demo hot-demo load-demo docs docker lint analyze mutation clean
+.PHONY: test test-e2e chaos chaos-matrix demo trace-demo scrub-demo tail-demo failover-demo fleet-demo fleet-soak transform-demo multichip-demo hot-demo load-demo docs docker lint analyze mutation clean
 
 test:
 	$(PYTHON) -m pytest tests/ -q --ignore=tests/e2e
@@ -34,9 +34,6 @@ chaos:
 # Deterministic for a given --seed; writes + re-validates the report.
 chaos-matrix:
 	$(PYTHON) tools/chaos_matrix.py --out artifacts/chaos_matrix_report.json
-
-bench:
-	$(PYTHON) bench.py
 
 demo:
 	$(PYTHON) demo/run_demo.py
@@ -183,8 +180,8 @@ hot-demo:
 # by the readahead-misprediction SLO spec's own verdict, continue across
 # every segment boundary, and leave attributable readahead.window flight
 # records.
-# Writes artifacts/load_report.json + artifacts/BENCH_LOAD.json (the
-# committed BENCH_LOAD_r01.json trajectory point) and re-validates both.
+# Writes artifacts/load_report.json + artifacts/BENCH_LOAD.json and
+# re-validates both.
 load-demo:
 	TSTPU_LOCK_WITNESS=1 $(PYTHON) tools/load_demo.py --out artifacts/load_report.json --bench-out artifacts/BENCH_LOAD.json
 
@@ -209,7 +206,7 @@ analyze:
 	$(PYTHON) -m tieredstorage_tpu.analysis --json artifacts/analysis_report.json
 
 lint: analyze
-	$(PYTHON) -m compileall -q tieredstorage_tpu tests tools bench.py
+	$(PYTHON) -m compileall -q tieredstorage_tpu tests tools
 
 # Mutation testing (counterpart of the reference's pitest gate,
 # /root/reference/build.gradle:24): flips operators in core pure-logic

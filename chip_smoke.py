@@ -181,9 +181,9 @@ class CompileLog:
 
 # ------------------------------------------------------------------ the data
 def make_segment(seed: int, n_bytes: int) -> bytes:
-    """Semi-compressible bytes shaped like Kafka log batches (the shape of
-    bench.py's `make_segment`): incompressible payload interleaved with
-    repetitive record scaffolding, made in bulk from `seed`."""
+    """Semi-compressible bytes shaped like Kafka log batches:
+    incompressible payload interleaved with repetitive record scaffolding,
+    made in bulk from `seed`."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

@@ -2,7 +2,7 @@
  * Broker-side shim: implements the KIP-405 RemoteStorageManager SPI by
  * forwarding the five operations to the tieredstorage_tpu sidecar process
  * over its shim-wire HTTP boundary (tieredstorage_tpu/sidecar/shimwire.py,
- * served by `python -m tieredstorage_tpu.sidecar --http-port N`).
+ * served by `python -m tieredstorage_tpu.sidecar --port N`).
  *
  * Deliberately dependency-free: only the JDK (java.net.http, java.io) and
  * kafka-storage-api (already on every broker's classpath). No grpc-java /

@@ -2,7 +2,7 @@
 
 Multi-chip sharding paths are validated on a virtual CPU mesh
 (xla_force_host_platform_device_count=8); real-TPU benchmarking happens in
-bench.py, not in the test suite.
+benchmark/run.py, not in the test suite.
 """
 
 from tieredstorage_tpu.utils.platforms import pin_virtual_cpu

@@ -2,7 +2,7 @@
 
 Same scenario shape as test_single_broker.py (remoteCopy → remoteRead →
 remoteManualDelete), but the broker sim's RSM is a SidecarRsmClient talking
-gRPC to a `python -m tieredstorage_tpu.sidecar` subprocess hosting the full
+shim-wire HTTP to a `python -m tieredstorage_tpu.sidecar` subprocess hosting the full
 transform/storage runtime (VERDICT r2 task 3's done-criterion: the e2e
 scenario green against the sidecar). Filesystem storage backend keeps the
 subprocess self-contained; compression+encryption on.

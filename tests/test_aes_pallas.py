@@ -6,7 +6,7 @@ plain-array stand-ins for the refs — identical math, no Mosaic/interpreter in
 the loop. The full `pallas_call` plumbing (grid, BlockSpecs, SMEM) runs under
 Mosaic's interpreter only when TIEREDSTORAGE_SLOW_TESTS=1: XLA-CPU takes ~8
 minutes to compile the interpreted kernel (the real-TPU Mosaic compile is
-what bench.py exercises).
+what tests/test_tpu_compile.py and chip_smoke.py exercise).
 """
 
 from __future__ import annotations

@@ -2,10 +2,11 @@
 
 Mirror of tests/test_trace_propagation.py for the deadline context: the
 caller's remaining budget crosses the HTTP gateway as the ``x-deadline-ms``
-header and the gRPC service as invocation metadata, is adopted server-side
+header (sent by hand and by ``SidecarRsmClient``), is adopted server-side
 for the whole request (including the streamed response drain), and an
 already-expired budget fails fast — before any storage work — with
-``DeadlineExceededException`` mapped to 504 / ``DEADLINE_EXCEEDED``.
+``DeadlineExceededException`` mapped to 504, which the client reads as
+``SidecarUnavailableError``.
 """
 
 from __future__ import annotations
@@ -16,7 +17,9 @@ import time
 import pytest
 
 from tests.test_rsm_lifecycle import make_rsm, make_segment_data, make_segment_metadata
+from tieredstorage_tpu.errors import RemoteStorageException
 from tieredstorage_tpu.sidecar import shimwire
+from tieredstorage_tpu.sidecar.client import SidecarRsmClient, SidecarUnavailableError
 from tieredstorage_tpu.sidecar.http_gateway import SidecarHttpGateway
 from tieredstorage_tpu.utils.deadline import (
     Deadline,
@@ -121,45 +124,42 @@ class TestHttpGatewayPropagation:
                 rsm.fetch_log_segment(md, 0)
 
 
-class TestGrpcPropagation:
-    def _serve(self, rsm):
-        pytest.importorskip("grpc")
-        from tieredstorage_tpu.sidecar.client import SidecarRsmClient
-        from tieredstorage_tpu.sidecar.server import SidecarServer
+class TestClientPropagation:
+    """The same crossing through SidecarRsmClient, the Python twin of the
+    JVM shim: it sends the ambient budget itself."""
 
-        server = SidecarServer(rsm).start()
-        client = SidecarRsmClient(f"127.0.0.1:{server.port}", timeout=60)
-        return server, client
+    def _serve(self, rsm, timeout=60):
+        gateway = SidecarHttpGateway(rsm).start()
+        client = SidecarRsmClient(f"127.0.0.1:{gateway.port}", timeout=timeout)
+        return gateway, client
 
-    def test_deadline_metadata_adopted(self, tmp_path, traced_rsm):
+    def test_deadline_header_adopted(self, tmp_path, traced_rsm):
         rsm = traced_rsm
         md = make_segment_metadata()
         rsm.copy_log_segment_data(md, make_segment_data(tmp_path, with_txn=False))
         rsm.tracer.clear()
-        server, client = self._serve(rsm)
+        gateway, client = self._serve(rsm)
         try:
             with deadline_scope(Deadline.after(30.0)):
                 with client.fetch_log_segment(md, 0) as stream:
                     assert len(stream.read()) == md.segment_size_in_bytes
         finally:
             client.close()
-            server.stop()
-        # The server-side sidecar span exists and the fetch went through the
-        # deadline-scoped guard; metadata carried the budget across.
-        assert _span_by_name(rsm.tracer.spans(), "sidecar.Fetch") is not None
+            gateway.stop()
+        # The client's header carried the budget across: the gateway span
+        # recorded what it adopted.
+        gateway_span = _span_by_name(rsm.tracer.spans(), "gateway.fetch")
+        assert 0.0 < gateway_span.attributes["deadline_ms"] <= 30_000.0
 
     def test_expired_deadline_fails_fast_as_unavailable(self, tmp_path, traced_rsm):
-        """Server-side DeadlineExceededException maps to DEADLINE_EXCEEDED,
-        which the client surfaces as its failover trigger
-        (SidecarUnavailableError) — the same degradation path a wedged
-        sidecar takes, now reached in milliseconds instead of a full
-        timeout."""
-        from tieredstorage_tpu.sidecar.client import SidecarUnavailableError
-
+        """Server-side DeadlineExceededException maps to 504, which the
+        client surfaces as its failover trigger (SidecarUnavailableError) —
+        the same degradation path a wedged sidecar takes, now reached in
+        milliseconds instead of a full timeout."""
         rsm = traced_rsm
         md = make_segment_metadata()
         rsm.copy_log_segment_data(md, make_segment_data(tmp_path, with_txn=False))
-        server, client = self._serve(rsm)
+        gateway, client = self._serve(rsm)
         try:
             start = time.monotonic()
             with deadline_scope(Deadline.after_ms(1)):
@@ -170,12 +170,9 @@ class TestGrpcPropagation:
             assert time.monotonic() - start < 1.0
         finally:
             client.close()
-            server.stop()
+            gateway.stop()
 
-    def test_grpc_server_sheds_with_resource_exhausted(self, tmp_path):
-        pytest.importorskip("grpc")
-        from tieredstorage_tpu.sidecar.client import SidecarRsmClient
-
+    def test_shed_request_carries_the_message_and_is_no_failover(self, tmp_path):
         rsm, _ = make_rsm(
             tmp_path, compression=False, encryption=False,
             extra_configs={
@@ -186,18 +183,16 @@ class TestGrpcPropagation:
         )
         md = make_segment_metadata()
         rsm.copy_log_segment_data(md, make_segment_data(tmp_path, with_txn=False))
-        from tieredstorage_tpu.sidecar.server import SidecarServer
-
-        server = SidecarServer(rsm).start()
-        client = SidecarRsmClient(f"127.0.0.1:{server.port}", timeout=10)
+        gateway, client = self._serve(rsm, timeout=10)
         try:
             rsm.admission.acquire("test-holder")
             try:
-                with pytest.raises(Exception) as exc_info:
+                with pytest.raises(RemoteStorageException) as exc_info:
                     with client.fetch_log_segment(md, 0) as stream:
                         stream.read()
-                # RESOURCE_EXHAUSTED is not a failover code: it maps to the
-                # generic RemoteStorageException carrying the shed detail.
+                # A 429 is a real answer, not a failover trigger: it maps to
+                # the generic RemoteStorageException carrying the shed detail.
+                assert not isinstance(exc_info.value, SidecarUnavailableError)
                 assert "AdmissionRejectedException" in str(exc_info.value)
             finally:
                 rsm.admission.release()
@@ -207,22 +202,5 @@ class TestGrpcPropagation:
             assert rsm.admission.shed_total == 1
         finally:
             client.close()
-            server.stop()
-
-
-class TestWorkerCountConfig:
-    def test_sidecar_grpc_max_workers_config(self, tmp_path):
-        pytest.importorskip("grpc")
-        from tieredstorage_tpu.sidecar.server import SidecarServer
-
-        rsm, _ = make_rsm(
-            tmp_path, compression=False, encryption=False,
-            extra_configs={"sidecar.grpc.max.workers": 3},
-        )
-        assert rsm.sidecar_grpc_max_workers == 3
-        server = SidecarServer(rsm)  # resolves the pool size from the config
-        try:
-            assert server.port > 0
-        finally:
-            server._server.stop(0)
-        rsm.close()
+            gateway.stop()
+            rsm.close()

@@ -37,7 +37,6 @@ def test_configs_rst_covers_all_config_classes():
         "``hedge.budget.percent``",
         "``retry.budget.percent``",
         "``admission.max.concurrent``",
-        "``sidecar.grpc.max.workers``",
         "``sidecar.http.max.workers``",
         "``fleet.enabled``",
         "``fleet.instance.id``",

@@ -3,11 +3,9 @@
 Three contracts, all CPU-runnable:
 
 - **Shape eligibility is pure host logic**: `use_pallas_aes` /
-  `use_pallas_ghash` must return True at the default bench shapes (16-chunk
-  x 4 MiB windows) on ANY platform — the platform/preflight half of the
-  dispatch gate is separate (`pallas_*_available`), so BENCH artifacts can
-  record which program a TPU run dispatches even when measured on the CPU
-  fallback.
+  `use_pallas_ghash` must return True at the production window shapes
+  (16-chunk x 4 MiB windows) on ANY platform — the platform/preflight half
+  of the dispatch gate is separate (`pallas_*_available`).
 - **Byte-for-byte parity**: the packed single-dispatch window ops
   (ops/gcm.py) and the TpuTransformBackend path built on them must produce
   exactly the wire bytes of the multi-dispatch ops (`gcm_encrypt_chunks` /
@@ -76,7 +74,7 @@ def _wire_varlen_multi_dispatch(dk, ivs, chunks):
 # ------------------------------------------------------------------ shapes
 class TestShapeEligibilityAtBenchShapes:
     """Eligibility is pure host logic — asserted on the CPU suite, at the
-    exact shapes bench.py derives for its measured windows."""
+    shapes the benchmark's deployments launch (benchmark/configs/)."""
 
     @staticmethod
     def _bench_shapes(chunk_bytes: int, window: int):
@@ -91,9 +89,9 @@ class TestShapeEligibilityAtBenchShapes:
     @pytest.mark.parametrize(
         "chunk_bytes,window",
         [
-            (4 << 20, 16),  # bench.py TPU default: 16-chunk x 4 MiB windows
+            (4 << 20, 16),  # a copy's window: 16 chunks x 4 MiB
             (4 << 20, 4),   # ranged-fetch prefetch window (16 MiB / 4 MiB)
-            (1 << 20, 8),   # bench.py CPU-fallback default segment
+            (1 << 20, 8),   # a smaller deployment: 8 chunks x 1 MiB
         ],
     )
     def test_production_window_shapes_are_eligible(self, chunk_bytes, window):
@@ -106,8 +104,7 @@ class TestShapeEligibilityAtBenchShapes:
 
     def test_eligibility_needs_no_device(self, monkeypatch):
         """The verdicts must not consult the backend at all: poisoning the
-        backend probe cannot change them (bench runs them before any device
-        is touched)."""
+        backend probe cannot change them."""
         import jax
 
         from tieredstorage_tpu.ops.aes_pallas import use_pallas_aes

@@ -12,7 +12,7 @@ limiter engaged, 8-way virtual mesh — asserting
   device stages and host zstd share cores and cannot genuinely overlap —
   attribution in artifacts_r5/segment_scale_attrib_zstd.txt. The overlap
   *logic* is pinned by test_transform_tpu.py's simulated-stage test; the
-  real-chip overlap shows up in bench.py's end-to-end numbers.)
+  real-chip overlap shows up in the benchmark's copy cells, PERF.md §5.)
 - constant host memory: peak RSS growth stays a small multiple of the
   in-flight window budget, nowhere near the 1 GiB a materialize-the-segment
   design would hold (the reference streams too —

@@ -1,11 +1,11 @@
 """End-to-end trace propagation across the sidecar boundary (ISSUE 2).
 
-One fetch through the HTTP gateway (or the gRPC service) must produce ONE
-trace tree — shared trace_id, correct parenting — spanning
-client → gateway/sidecar → RSM → storage backend, and the tree must export
-as valid Chrome trace-event JSON. The client side uses its own Tracer
-instance, exactly like the JVM shim or a remote Python client would: the
-only thing crossing the wire is the W3C ``traceparent`` header/metadata.
+One fetch through the HTTP gateway must produce ONE trace tree — shared
+trace_id, correct parenting — spanning client → gateway → RSM → storage
+backend, and the tree must export as valid Chrome trace-event JSON. The
+client side uses its own Tracer instance, exactly like the JVM shim or a
+remote Python client would: the only thing crossing the wire is the W3C
+``traceparent`` header.
 """
 
 from __future__ import annotations
@@ -125,11 +125,9 @@ class TestHttpGatewayPropagation:
             assert isinstance(event["ts"], float)
 
 
-class TestGrpcPropagation:
+class TestClientPropagation:
     def test_client_to_sidecar_single_trace(self, tmp_path, traced_rsm):
-        grpc = pytest.importorskip("grpc")  # noqa: F841 — boundary dep
         from tieredstorage_tpu.sidecar.client import SidecarRsmClient
-        from tieredstorage_tpu.sidecar.server import SidecarServer
 
         rsm = traced_rsm
         md = make_segment_metadata()
@@ -137,24 +135,22 @@ class TestGrpcPropagation:
         rsm.tracer.clear()
 
         client_tracer = Tracer(enabled=True)
-        server = SidecarServer(rsm).start()
+        gateway = SidecarHttpGateway(rsm).start()
         client = SidecarRsmClient(
-            f"127.0.0.1:{server.port}", timeout=60, tracer=client_tracer
+            f"127.0.0.1:{gateway.port}", timeout=60, tracer=client_tracer
         )
         try:
             with client.fetch_log_segment(md, 0) as stream:
                 assert len(stream.read()) == md.segment_size_in_bytes
         finally:
             client.close()
-            # stop() closes the RSM too; the traced_rsm fixture's close() is
-            # idempotent so double-close is fine.
-            server.stop()
+            gateway.stop()
 
-        client_span = _span_by_name(client_tracer.spans(), "client.Fetch")
-        sidecar_span = _span_by_name(rsm.tracer.spans(), "sidecar.Fetch")
+        client_span = _span_by_name(client_tracer.spans(), "client.fetch_log_segment")
+        gateway_span = _span_by_name(rsm.tracer.spans(), "gateway.fetch")
         rsm_span = _span_by_name(rsm.tracer.spans(), "rsm.fetch_log_segment")
-        assert sidecar_span.trace_id == client_span.trace_id
-        assert sidecar_span.parent_id == client_span.span_id
+        assert gateway_span.trace_id == client_span.trace_id
+        assert gateway_span.parent_id == client_span.span_id
         assert rsm_span.trace_id == client_span.trace_id
-        assert rsm_span.parent_id == sidecar_span.span_id
+        assert rsm_span.parent_id == gateway_span.span_id
         assert client_span.attributes["bytes"] == md.segment_size_in_bytes

@@ -245,8 +245,8 @@ def _base_def() -> ConfigDef:
         "deadline.default.ms", "long", default=None,
         validator=null_or(in_range(1, None)), importance="medium",
         doc="Default end-to-end deadline installed at the RSM/gateway entry "
-            "when the caller did not propagate one (x-deadline-ms header / "
-            "gRPC metadata). Every layer clamps its waiting to the remaining "
+            "when the caller did not propagate one (the x-deadline-ms "
+            "header). Every layer clamps its waiting to the remaining "
             "budget and expired requests fail fast with "
             "DeadlineExceededException before touching the network; null "
             "means unconstrained.",
@@ -377,11 +377,10 @@ def _base_def() -> ConfigDef:
     ))
     d.define(ConfigKey(
         "admission.enabled", "bool", default=False, importance="medium",
-        doc="Gate the sidecar boundaries (HTTP gateway + gRPC service) with "
-            "an admission controller: at most admission.max.concurrent "
-            "requests execute, admission.max.queue more wait, and the rest "
-            "are shed at entry with 429 + Retry-After / RESOURCE_EXHAUSTED "
-            "before the request body is read.",
+        doc="Gate the sidecar boundary (the HTTP gateway) with an admission "
+            "controller: at most admission.max.concurrent requests execute, "
+            "admission.max.queue more wait, and the rest are shed at entry "
+            "with 429 + Retry-After before the request body is read.",
     ))
     d.define(ConfigKey(
         "admission.max.concurrent", "int", default=64,
@@ -404,16 +403,8 @@ def _base_def() -> ConfigDef:
     d.define(ConfigKey(
         "admission.retry.after.ms", "long", default=1_000,
         validator=in_range(1, None), importance="low",
-        doc="Backoff hint returned with shed requests (HTTP Retry-After "
-            "header, gRPC retry-after trailer), rounded up to whole "
-            "seconds on the HTTP side.",
-    ))
-    d.define(ConfigKey(
-        "sidecar.grpc.max.workers", "int", default=8,
-        validator=in_range(1, None), importance="low",
-        doc="Thread pool size of the gRPC sidecar server (was hardcoded at "
-            "8). Size to the expected broker fetch parallelism; admission "
-            "control sheds what the pool cannot absorb.",
+        doc="Backoff hint returned with shed requests (the Retry-After "
+            "header), rounded up to whole seconds.",
     ))
     d.define(ConfigKey(
         "sidecar.http.max.workers", "int", default=32,
@@ -993,10 +984,6 @@ class RemoteStorageManagerConfig:
     @property
     def admission_retry_after_ms(self) -> int:
         return self._values["admission.retry.after.ms"]
-
-    @property
-    def sidecar_grpc_max_workers(self) -> int:
-        return self._values["sidecar.grpc.max.workers"]
 
     @property
     def sidecar_http_max_workers(self) -> int:

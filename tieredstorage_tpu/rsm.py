@@ -187,7 +187,7 @@ class RemoteStorageManager:
         #: Fleet-wide telemetry aggregator (fleet mode).
         self._fleet_telemetry = None
         #: Entry-gate admission controller (`admission.enabled`); the sidecar
-        #: boundaries (HTTP gateway + gRPC server) shed through this.
+        #: boundary (the HTTP gateway) sheds through this.
         self.admission: Optional[AdmissionController] = None
         #: Fleet mode (`fleet.*`): consistent-hash router + peer cache tier.
         self.fleet_router: Optional[FleetRouter] = None
@@ -917,19 +917,11 @@ class RemoteStorageManager:
 
     @property
     def default_deadline_s(self) -> Optional[float]:
-        """`deadline.default.ms` in seconds; the sidecar boundaries and the
+        """`deadline.default.ms` in seconds; the sidecar boundary and the
         _traced entry points install this when the caller sent no deadline."""
         if self._config is None or self._config.deadline_default_ms is None:
             return None
         return self._config.deadline_default_ms / 1000.0
-
-    @property
-    def sidecar_grpc_max_workers(self) -> int:
-        """`sidecar.grpc.max.workers` (SidecarServer reads this when no
-        explicit max_workers is passed)."""
-        return (
-            self._config.sidecar_grpc_max_workers if self._config is not None else 8
-        )
 
     @property
     def sidecar_http_max_workers(self) -> int:
@@ -1573,8 +1565,9 @@ class RemoteStorageManager:
         property structurally: the returned stream is lazy
         (FetchChunkEnumeration fetches chunk N+1 only when the consumer
         reads past chunk N, and close() stops the enumeration early), so an
-        abandoned read costs nothing and raises nothing; over the gRPC
-        sidecar boundary a cancelled RPC simply stops draining the stream.
+        abandoned read costs nothing and raises nothing; over the sidecar
+        boundary a reader that hangs up ends the gateway's stream at its
+        next write.
         """
         config = self._require_configured()
         if start_position < 0:

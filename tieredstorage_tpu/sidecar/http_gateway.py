@@ -1,10 +1,11 @@
-"""HTTP/1.1 gateway for the broker-side JVM shim (shim wire format v1).
+"""HTTP/1.1 gateway: the sidecar's broker boundary (shim wire format v1).
 
-Serves the same five operations as the gRPC service (sidecar/server.py)
-against the same RemoteStorageManager, over the dependency-free framing in
+Serves copy, fetch, fetch-index, delete and health against a
+RemoteStorageManager over the dependency-free framing in
 sidecar/shimwire.py, so the Java shim (`kafka-shim/`) needs nothing but the
-JDK. Runs inside the sidecar process; `python -m tieredstorage_tpu.sidecar
---http-port N` starts it next to the gRPC listener.
+JDK; sidecar/client.py is its Python twin. It is the sidecar process's only
+broker-facing listener: `python -m tieredstorage_tpu.sidecar --port N`
+(sidecar/server.py:main) starts it.
 
 Error mapping (the shim translates back to KIP-405 exception types):
 404 RemoteResourceNotFoundException, 400 invalid argument,

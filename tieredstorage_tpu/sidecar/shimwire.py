@@ -5,11 +5,10 @@ third-party jars: a broker operator drops one class (plus
 `kafka-storage-api`, already on the broker classpath) next to the broker
 and points it at the sidecar. grpc-java + protobuf-java + netty would be a
 shaded-jar dependency train, and `java.net.http` cannot read the HTTP/2
-trailers gRPC carries its status in — so the sidecar exposes this second,
-deliberately boring boundary for the shim: HTTP/1.1 + a fixed big-endian
-binary framing that `java.io.DataOutputStream` writes naturally. The gRPC
-service (sidecar/server.py) remains the boundary for Python clients; both
-front the same RemoteStorageManager in the same process.
+trailers gRPC carries its status in — so the sidecar's one boundary is
+deliberately boring: HTTP/1.1 + a fixed big-endian binary framing that
+`java.io.DataOutputStream` writes naturally. Python callers speak it too
+(sidecar/client.py).
 
 All integers big-endian (Java DataOutput order). The metadata block mirrors
 KIP-405 RemoteLogSegmentMetadata (reference:
@@ -41,8 +40,9 @@ Requests (POST bodies; responses are raw bytes or empty):
     GET /v1/health   -> 200
 
 Errors: 404 = RemoteResourceNotFoundException, 400 = invalid argument,
-500 = anything else; the body is a UTF-8 message. The Java shim maps these
-back onto the KIP-405 exception types.
+429 = shed at admission, 504 = deadline exceeded, 500 = anything else; the
+body is a UTF-8 message. The Java shim maps these back onto the KIP-405
+exception types, sidecar/client.py onto the RSM's.
 
 Trace context deliberately rides the standard W3C ``traceparent`` HTTP
 header, NOT the binary frame: wire version 1 stays byte-stable, and the JVM
@@ -191,6 +191,15 @@ def decode_metadata(buf: BinaryIO) -> RemoteLogSegmentMetadata:
     )
 
 
+#: What an absent copy section is on the wire.
+SECTION_ABSENT = b"\x00"
+
+
+def section_header(length: int) -> bytes:
+    """What precedes a present copy section's `length` bytes on the wire."""
+    return struct.pack(">BQ", 1, length)
+
+
 def encode_sections(sections: dict) -> bytes:
     """COPY_SECTIONS name -> Optional[bytes], in wire order (the Python-side
     encoder mirror of the Java shim's copyBody; symmetry-pinned against the
@@ -199,9 +208,9 @@ def encode_sections(sections: dict) -> bytes:
     for name in COPY_SECTIONS:
         blob = sections.get(name)
         if blob is None:
-            out.write(b"\x00")
+            out.write(SECTION_ABSENT)
         else:
-            out.write(struct.pack(">BQ", 1, len(blob)))
+            out.write(section_header(len(blob)))
             out.write(blob)
     return out.getvalue()
 

@@ -107,9 +107,9 @@ class DispatchStats:
     device dispatch (keystream → XOR → GHASH → tag in a single program —
     `ops/gcm.py` packed window ops), and one device→host fetch. Every
     extra launch or fetch pays a size-independent floor, so launch-count
-    regressions are throughput regressions; bench.py and chip_smoke.py
-    report `dispatches_per_window` and
-    `bytes_per_dispatch` from these counters next to the GiB/s numbers.
+    regressions are throughput regressions; the benchmark reads
+    `dispatches_per_window.copy` and `dispatches_per_fetch.fetch` from
+    these counters next to its end-to-end numbers.
     Guarded by the owning backend's `_stats_lock` (one backend instance
     serves concurrent upload/fetch windows on the gateway worker pool —
     the guarded-by race checker infers and enforces the guard, and the

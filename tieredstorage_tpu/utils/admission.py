@@ -1,8 +1,7 @@
 """Admission control: bounded concurrency + bounded queue at the entry point.
 
 The sidecar previously accepted unlimited concurrent work: the HTTP gateway
-is a ThreadingHTTPServer (a thread per connection) and the gRPC server has a
-worker pool but an unbounded accept queue, so overload manifested as
+has a worker pool but an unbounded accept queue, so overload manifested as
 ever-growing queues, memory growth, and every request timing out together —
 the classic congestion-collapse shape. DAGOR ("Overload Control for Scaling
 WeChat Microservices", SOSP 2018) is explicit that shedding must happen at
@@ -12,8 +11,8 @@ body is even read), and that rejected callers must be told to back off.
 ``AdmissionController`` is that gate: at most ``max_concurrent`` requests
 execute, at most ``max_queue`` more wait (bounded, with a wait deadline),
 and everything beyond that is shed immediately with
-``AdmissionRejectedException`` carrying a Retry-After hint — the boundaries
-translate it to HTTP 429 + ``Retry-After`` and gRPC ``RESOURCE_EXHAUSTED``.
+``AdmissionRejectedException`` carrying a Retry-After hint — the gateway
+translates it to HTTP 429 + ``Retry-After``.
 Counters are plain ints exported as resilience gauges; ``on_wait`` feeds the
 admission-wait-time histogram.
 

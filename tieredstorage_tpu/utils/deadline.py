@@ -16,14 +16,13 @@ Mechanics mirror the tracing context (utils/tracing.py):
 - it propagates through a thread-local scope (``deadline_scope`` /
   ``current_deadline``) so the storage transport and the chunk path consume
   it without plumbing an argument through every signature;
-- across the sidecar boundary it rides the ``x-deadline-ms`` HTTP header /
-  gRPC invocation metadata as *remaining milliseconds* (absolute monotonic
-  time is process-local, so the wire carries the budget, not the instant —
-  the same scheme gRPC itself uses for deadline propagation);
+- across the sidecar boundary it rides the ``x-deadline-ms`` HTTP header as
+  *remaining milliseconds* (absolute monotonic time is process-local, so
+  the wire carries the budget, not the instant — the scheme gRPC uses for
+  deadline propagation);
 - expired deadlines raise ``DeadlineExceededException`` — a distinct type so
-  the sidecar boundaries map it to 504 / ``DEADLINE_EXCEEDED`` instead of a
-  generic 500, and so the breaker can treat it as caller impatience rather
-  than backend failure.
+  the sidecar boundary maps it to 504 instead of a generic 500, and so the
+  breaker can treat it as caller impatience rather than backend failure.
 """
 
 from __future__ import annotations
@@ -38,7 +37,7 @@ from typing import Iterator, Optional
 from tieredstorage_tpu.storage.core import StorageBackendException
 from tieredstorage_tpu.utils.locks import new_lock
 
-#: Header / gRPC-metadata key carrying the remaining budget in integer
+#: Header carrying the remaining budget in integer
 #: milliseconds (the deadline twin of the ``traceparent`` key).
 DEADLINE_HEADER = "x-deadline-ms"
 

@@ -1,4 +1,4 @@
-"""Platform set-up shared by tests, the sidecar, chip_smoke.py and bench.py:
+"""Platform set-up shared by tests, the sidecar, chip_smoke.py and benchmark/run.py:
 virtual-CPU-mesh pinning, the persistent compile cache, and the count of
 programs JAX traces.
 

@@ -188,7 +188,7 @@ class Tracer:
 
     def current_traceparent(self) -> Optional[str]:
         """``traceparent`` value for the active context, for injection into
-        outgoing HTTP headers / gRPC metadata; None when there is nothing to
+        outgoing HTTP headers; None when there is nothing to
         propagate (tracing disabled or no active span)."""
         if not self.enabled:
             return None

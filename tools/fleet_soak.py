@@ -176,8 +176,7 @@ class Sidecar:
             [
                 sys.executable, "-m", "tieredstorage_tpu.sidecar",
                 "--config", str(self.config_path),
-                "--port", "0",
-                "--http-port", str(self.http_port),
+                "--port", str(self.http_port),
                 "--fleet-peers", self.peers_arg,
             ],
             cwd=str(REPO_ROOT), env=env,

@@ -99,11 +99,10 @@ ISSUE 18 added the predictive-readahead proof to the same gate:
     boundary, and leave attributable synthetic ``readahead.window``
     flight records in the ring.
 
-Writes ``artifacts/load_report.json`` (re-read + re-validated) and the
-bench-trajectory point ``BENCH_LOAD_r01.json`` (throughput, p50/p99,
-shed %, failover count, cache-tier hit %, probe occupancy + GiB/s) so
-capacity regressions become PR-over-PR visible the same way transform
-throughput is. This is the ``make load-demo`` CI gate.
+Writes ``artifacts/load_report.json`` (re-read + re-validated) and, at
+``--bench-out``, this CPU run's own record (throughput, p50/p99, shed %,
+failover count, cache-tier hit %, probe occupancy). This is the
+``make load-demo`` CI gate.
 """
 
 from __future__ import annotations
@@ -711,7 +710,7 @@ def _build_probe_chain(batch: bool):
     # Warm the jit program cache for every shape the probe can launch
     # (fixed 8-row windows on the direct path; the power-of-two row ladder
     # of merged varlen flushes when batching): XLA compile cost is a
-    # deployment concern measured by bench.py's compile section — leaving
+    # deployment concern (the benchmark reports it as set-up) — leaving
     # it inside the timed phase would make the latency SLO judge the
     # compiler, not the serving path. Throwaway stats are reset below.
     warm_dk = AesEncryptionProvider.create_data_key_and_aad()
