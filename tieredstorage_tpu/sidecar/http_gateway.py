@@ -72,8 +72,8 @@ The observability plane (ISSUE 14) adds three read-only routes:
 from __future__ import annotations
 
 import contextlib
+import io
 import math
-import pathlib
 import tempfile
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -96,11 +96,13 @@ from tieredstorage_tpu.utils.flightrecorder import NOOP_RECORDER
 from tieredstorage_tpu.utils.tracing import NOOP_TRACER
 
 _STREAM_BLOCK = 1 << 20
-#: Spool request bodies to disk past this (copy uploads are whole segments).
-_SPOOL_BYTES = 64 << 20
-#: Reject request bodies past this — matches the gRPC boundary's
-#: max-message ceiling so a runaway client can't OOM the sidecar.
+#: Reject request bodies past this, so a runaway client cannot fill the
+#: sidecar's scratch disk: a /v1/copy body is a whole log segment with its
+#: indexes (Kafka's default log.segment.bytes is 1 GiB).
 MAX_BODY_BYTES = 2 << 30
+#: Bodies of every other route are held in memory (a metadata block and a
+#: few integers: ~100 bytes), so they are refused past this.
+MAX_INLINE_BODY_BYTES = 64 << 20
 
 
 class _BodyTooLarge(Exception):
@@ -113,100 +115,179 @@ class _StreamAborted(Exception):
     second response being written into the body."""
 
 
+class _BodyReader:
+    """One request's body, read off the connection with its framing undone.
+
+    A file-like (`read`, `readinto`) over the handler's `rfile` for either
+    framing the gateway accepts: a `Content-Length`, or the chunked transfer
+    java.net.http uses for bodies of unknown length (the shim's copy path
+    wraps file streams; BaseHTTPRequestHandler does not decode it). Nothing
+    is read ahead of what the caller asks for, so the shim-wire decoders can
+    run straight over the socket; `cap` bounds the running total.
+    """
+
+    def __init__(self, rfile, headers, cap: int):
+        self._rfile = rfile
+        self._cap = cap
+        #: Body bytes handed out so far.
+        self.total = 0
+        self._chunked = headers.get("Transfer-Encoding", "").lower() == "chunked"
+        #: Bytes left of the current chunk (of the whole body under a
+        #: Content-Length).
+        self._left = 0
+        self._ended = not self._chunked
+        if not self._chunked:
+            raw_len = headers.get("Content-Length", "0").strip()
+            # Strict 1*DIGIT: bare int() accepts '+5'/'1_0'/'-7', all desync
+            # surface, and a negative length would never be reached.
+            # str.isdigit() is NOT the right gate — it accepts non-ASCII
+            # digits (e.g. '٥', '５') that int() happily parses, so hold the
+            # same explicit ASCII allowlist as the chunk-size arm.
+            if not raw_len or not all(c in "0123456789" for c in raw_len):
+                raise shimwire.ShimWireError(f"bad Content-Length {raw_len!r}")
+            self._left = int(raw_len)
+            if self._left > cap:
+                raise _BodyTooLarge()
+
+    @property
+    def exhausted(self) -> bool:
+        """The body's last byte (and a chunked body's trailer) has been read."""
+        return self._left == 0 and self._ended
+
+    def _more(self) -> bool:
+        """Whether body bytes are left, after stepping into the next chunk."""
+        if self._left == 0 and not self._ended:
+            self._next_chunk()
+        return self._left > 0
+
+    def _next_chunk(self) -> None:
+        raw_line = self._rfile.readline(1024)
+        if not raw_line.endswith(b"\n"):
+            # Truncation here would silently shift the remainder of the size
+            # line into the chunk data.
+            raise shimwire.ShimWireError("chunk size line too long")
+        size_line = raw_line.strip()
+        # Strict RFC 7230 chunk-size grammar (1*HEXDIG). int(_, 16) alone also
+        # accepts "-5"/"+5"/"0x1f"/"1_0" — a negative size would never be
+        # reached, and the non-canonical ones are request-smuggling surface
+        # against stricter intermediaries. BWS before the chunk-ext ';' is
+        # valid per RFC 7230 §3.2.3 (recipients MUST parse and remove) —
+        # strip it before the strict 1*HEXDIG check.
+        size_field = size_line.split(b";")[0].strip()
+        if not size_field or not all(
+            c in b"0123456789abcdefABCDEF" for c in size_field
+        ):
+            raise shimwire.ShimWireError(f"bad chunk size line {size_line!r}")
+        size = int(size_field, 16)
+        if size == 0:
+            # Consume the trailer section up to the final CRLF.
+            while self._rfile.readline(1024).strip():
+                pass
+            self._ended = True
+        elif self.total + size > self._cap:
+            raise _BodyTooLarge()
+        self._left = size
+
+    def _took(self, n: int) -> None:
+        self.total += n
+        self._left -= n
+        if self._chunked and self._left == 0:
+            self._rfile.read(2)  # chunk-terminating CRLF
+
+    def read(self, n: int = -1) -> bytes:
+        """Up to `n` body bytes (all that are left when negative): fewer only
+        at the body's end."""
+        pieces = []
+        while n != 0 and self._more():
+            want = min(self._left, _STREAM_BLOCK)
+            block = self._rfile.read(want if n < 0 else min(n, want))
+            if not block:
+                raise shimwire.ShimWireError("request body truncated")
+            self._took(len(block))
+            pieces.append(block)
+            if n > 0:
+                n -= len(block)
+        return pieces[0] if len(pieces) == 1 else b"".join(pieces)
+
+    def readinto(self, view) -> int:
+        """Fill `view` with body bytes; short only at the body's end."""
+        view = memoryview(view)
+        filled = 0
+        while filled < len(view) and self._more():
+            got = self._rfile.readinto(view[filled:filled + self._left])
+            if not got:
+                raise shimwire.ShimWireError("request body truncated")
+            self._took(got)
+            filled += got
+        return filled
+
+    def drain(self) -> None:
+        """Read what is left of the body and drop it."""
+        block = bytearray(64 << 10)
+        while self.readinto(block):
+            pass
+
+
+class _CopyBody:
+    """A /v1/copy body as `_copy_body` received it: the metadata and the
+    section files in a scratch directory, which `close` removes."""
+
+    def __init__(self, scratch, metadata, paths: dict):
+        self._scratch = scratch
+        self.metadata = metadata
+        self.paths = paths
+
+    def close(self) -> None:
+        self._scratch.cleanup()
+
+
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     rsm = None  # set per-server subclass
+    gateway = None  # likewise: the SidecarHttpGateway, which counts copy bodies
 
     def log_message(self, fmt, *args):  # quiet; the RSM has its own tracing
         pass
 
     # ------------------------------------------------------------- plumbing
     def _body(self):
-        """Request body as a seekable file, disk-spooled past _SPOOL_BYTES
-        and capped at MAX_BODY_BYTES (a copy request holds a whole segment,
-        which must not be required to fit in sidecar RAM).
-
-        Copy uploads touch disk twice (spooled body, then the decoded
-        section files) — accepted: decoding straight off the socket would
-        tie chunked-transfer framing into the section parser, and a segment
-        copy is a once-per-segment operation whose cost is dominated by the
-        transform, not local disk."""
+        """The request body in memory, as a file: what every route but
+        /v1/copy reads (a metadata block and a few integers). Refused past
+        MAX_INLINE_BODY_BYTES."""
         tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
         with tracer.span("gateway.spool") as span:
-            out = self._spool_body()
+            data = _BodyReader(self.rfile, self.headers, MAX_INLINE_BODY_BYTES).read()
             if span is not None:
-                span.attributes["bytes"] = out.tell()
-        out.seek(0)
-        return out
+                span.attributes["bytes"] = len(data)
+        return io.BytesIO(data)
 
-    def _spool_body(self):
-        out = tempfile.SpooledTemporaryFile(max_size=_SPOOL_BYTES)
-        total = 0
-
-        def take(n: int) -> None:
-            nonlocal total
-            remaining = n
-            while remaining:
-                block = self.rfile.read(min(remaining, _STREAM_BLOCK))
-                if not block:
-                    raise shimwire.ShimWireError("request body truncated")
-                total += len(block)
-                if total > MAX_BODY_BYTES:
-                    raise _BodyTooLarge()
-                out.write(block)
-                remaining -= len(block)
-
-        if self.headers.get("Transfer-Encoding", "").lower() == "chunked":
-            # java.net.http streams unknown-length bodies (the shim's copy
-            # path wraps file streams) as chunked; BaseHTTPRequestHandler
-            # doesn't decode it, so do it here.
-            while True:
-                raw_line = self.rfile.readline(1024)
-                if not raw_line.endswith(b"\n"):
-                    # Truncation here would silently shift the remainder of
-                    # the size line into the chunk data.
-                    raise shimwire.ShimWireError("chunk size line too long")
-                size_line = raw_line.strip()
-                # Strict RFC 7230 chunk-size grammar (1*HEXDIG). int(_, 16)
-                # alone also accepts "-5"/"+5"/"0x1f"/"1_0" — the negative
-                # forms would make take(n<0) spin reading to EOF, and the
-                # non-canonical ones are request-smuggling surface against
-                # stricter intermediaries.
-                # BWS before the chunk-ext ';' is valid per RFC 7230 §3.2.3
-                # (recipients MUST parse and remove) — strip it before the
-                # strict 1*HEXDIG check.
-                size_field = size_line.split(b";")[0].strip()
-                if not size_field or not all(
-                    c in b"0123456789abcdefABCDEF" for c in size_field
-                ):
-                    raise shimwire.ShimWireError(
-                        f"bad chunk size line {size_line!r}"
-                    )
-                size = int(size_field, 16)
-                if size == 0:
-                    # Consume the trailer section up to the final CRLF.
-                    while self.rfile.readline(1024).strip():
-                        pass
-                    break
-                take(size)
-                self.rfile.read(2)  # chunk-terminating CRLF
-        else:
-            raw_len = self.headers.get("Content-Length", "0").strip()
-            # Same strict grammar rationale as chunk sizes: bare int()
-            # accepts '+5'/'1_0'/'-7', all desync surface ('-7' would also
-            # spin take() to EOF). str.isdigit() is NOT the right gate — it
-            # accepts non-ASCII digits (e.g. '٥', '５') that int() happily
-            # parses, so hold the same explicit ASCII allowlist as the
-            # chunk-size arm.
-            if not raw_len or not all(c in "0123456789" for c in raw_len):
-                raise shimwire.ShimWireError(
-                    f"bad Content-Length {raw_len!r}"
-                )
-            length = int(raw_len)
-            if length > MAX_BODY_BYTES:
-                raise _BodyTooLarge()
-            take(length)
-        return out
+    def _copy_body(self) -> _CopyBody:
+        """A /v1/copy body, decoded as it comes off the socket: the metadata,
+        then each present section straight into its file in a scratch
+        directory, so a segment's bytes are written locally once (the files
+        the RSM opens) and never have to fit in sidecar RAM. Returns only
+        once the body is whole: six section slots decoded, every file at its
+        stated length, the reader at the body's end (bytes after the sixth
+        section are read and dropped). Any failure before that leaves the
+        connection mid-body: the caller answers and hangs up."""
+        tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
+        scratch = tempfile.TemporaryDirectory(prefix="sidecar-http-copy-")
+        try:
+            with tracer.span("gateway.spool") as span:
+                reader = _BodyReader(self.rfile, self.headers, MAX_BODY_BYTES)
+                metadata = shimwire.decode_metadata(reader)
+                paths = shimwire.decode_sections_to_dir(reader, scratch.name)
+                reader.drain()
+                if span is not None:
+                    span.attributes["bytes"] = reader.total
+        except BaseException:
+            scratch.cleanup()
+            raise
+        self.gateway.count_copy_body(
+            received=reader.total,
+            written=sum(p.stat().st_size for p in paths.values() if p is not None),
+        )
+        return _CopyBody(scratch, metadata, paths)
 
     def _reply(self, status: int, body: bytes = b"", headers=None) -> None:
         self.send_response(status)
@@ -224,8 +305,7 @@ class _Handler(BaseHTTPRequestHandler):
         happens on the first read. Pull that block BEFORE committing the
         status line so not-found maps to a clean 404 instead of a
         truncated 200. A failure later mid-stream can only abort the
-        connection (the shim surfaces that as a transport error, the same
-        way a gRPC mid-stream abort lands)."""
+        connection (the shim surfaces that as a transport error)."""
         tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
         with contextlib.closing(stream), \
                 tracer.span("gateway.reply_stream", bytes=0, aborted=False) as span:
@@ -478,19 +558,22 @@ class _Handler(BaseHTTPRequestHandler):
         if self.path in ("/fleet/gossip", "/v1/fleet/gossip"):
             self._fleet_gossip()
             return
+        # The route decides where its body lands: a copy's sections go to
+        # files as they arrive, the rest are a few bytes in memory.
         routes = {
-            "/v1/copy": self._copy,
-            "/v1/fetch": self._fetch,
-            "/v1/fetch-index": self._fetch_index,
-            "/v1/delete": self._delete,
+            "/v1/copy": (self._copy_body, self._copy),
+            "/v1/fetch": (self._body, self._fetch),
+            "/v1/fetch-index": (self._body, self._fetch_index),
+            "/v1/delete": (self._body, self._delete),
         }
-        handler = routes.get(self.path)
-        if handler is None:
+        route = routes.get(self.path)
+        if route is None:
             self._reply(404, b"no such endpoint")
             return
         # Admission gate FIRST — an overloaded sidecar sheds before reading
-        # (and spooling) the request body. The unread body desyncs the
-        # keep-alive framing, so a shed reply also drops the connection.
+        # the request body (a copy's is a whole segment). The unread body
+        # desyncs the keep-alive framing, so a shed reply also drops the
+        # connection.
         admission = getattr(self.rsm, "admission", None)
         tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
         # Optional tenant identity: engages the controller's per-tenant
@@ -505,12 +588,12 @@ class _Handler(BaseHTTPRequestHandler):
                 self.close_connection = True
                 return
         try:
-            self._handle_admitted(handler, tracer)
+            self._handle_admitted(*route, tracer)
         finally:
             if admission is not None:
                 admission.release(tenant=tenant)
 
-    def _handle_admitted(self, handler, tracer) -> None:
+    def _handle_admitted(self, receive, handler, tracer) -> None:
         # Join the caller's trace (W3C traceparent header, sent by the JVM
         # shim or a Python client) and record the gateway leg as one span:
         # the whole server-side handling, from the body's first byte read
@@ -524,12 +607,12 @@ class _Handler(BaseHTTPRequestHandler):
                 self.headers.get(shimwire.TRACEPARENT_HEADER)), \
                 tracer.span(name) as span:
             try:
-                body = self._body()
+                body = receive()
             except _BodyTooLarge:
                 self._reply(413, b"request body exceeds MAX_BODY_BYTES")
                 self.close_connection = True  # unread body left on the socket
                 return
-            except Exception as exc:  # noqa: BLE001 — body-framing failure
+            except Exception as exc:  # noqa: BLE001 — framing or decode failure
                 # The request body was only partially consumed: the remaining
                 # bytes would be parsed as the next request line, desyncing
                 # the keep-alive connection. Answer, then drop the connection.
@@ -565,36 +648,35 @@ class _Handler(BaseHTTPRequestHandler):
             except Exception as exc:  # noqa: BLE001 — boundary translation
                 self._fail(exc)
 
-    def _copy(self, body) -> None:
+    def _copy(self, body: _CopyBody) -> None:
         tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
-        with tempfile.TemporaryDirectory(prefix="sidecar-http-copy-") as tmp:
-            # Sections stream straight to files — a multi-GiB segment never
-            # has to fit in sidecar RAM on top of the spooled request body.
+        # The scratch directory goes before the reply does, so a client that
+        # has its answer finds nothing of the copy left behind.
+        with contextlib.closing(body):
             with tracer.span("gateway.decode"):
-                md = shimwire.decode_metadata(body)
-                paths = shimwire.decode_sections_to_dir(body, tmp)
-            for required in ("log_segment", "offset_index", "time_index",
-                             "leader_epoch_index"):
-                if paths[required] is None:
-                    raise shimwire.ShimWireError(
-                        f"missing required section {required}"
-                    )
-            if paths["producer_snapshot"] is None:
-                # KIP-405 requires the snapshot; tolerate shims for older
-                # brokers by materializing an empty one, like the reference
-                # e2e fixtures do.
-                p = pathlib.Path(tmp) / "producer_snapshot"
-                p.write_bytes(b"")
-                paths["producer_snapshot"] = p
-            data = LogSegmentData(
-                log_segment=paths["log_segment"],
-                offset_index=paths["offset_index"],
-                time_index=paths["time_index"],
-                producer_snapshot_index=paths["producer_snapshot"],
-                transaction_index=paths["transaction_index"],
-                leader_epoch_index=paths["leader_epoch_index"].read_bytes(),
-            )
-            custom = self.rsm.copy_log_segment_data(md, data)
+                paths = body.paths
+                for required in ("log_segment", "offset_index", "time_index",
+                                 "leader_epoch_index"):
+                    if paths[required] is None:
+                        raise shimwire.ShimWireError(
+                            f"missing required section {required}"
+                        )
+                snapshot = paths["producer_snapshot"]
+                if snapshot is None:
+                    # KIP-405 requires the snapshot; tolerate shims for older
+                    # brokers by materializing an empty one, like the
+                    # reference e2e fixtures do.
+                    snapshot = paths["log_segment"].with_name("producer_snapshot")
+                    snapshot.write_bytes(b"")
+                data = LogSegmentData(
+                    log_segment=paths["log_segment"],
+                    offset_index=paths["offset_index"],
+                    time_index=paths["time_index"],
+                    producer_snapshot_index=snapshot,
+                    transaction_index=paths["transaction_index"],
+                    leader_epoch_index=paths["leader_epoch_index"].read_bytes(),
+                )
+            custom = self.rsm.copy_log_segment_data(body.metadata, data)
         if custom:
             self._reply(200, bytes(custom))
         else:
@@ -660,7 +742,13 @@ class SidecarHttpGateway:
         host: str = "127.0.0.1",
         max_workers: Optional[int] = None,
     ):
-        handler = type("BoundHandler", (_Handler,), {"rsm": rsm})
+        handler = type("BoundHandler", (_Handler,), {"rsm": rsm, "gateway": self})
+        #: Exact counts over /v1/copy bodies received whole: their bytes, and
+        #: the bytes the gateway wrote to local files before calling the RSM
+        #: (the section files: one write per byte).
+        self.copy_body_bytes = 0
+        self.copy_body_bytes_written = 0
+        self._counts_lock = threading.Lock()
         if max_workers is None:
             max_workers = getattr(rsm, "sidecar_http_max_workers", 32)
         self._server = _BoundedThreadingHTTPServer((host, port), handler, max_workers)
@@ -670,6 +758,11 @@ class SidecarHttpGateway:
     @property
     def max_workers(self) -> int:
         return self._server.max_workers
+
+    def count_copy_body(self, *, received: int, written: int) -> None:
+        with self._counts_lock:
+            self.copy_body_bytes += received
+            self.copy_body_bytes_written += written
 
     def start(self) -> "SidecarHttpGateway":
         self._thread = threading.Thread(

@@ -215,16 +215,26 @@ def encode_sections(sections: dict) -> bytes:
     return out.getvalue()
 
 
+#: Block in which a large copy section goes from `buf` to its file.
+_SECTION_BLOCK = 1 << 20
+
+
 def decode_sections_to_dir(
     buf: BinaryIO, directory, *, max_section: int = 2 << 30
 ) -> dict:
-    """Like decode_sections, but streams each present section straight into
-    `directory`/<name> so a whole segment never has to sit in sidecar RAM.
-    Returns COPY_SECTIONS name -> Optional[pathlib.Path]."""
+    """Decode the six copy sections from `buf`, each present one straight
+    into `directory`/<name>, so a whole segment never has to sit in sidecar
+    RAM. `buf` is any binary file with `read` and `readinto`: the gateway
+    hands the request body as it comes off the socket, so a section's bytes
+    are written once on their way in. A section of a block or more goes
+    through one reused buffer (`readinto`, no fresh `bytes` per block).
+    Returns COPY_SECTIONS name -> Optional[pathlib.Path]; raises
+    ShimWireError on a section over `max_section` or a file short of its
+    stated length."""
     import pathlib
-    import shutil
 
     directory = pathlib.Path(directory)
+    block = None
     sections: dict = {}
     for name in COPY_SECTIONS:
         (present,) = struct.unpack(">B", _read(buf, 1))
@@ -236,34 +246,22 @@ def decode_sections_to_dir(
             raise ShimWireError(f"section {name} of {length} bytes over the cap")
         path = directory / name
         with open(path, "wb") as out:
-            shutil.copyfileobj(io.BytesIO(_read(buf, length)) if length < (1 << 20)
-                               else _SectionReader(buf, length), out)
+            if length < _SECTION_BLOCK:
+                out.write(_read(buf, length))
+            else:
+                if block is None:
+                    block = memoryview(bytearray(_SECTION_BLOCK))
+                remaining = length
+                while remaining:
+                    got = buf.readinto(block[:min(remaining, _SECTION_BLOCK)])
+                    if not got:
+                        raise ShimWireError("truncated section payload")
+                    out.write(block[:got])
+                    remaining -= got
         if path.stat().st_size != length:
             raise ShimWireError(f"section {name} truncated")
         sections[name] = path
     return sections
-
-
-class _SectionReader(io.RawIOBase):
-    """Bounded view over `buf` for streaming one section to disk."""
-
-    def __init__(self, buf: BinaryIO, length: int):
-        self._buf = buf
-        self._remaining = length
-
-    def readable(self) -> bool:
-        return True
-
-    def read(self, size: int = -1) -> bytes:
-        if self._remaining == 0:
-            return b""
-        if size is None or size < 0:
-            size = self._remaining
-        data = self._buf.read(min(size, self._remaining))
-        if not data:
-            raise ShimWireError("truncated section payload")
-        self._remaining -= len(data)
-        return data
 
 
 def encode_fetch_tail(start: int, end: Optional[int]) -> bytes:
