@@ -54,10 +54,12 @@ class Deployed:
     """The configuration's RSM behind its gateway over a store that the plain
     reference fills, with the program's spans on."""
 
+    #: The deployment's file and the plain writer that fills its store.
+    config_file = "kip405-aes-chunkcache.json"
+    writer = reference
+
     def __init__(self, tmp_path: pathlib.Path, **overrides) -> None:
-        config = json.loads(
-            (BENCHMARK / "configs" / "kip405-aes-chunkcache.json").read_text()
-        )
+        config = json.loads((BENCHMARK / "configs" / self.config_file).read_text())
         self.key, public, private = reference.new_key_pair(tmp_path, harness.KEY_ID)
         store = harness.store_and_keys(tmp_path, public, private)
         self.root = pathlib.Path(store["storage.root"])
@@ -84,8 +86,8 @@ class Deployed:
         data key; returns its name and the metadata a broker would send."""
         name = reference.SegmentName.seeded(SEED, self._ordinal)
         self._ordinal += 1
-        reference.write_segment(self.root, name, self.key, harness.KEY_ID,
-                                self.source[:n_bytes], self.indexes, CHUNK)
+        self.writer.write_segment(self.root, name, self.key, harness.KEY_ID,
+                                  self.source[:n_bytes], self.indexes, CHUNK)
         return name, harness.segment_metadata(name, n_bytes)
 
     def warm(self) -> None:

@@ -197,6 +197,27 @@ def test_prefetch_sub_window_decrypt_programs_compile(one_chip, kernels_on, varl
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("rows,payload,rung", [
+    (1, 3_268_884, 3_670_016),  # a full chunk's frame alone: the steady scan
+    (1, 3_036_421, 3_145_728),  # the ragged chunk's frame alone
+    (2, 3_268_884, 3_670_016),  # two full rows: a segment's entry
+], ids=["one_full_row", "one_ragged_row", "two_rows"])
+def test_compressed_chunk_decrypt_programs_compile(one_chip, kernels_on, rows, payload, rung):
+    """What a chunk cache over a zstd + AES segment launches
+    (`kip405-zstd-aes-chunkcache`): every window varlen, one or two rows of
+    ~3.27 MB frames on the 3.5 MiB rung, the ragged chunk's on the 3.0 MiB
+    one: a handful of programs for every chunk of every segment."""
+    ctx, args, static = varlen_args(rows, payload, rows_on=one_chip, consts_on=one_chip)
+    assert ctx.max_bytes == rung == gcm.bucket_max_bytes(payload)
+    compiled = gcm._packed_jit(True, True, None).lower(
+        *args, **static, decrypt=True
+    ).compile()
+    assert kernel_calls(compiled) == 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= rows * (rung + 16)  # the staged rows, donated
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
 def test_varlen_window_program_compiles_one_bucket_down(one_chip, kernels_on):
     """A compressed window: the varlen program one ladder bucket below
     4 MiB."""

@@ -139,11 +139,12 @@ class PrometheusExporter:
     and `flight_recorder` for the flight section (requests seen, slow-ring
     occupancy, top-3 slowest with tier breakdown) next to it, and
     `chunk_cache` (the RSM's chunk cache tier, if it has one) for that
-    tier's exact counts."""
+    tier's exact counts, and `transform_backend` for its windows'
+    (`dispatch`, where the backend counts them)."""
 
     def __init__(self, registries: Iterable[MetricsRegistry], *, port: int = 0,
                  host: str = "127.0.0.1", tracer=None, flight_recorder=None,
-                 chunk_cache=None):
+                 chunk_cache=None, transform_backend=None):
         regs = list(registries)
         outer = self
 
@@ -180,6 +181,7 @@ class PrometheusExporter:
         self.tracer = tracer
         self.flight_recorder = flight_recorder
         self.chunk_cache = chunk_cache
+        self.transform_backend = transform_backend
         self._server = ThreadingHTTPServer((host, port), Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(
@@ -201,7 +203,10 @@ class PrometheusExporter:
         `chunk_cache` where the deployment has that tier (`ChunkCache.
         counters()`: the foreground's reads, hits and misses, joins of
         loads in flight, degradations, prefetch failures, and the prefetch
-        tasks' own windows and rows)."""
+        tasks' own windows and rows), and `dispatch` where the transform
+        backend counts its windows (`DispatchStats.as_dict()`: windows,
+        rows, launches, transfers, `bytes_in` beside `padded_bytes`,
+        `varlen_windows`, the staging ring's counts)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}
@@ -222,6 +227,9 @@ class PrometheusExporter:
             {"enabled": True, **cache.counters()} if cache is not None
             else {"enabled": False}
         )
+        dispatch_counts = getattr(self.transform_backend, "dispatch_counts", None)
+        if dispatch_counts is not None:
+            out["dispatch"] = dispatch_counts()
         recorder = self.flight_recorder
         out["flight"] = (
             recorder.summary() if recorder is not None else {"enabled": False}

@@ -81,11 +81,12 @@ def main(argv: Optional[list[str]] = None) -> None:
         # The RSM's tracer rides along so /varz serves the span summary
         # (p50/p95/p99 per name) next to /metrics and /healthz; the flight
         # recorder adds the per-request `flight` section (ISSUE 14), the
-        # chunk cache tier its `chunk_cache` counts.
+        # chunk cache tier its `chunk_cache` counts, the transform backend
+        # its windows' (`dispatch`).
         exporter = PrometheusExporter(
             [rsm.metrics.registry], port=args.metrics_port, host=args.host,
             tracer=rsm.tracer, flight_recorder=rsm.flight_recorder,
-            chunk_cache=rsm.chunk_cache,
+            chunk_cache=rsm.chunk_cache, transform_backend=rsm.transform_backend,
         ).start()
     from tieredstorage_tpu.sidecar.http_gateway import SidecarHttpGateway
 

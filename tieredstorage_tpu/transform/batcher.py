@@ -795,7 +795,7 @@ class WindowBatcher:
             return
         backend._note_batched_fetch()
         for e in live:
-            backend._note_batched_window(e.n_bytes, len(e.sizes))
+            backend._note_window(e.n_bytes, len(e.sizes), n_bytes, True)
 
         occupancy = len(live)
         with self._cond:

@@ -153,6 +153,14 @@ class DispatchStats:
     #: that came from the ring, mapped and touched by an earlier window.
     staging_acquired: int = 0
     staging_reused: int = 0
+    #: Windows launched in the varlen form (rows of differing sizes, and
+    #: every window of a compressed segment, whatever its row count), and
+    #: the bytes the windows' rows were staged at: rows x the window's row
+    #: width, which for a varlen window is its rung of
+    #: `ops.gcm.bucket_max_bytes`. Over `bytes_in` it is what the rungs
+    #: cost (before any mesh or kernel row padding).
+    varlen_windows: int = 0
+    padded_bytes: int = 0
 
     @property
     def dispatches_per_window(self) -> float:
@@ -232,6 +240,11 @@ class TpuTransformBackend(TransformBackend):
             self.dispatch_stats = DispatchStats()
         return retired
 
+    def dispatch_counts(self) -> dict:
+        """`DispatchStats.as_dict()` as it stands, for `/varz`'s `dispatch`."""
+        with self._stats_lock:
+            return self.dispatch_stats.as_dict()
+
     @staticmethod
     def thread_dispatch_counters() -> tuple[int, int]:
         """This THREAD's cumulative (GCM dispatches, planned HBM round
@@ -299,14 +312,17 @@ class TpuTransformBackend(TransformBackend):
         batcher = self.batcher
         return (0, 0.0, 0) if batcher is None else batcher.thread_evidence()
 
-    def _note_batched_window(self, n_bytes: int, rows: int) -> None:
-        """Window accounting for a batched window — either direction (the
-        flusher launches; every coalesced window still counts, so
-        `dispatches_per_window` reads `launches/windows <= 1/occupancy`)."""
+    def _note_window(self, n_bytes: int, rows: int, row_bytes: int, varlen: bool) -> None:
+        """One window's payload, rows, staged row width and form. The
+        batcher notes every window a merged launch coalesced (varlen, its
+        rows as wide as the launch's), so `dispatches_per_window` reads
+        `launches/windows <= 1/occupancy`."""
         with self._stats_lock:
             self.dispatch_stats.windows += 1
             self.dispatch_stats.rows += rows
             self.dispatch_stats.bytes_in += n_bytes
+            self.dispatch_stats.padded_bytes += rows * row_bytes
+            self.dispatch_stats.varlen_windows += varlen
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
     def _note_batched_fetch(self) -> None:
@@ -467,13 +483,20 @@ class TpuTransformBackend(TransformBackend):
             )
         return np.frombuffer(os.urandom(IV_SIZE * n), dtype=np.uint8).reshape(n, IV_SIZE)
 
-    def _window_context(self, enc, sizes: list[int]):
+    def _window_context(self, enc, sizes: list[int], compressed: bool = False):
         """The GCM context of a window of these row sizes, its row width and
         whether it is the varlen form. A hit is a dictionary lookup; a miss
-        builds the (key, aad, size)'s constants on the host (`built`)."""
+        builds the (key, aad, size)'s constants on the host (`built`).
+
+        Rows of a compressed segment each have a size of their own, so their
+        windows take the varlen form whatever their row count: the row width
+        is then a rung of `bucket_max_bytes`, and a one-row window shares its
+        program with every chunk on that rung where the fixed form would
+        trace and compile one per distinct size. Encrypt-only rows of one
+        size keep the fixed-shape program."""
         with self.tracer.span("transform.context") as span:
             builds = gcm_ops.thread_context_builds()
-            varlen = len(set(sizes)) != 1
+            varlen = compressed or len(set(sizes)) != 1
             if varlen:
                 ctx = make_varlen_context(enc.data_key, enc.aad, max(sizes))
                 n_bytes = ctx.max_bytes
@@ -631,15 +654,11 @@ class TpuTransformBackend(TransformBackend):
         sizes = [len(c) for c in chunks]
         ivs = self._make_ivs(len(chunks), opts)
 
-        ctx, n_bytes, varlen = self._window_context(enc, sizes)
+        ctx, n_bytes, varlen = self._window_context(enc, sizes, opts.compression)
         packed = self._build_packed(chunks, sizes, ivs, n_bytes, varlen)
         staged = self._stage_packed(packed, varlen)
         out = self._launch_packed(ctx, staged, varlen, decrypt=False)
-        with self._stats_lock:
-            self.dispatch_stats.windows += 1
-            self.dispatch_stats.rows += len(sizes)
-            self.dispatch_stats.bytes_in += sum(sizes)
-            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+        self._note_window(sum(sizes), len(sizes), n_bytes, varlen)
         return ivs, sizes, n_bytes, out, [packed]
 
     @_spanned("transform.encrypt_finish", count=lambda staged: len(staged[1]),
@@ -676,35 +695,40 @@ class TpuTransformBackend(TransformBackend):
         if opts.encryption is not None:
             out = self._decrypt_batch(out, opts)
         if opts.compression:
-            if opts.compression_codec == THUFF:
-                from tieredstorage_tpu.transform import thuff
-
-                return thuff.decompress_batch(out, opts.max_original_chunk_size)
-            if opts.compression_codec == TLZHUFF:
-                from tieredstorage_tpu.transform import lzhuff
-
-                return lzhuff.decompress_batch(out, opts.max_original_chunk_size)
-            if opts.compression_codec != ZSTD:
-                raise ValueError(f"Codec {opts.compression_codec!r} not implemented")
-            if self._use_native():
-                out = native.zstd_decompress_batch(
-                    out, max_decompressed=opts.max_original_chunk_size
-                )
-            else:
-                if zstandard is None:
-                    raise ModuleNotFoundError(
-                        "The 'zstandard' package is required for the 'zstd' "
-                        "codec but is not installed"
-                    )
-                native.checked_frame_content_sizes(out, opts.max_original_chunk_size)
-                # One DCtx per chunk: zstandard (de)compressor objects are not
-                # thread-safe across the pool's workers.
-                out = list(
-                    self._zstd_pool().map(
-                        lambda c: zstandard.ZstdDecompressor().decompress(c), out
-                    )
-                )
+            out = self._decompress_batch(out, opts)
         return out
+
+    @_spanned("transform.decompress")
+    def _decompress_batch(self, chunks: list[bytes], opts: DetransformOptions) -> list[bytes]:
+        """The codec's half of a fetch, after every row's tag has been
+        verified: `transform.compress`'s twin."""
+        if opts.compression_codec == THUFF:
+            from tieredstorage_tpu.transform import thuff
+
+            return thuff.decompress_batch(chunks, opts.max_original_chunk_size)
+        if opts.compression_codec == TLZHUFF:
+            from tieredstorage_tpu.transform import lzhuff
+
+            return lzhuff.decompress_batch(chunks, opts.max_original_chunk_size)
+        if opts.compression_codec != ZSTD:
+            raise ValueError(f"Codec {opts.compression_codec!r} not implemented")
+        if self._use_native():
+            return native.zstd_decompress_batch(
+                chunks, max_decompressed=opts.max_original_chunk_size
+            )
+        if zstandard is None:
+            raise ModuleNotFoundError(
+                "The 'zstandard' package is required for the 'zstd' "
+                "codec but is not installed"
+            )
+        native.checked_frame_content_sizes(chunks, opts.max_original_chunk_size)
+        # One DCtx per chunk: zstandard (de)compressor objects are not
+        # thread-safe across the pool's workers.
+        return list(
+            self._zstd_pool().map(
+                lambda c: zstandard.ZstdDecompressor().decompress(c), chunks
+            )
+        )
 
     @_spanned("transform.decrypt")
     def _decrypt_batch(self, chunks: list[bytes], opts: DetransformOptions) -> list[bytes]:
@@ -735,27 +759,26 @@ class TpuTransformBackend(TransformBackend):
             # Zero-length rows are excluded by the varlen window contract
             # the merged launch uses; such windows take the direct path.
             return batcher.submit(enc, payloads, sizes, ivs, received_tags)
-        return self._decrypt_window(enc, payloads, sizes, ivs, received_tags)
+        return self._decrypt_window(
+            enc, payloads, sizes, ivs, received_tags, opts.compression
+        )
 
     def _decrypt_window(
         self, enc, payloads: list, sizes: list[int], ivs: np.ndarray,
-        received_tags: list,
+        received_tags: list, compressed: bool = False,
     ) -> list[bytes]:
         """The unbatched decrypt window: ONE staging transfer, ONE fused
         launch, ONE fetch for this caller's rows alone. Also the
         batcher's single-waiter fast path (zero added latency at light
         load — including the hot-tier retention hook, which only fires
         here: a merged buffer interleaves requests and is never offered
-        for retention)."""
-        ctx, n_bytes, varlen = self._window_context(enc, sizes)
+        for retention). `compressed` is the manifest's word that the rows
+        are compressed chunks (`_window_context`)."""
+        ctx, n_bytes, varlen = self._window_context(enc, sizes, compressed)
         packed = self._build_packed(payloads, sizes, ivs, n_bytes, varlen)
         staged = self._stage_packed(packed, varlen)
         out = self._launch_packed(ctx, staged, varlen, decrypt=True)
-        with self._stats_lock:
-            self.dispatch_stats.windows += 1
-            self.dispatch_stats.rows += len(sizes)
-            self.dispatch_stats.bytes_in += sum(sizes)
-            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+        self._note_window(sum(sizes), len(sizes), n_bytes, varlen)
 
         with self.tracer.span("transform.d2h_wait"):
             host = np.asarray(out)
