@@ -87,7 +87,7 @@ class TestHttpGatewayPropagation:
         assert storage_span.parent_id == stream_span.span_id
         assert detransform_span.parent_id == stream_span.span_id
         assert stream_span.attributes == {
-            "bytes": md.segment_size_in_bytes, "aborted": False,
+            "bytes": md.segment_size_in_bytes, "views": True, "aborted": False,
         }
         assert detransform_span.attributes["bytes_out"] > 0
 

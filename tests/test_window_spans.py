@@ -187,7 +187,7 @@ def test_fetch_span_once_per_window_or_request(served, name, count, parent):
 def test_fetch_span_attributes(served):
     spans = served["fetch"]
     assert [s.attributes for s in _named(spans, "gateway.reply_stream")] == [
-        {"bytes": CHUNK, "aborted": False}
+        {"bytes": CHUNK, "views": True, "aborted": False}
     ] * 3
     admit = _named(spans, "hot.admit")[0]
     assert admit.attributes["admitted"] is True and admit.attributes["bytes"] >= CHUNK

@@ -239,10 +239,12 @@ class HotWindow:
     def chunk_view(self, chunk_id: int) -> memoryview:
         """ZERO-COPY ranged slice of the pinned host mirror — the hot
         serve (ISSUE 13 satellite, ROADMAP item 3 remainder). The gateway
-        streams the view straight to the socket; no per-chunk ``tobytes``
-        copy. The view holds the mirror's buffer alive (numpy refcount),
-        so an eviction racing a serve can never tear the bytes — at the
-        cost of the mirror lingering while any served view is retained."""
+        streams the view straight to the socket (`FetchChunkEnumeration`
+        slices it, `_reply_stream` hands the slice to ``sendmsg``); no
+        per-chunk ``tobytes``, ``BytesIO`` or block copy on the way. The
+        view holds the mirror's buffer alive (numpy refcount), so an
+        eviction racing a serve can never tear the bytes — at the cost of
+        the mirror lingering while any served view is retained."""
         i = self._row[chunk_id]
         off = self.offsets[i]
         return memoryview(self.mirror)[off : off + self.lens[i]]

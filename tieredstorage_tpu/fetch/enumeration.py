@@ -4,18 +4,22 @@ Reference: core/.../fetch/FetchChunkEnumeration.java — chunk id window from
 the chunk index (ctor :54-70), skip into the first chunk and cap the last
 (:100-131), lazy stream so early close stops fetching (:160-175; the broker
 rarely drains a whole fetch).
+
+Where the reference wraps each chunk in a stream, this one yields views: the
+plaintext a tier returned (a cache-held or freshly decrypted `bytes`, a slice
+of a hot-tier mirror) is never sliced into a copy or wrapped in a `BytesIO`,
+so the gateway can hand the socket a `memoryview` of that same object.
 """
 
 from __future__ import annotations
 
-import io
 from typing import BinaryIO, Iterator
 
 from tieredstorage_tpu.errors import RemoteResourceNotFoundException
 from tieredstorage_tpu.fetch.chunk_manager import ChunkManager
 from tieredstorage_tpu.manifest.segment_manifest import SegmentManifestV1
 from tieredstorage_tpu.storage.core import BytesRange, KeyNotFoundException, ObjectKey
-from tieredstorage_tpu.utils.streams import BoundedStream, LazyConcatStream
+from tieredstorage_tpu.utils.streams import ViewConcatStream
 
 
 class FetchChunkEnumeration:
@@ -43,19 +47,22 @@ class FetchChunkEnumeration:
         self._skip_in_first = byte_range.from_position - first_chunk.original_position
         self._total = min(byte_range.size, index.original_file_size - byte_range.from_position)
 
-    def _parts(self) -> Iterator[BinaryIO]:
+    def _parts(self) -> Iterator[memoryview]:
+        """The range's bytes, chunk by chunk, each a view of the object the
+        chunk manager returned: a chunk is asked for only once the consumer
+        has drained the one before, and closing the generator ends it."""
         remaining = self._total
         try:
             for chunk_id in range(self._first_chunk_id, self._last_chunk_id + 1):
                 data = self._chunk_manager.get_chunks(self._key, self._manifest, [chunk_id])[0]
-                if chunk_id == self._first_chunk_id:
-                    data = data[self._skip_in_first :]
-                if len(data) > remaining:
-                    data = data[:remaining]
-                remaining -= len(data)
-                yield io.BytesIO(data)
+                skip = self._skip_in_first if chunk_id == self._first_chunk_id else 0
+                view = memoryview(data)[skip:][:remaining]
+                remaining -= len(view)
+                yield view
         except KeyNotFoundException as e:
             raise RemoteResourceNotFoundException(str(e)) from e
 
     def to_stream(self) -> BinaryIO:
-        return BoundedStream(LazyConcatStream(self._parts()), self._total)
+        """A lazy `BinaryIO` over the range that also hands its bytes out as
+        views (`read_view`, `read_views`)."""
+        return ViewConcatStream(self._parts())

@@ -140,11 +140,12 @@ class PrometheusExporter:
     occupancy, top-3 slowest with tier breakdown) next to it, and
     `chunk_cache` (the RSM's chunk cache tier, if it has one) for that
     tier's exact counts, and `transform_backend` for its windows'
-    (`dispatch`, where the backend counts them)."""
+    (`dispatch`, where the backend counts them), and `gateway` (the
+    `SidecarHttpGateway`) for the bytes of its copy bodies and replies."""
 
     def __init__(self, registries: Iterable[MetricsRegistry], *, port: int = 0,
                  host: str = "127.0.0.1", tracer=None, flight_recorder=None,
-                 chunk_cache=None, transform_backend=None):
+                 chunk_cache=None, transform_backend=None, gateway=None):
         regs = list(registries)
         outer = self
 
@@ -182,6 +183,7 @@ class PrometheusExporter:
         self.flight_recorder = flight_recorder
         self.chunk_cache = chunk_cache
         self.transform_backend = transform_backend
+        self.gateway = gateway
         self._server = ThreadingHTTPServer((host, port), Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(
@@ -206,7 +208,10 @@ class PrometheusExporter:
         tasks' own windows and rows), and `dispatch` where the transform
         backend counts its windows (`DispatchStats.as_dict()`: windows,
         rows, launches, transfers, `bytes_in` beside `padded_bytes`,
-        `varlen_windows`, the staging ring's counts)."""
+        `varlen_windows`, the staging ring's counts), and `gateway` where
+        one is wired (`SidecarHttpGateway.counters()`: the bytes of whole
+        copy bodies and those written locally, the bytes of streamed
+        replies and those of them handed to the socket as views)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}
@@ -230,6 +235,8 @@ class PrometheusExporter:
         dispatch_counts = getattr(self.transform_backend, "dispatch_counts", None)
         if dispatch_counts is not None:
             out["dispatch"] = dispatch_counts()
+        if self.gateway is not None:
+            out["gateway"] = self.gateway.counters()
         recorder = self.flight_recorder
         out["flight"] = (
             recorder.summary() if recorder is not None else {"enabled": False}

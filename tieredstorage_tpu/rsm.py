@@ -1566,8 +1566,15 @@ class RemoteStorageManager:
         (FetchChunkEnumeration fetches chunk N+1 only when the consumer
         reads past chunk N, and close() stops the enumeration early), so an
         abandoned read costs nothing and raises nothing; over the sidecar
-        boundary a reader that hangs up ends the gateway's stream at its
-        next write.
+        boundary a reader that hangs up ends the gateway's stream at the
+        first write the kernel refuses (what its socket buffers had already
+        taken, and the chunk read for it, is spent).
+
+        The stream is a `utils.streams.ViewConcatStream`: `read(n)` and
+        `readinto` copy once, and `read_view(n)` / `read_views(n)` hand out
+        the next bytes as `memoryview`s of the very objects the fetch tiers
+        returned (a cache-held or freshly decrypted `bytes`, a hot-tier
+        mirror), which the gateway passes to the socket as they are.
         """
         config = self._require_configured()
         if start_position < 0:

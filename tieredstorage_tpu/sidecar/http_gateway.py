@@ -305,26 +305,55 @@ class _Handler(BaseHTTPRequestHandler):
         happens on the first read. Pull that block BEFORE committing the
         status line so not-found maps to a clean 404 instead of a
         truncated 200. A failure later mid-stream can only abort the
-        connection (the shim surfaces that as a transport error)."""
+        connection (the shim surfaces that as a transport error).
+
+        A stream that offers views (`read_views`: `fetch_log_segment`'s) is
+        drained through them, so a block is `memoryview`s of the plaintext
+        the fetch tiers returned and the kernel's copy into the socket is
+        the only one; any other stream (`fetch_index`'s) is read. Either
+        way a block goes out with its chunk-size line and trailer in one
+        gather write: a 2-byte trailer sent on its own behind unacknowledged
+        data is what Nagle holds back. A block is `_STREAM_BLOCK` bytes
+        until the stream ends, across the segment's chunks: a short block
+        where a chunk ends, with the next chunk's bytes in a later write,
+        stalled a reader whose bytes span the two by a TCP timer's 200 ms
+        on the v5e's host (PERF.md section 6, PR 34)."""
         tracer = getattr(self.rsm, "tracer", NOOP_TRACER)
-        with contextlib.closing(stream), \
-                tracer.span("gateway.reply_stream", bytes=0, aborted=False) as span:
-            first = stream.read(_STREAM_BLOCK)
+        views = hasattr(stream, "read_views")
+        take = stream.read_views if views else lambda size: [stream.read(size)]
+        sent = 0
+        with contextlib.closing(stream), tracer.span(
+                "gateway.reply_stream", bytes=0, views=views, aborted=False) as span:
+            block = take(_STREAM_BLOCK)
             self.send_response(200)
             self.send_header("Transfer-Encoding", "chunked")
             self.end_headers()
             try:
-                block = first
-                while block:
-                    self.wfile.write(b"%x\r\n" % len(block) + block + b"\r\n")
-                    if span is not None:
-                        span.attributes["bytes"] += len(block)
-                    block = stream.read(_STREAM_BLOCK)
+                while size := sum(map(len, block)):
+                    self._send_gathered([b"%x\r\n" % size, *block, b"\r\n"])
+                    sent += size
+                    block = take(_STREAM_BLOCK)
                 self.wfile.write(b"0\r\n\r\n")
             except Exception as exc:
                 if span is not None:  # the reader had left, or the stream failed
                     span.attributes["aborted"] = True
                 raise _StreamAborted() from exc
+            finally:
+                if span is not None:
+                    span.attributes["bytes"] = sent
+                self.gateway.count_reply(sent=sent, as_views=sent if views else 0)
+
+    def _send_gathered(self, parts: list) -> None:
+        """`parts` to the socket as one gather write, again until the kernel
+        has taken every byte. `wfile` is unbuffered, so nothing of an earlier
+        `wfile.write` can be overtaken."""
+        parts = [memoryview(part) for part in parts]
+        while parts:
+            taken = self.connection.sendmsg(parts)
+            while parts and taken >= len(parts[0]):
+                taken -= len(parts.pop(0))
+            if taken:
+                parts[0] = parts[0][taken:]
 
     def _fail(self, exc: Exception) -> None:
         headers = None
@@ -748,6 +777,11 @@ class SidecarHttpGateway:
         #: (the section files: one write per byte).
         self.copy_body_bytes = 0
         self.copy_body_bytes_written = 0
+        #: Exact counts over streamed replies (/v1/fetch, /v1/fetch-index),
+        #: aborted ones too: the body bytes the kernel took, and those of
+        #: them handed to it as views of what the fetch tiers returned.
+        self.reply_bytes_sent = 0
+        self.reply_bytes_as_views = 0
         self._counts_lock = threading.Lock()
         if max_workers is None:
             max_workers = getattr(rsm, "sidecar_http_max_workers", 32)
@@ -763,6 +797,21 @@ class SidecarHttpGateway:
         with self._counts_lock:
             self.copy_body_bytes += received
             self.copy_body_bytes_written += written
+
+    def count_reply(self, *, sent: int, as_views: int) -> None:
+        with self._counts_lock:
+            self.reply_bytes_sent += sent
+            self.reply_bytes_as_views += as_views
+
+    def counters(self) -> dict:
+        """The `/varz` `gateway` section: the exact counts above."""
+        with self._counts_lock:
+            return {
+                "copy_body_bytes": self.copy_body_bytes,
+                "copy_body_bytes_written": self.copy_body_bytes_written,
+                "reply_bytes_sent": self.reply_bytes_sent,
+                "reply_bytes_as_views": self.reply_bytes_as_views,
+            }
 
     def start(self) -> "SidecarHttpGateway":
         self._thread = threading.Thread(

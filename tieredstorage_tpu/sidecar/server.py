@@ -72,6 +72,9 @@ def main(argv: Optional[list[str]] = None) -> None:
         from tieredstorage_tpu.fleet import parse_instances
 
         rsm.set_fleet_peers(parse_instances(args.fleet_peers.split(",")))
+    from tieredstorage_tpu.sidecar.http_gateway import SidecarHttpGateway
+
+    gateway = SidecarHttpGateway(rsm, port=args.port, host=args.host)
     exporter = None
     if args.metrics_port is not None:
         from tieredstorage_tpu.metrics.prometheus import PrometheusExporter
@@ -82,15 +85,15 @@ def main(argv: Optional[list[str]] = None) -> None:
         # (p50/p95/p99 per name) next to /metrics and /healthz; the flight
         # recorder adds the per-request `flight` section (ISSUE 14), the
         # chunk cache tier its `chunk_cache` counts, the transform backend
-        # its windows' (`dispatch`).
+        # its windows' (`dispatch`), the gateway its bodies' and replies'
+        # bytes (`gateway`).
         exporter = PrometheusExporter(
             [rsm.metrics.registry], port=args.metrics_port, host=args.host,
             tracer=rsm.tracer, flight_recorder=rsm.flight_recorder,
             chunk_cache=rsm.chunk_cache, transform_backend=rsm.transform_backend,
+            gateway=gateway,
         ).start()
-    from tieredstorage_tpu.sidecar.http_gateway import SidecarHttpGateway
-
-    gateway = SidecarHttpGateway(rsm, port=args.port, host=args.host).start()
+    gateway.start()
     # Gossip membership starts only once the gateway can answer inbound
     # /fleet/gossip probes (a no-op unless fleet.gossip.enabled).
     rsm.start_fleet_gossip()

@@ -1,4 +1,5 @@
-"""Stream helpers: bounded reads, chunked copy, lazy concatenation.
+"""Stream helpers: bounded reads, chunked copy, lazy concatenation (of
+streams for an upload, of views for a fetch).
 
 Host-side equivalents of the reference's commons-io BoundedInputStream usage
 (core/.../fetch/FetchChunkEnumeration.java:100-131) and SequenceInputStream
@@ -8,6 +9,7 @@ composition (core/.../transform/DetransformFinisher.java:48-53).
 from __future__ import annotations
 
 import io
+import sys
 from typing import BinaryIO, Callable, Iterator, Optional
 
 _COPY_BUF = 1024 * 1024
@@ -89,6 +91,75 @@ class LazyConcatStream(io.RawIOBase):
             if self._current is not None:
                 self._current.close()
                 self._current = None
+            close_all = getattr(self._parts, "close", None)
+            if close_all is not None:
+                close_all()
+        finally:
+            super().close()
+
+
+class ViewConcatStream(io.RawIOBase):
+    """Concatenates buffers produced on demand by an iterator of views, and
+    hands them on as views.
+
+    The fetch path's `LazyConcatStream` (fetch/enumeration.py): the iterator
+    is advanced only once every byte of the view before has been taken, and
+    closing the stream early stops the iteration. `read_view` and
+    `read_views` are the ways out that copy nothing: the gateway hands what
+    they return to the socket. `read` and `readinto` are the `BinaryIO` of
+    in-process callers, one copy each. The iterator bounds the stream: every
+    view is served whole.
+    """
+
+    def __init__(self, parts: Iterator[memoryview]):
+        self._parts = parts
+        #: What is left of the view being served.
+        self._current: Optional[memoryview] = None
+
+    def readable(self) -> bool:
+        return True
+
+    def read_view(self, size: int) -> memoryview:
+        """The next at most `size` bytes as a view of the buffer the iterator
+        yielded them in (`.obj` is that buffer's owner, which the view keeps
+        alive): never across two buffers, so fewer than `size` says nothing
+        of the stream's end; empty only there."""
+        while not self._current:
+            try:
+                self._current = next(self._parts)
+            except StopIteration:
+                self._current = None
+                return memoryview(b"")
+        view = self._current[:size]
+        self._current = self._current[size:]
+        return view
+
+    def read_views(self, size: int) -> list[memoryview]:
+        """The next `size` bytes (all that are left when negative) as the
+        views they lie in, in order: fewer bytes only at the stream's end,
+        none only there. A buffer that ends inside the range is followed by
+        the next one's view, so a block for a gather write is whole."""
+        left = sys.maxsize if size is None or size < 0 else size
+        views = []
+        while left and (view := self.read_view(left)):
+            views.append(view)
+            left -= len(view)
+        return views
+
+    def read(self, size: int = -1) -> bytes:
+        return b"".join(self.read_views(size))
+
+    def readinto(self, b) -> int:
+        out = memoryview(b).cast("B")
+        filled = 0
+        for view in self.read_views(len(out)):
+            out[filled : filled + len(view)] = view
+            filled += len(view)
+        return filled
+
+    def close(self) -> None:
+        try:
+            self._current = None
             close_all = getattr(self._parts, "close", None)
             if close_all is not None:
                 close_all()
