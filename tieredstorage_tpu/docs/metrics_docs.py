@@ -318,7 +318,12 @@ def generate() -> str:
         "Prometheus endpoint serves them as ``_bucket`` (cumulative ``le``",
         "labels), ``_sum``, and ``_count`` series; all other names are gauges",
         "or windowed rate/avg/max stats. See ``docs/tracing.rst`` for the",
-        "request-tracing layer these histograms summarize.",
+        "request-tracing layer these histograms summarize, and for ``/varz``,",
+        "where exact counts sit beside the spans: under ``S3Storage`` its ``s3``",
+        "section (``S3Storage.counters()``) has the ``s3-client-metrics``",
+        "group's ``-requests-total`` and ``-errors-total`` as they stand, with",
+        "the connections the client's pool dialled, retries, and the body bytes",
+        "sent as parts and read of ranged replies.",
         "",
     ])
     for heading, collected in [

@@ -145,7 +145,8 @@ class PrometheusExporter:
 
     def __init__(self, registries: Iterable[MetricsRegistry], *, port: int = 0,
                  host: str = "127.0.0.1", tracer=None, flight_recorder=None,
-                 chunk_cache=None, transform_backend=None, gateway=None):
+                 chunk_cache=None, transform_backend=None, gateway=None,
+                 storage_backend=None):
         regs = list(registries)
         outer = self
 
@@ -184,6 +185,7 @@ class PrometheusExporter:
         self.chunk_cache = chunk_cache
         self.transform_backend = transform_backend
         self.gateway = gateway
+        self.storage_backend = storage_backend
         self._server = ThreadingHTTPServer((host, port), Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(
@@ -211,7 +213,11 @@ class PrometheusExporter:
         `varlen_windows`, the staging ring's counts), and `gateway` where
         one is wired (`SidecarHttpGateway.counters()`: the bytes of whole
         copy bodies and those written locally, the bytes of streamed
-        replies and those of them handed to the socket as views)."""
+        replies and those of them handed to the socket as views), and `s3`
+        where the store is `S3Storage` (`S3Storage.counters()`: attempts by
+        request class, error totals, connections dialled, retries, bytes
+        sent as parts and read of ranged bodies; absent under another
+        store)."""
         tracer = self.tracer
         if tracer is None:
             out: dict = {"tracing": False}
@@ -237,6 +243,9 @@ class PrometheusExporter:
             out["dispatch"] = dispatch_counts()
         if self.gateway is not None:
             out["gateway"] = self.gateway.counters()
+        store_counters = getattr(self.storage_backend, "counters", None)
+        if store_counters is not None:
+            out["s3"] = store_counters()
         recorder = self.flight_recorder
         out["flight"] = (
             recorder.summary() if recorder is not None else {"enabled": False}

@@ -143,6 +143,7 @@ class RemoteStorageManager:
     def __init__(self) -> None:
         self._config: Optional[RemoteStorageManagerConfig] = None
         self._storage: Optional[StorageBackend] = None
+        self._storage_backend: Optional[StorageBackend] = None
         self._transform_backend = None
         self._object_key_factory: Optional[ObjectKeyFactory] = None
         self._rsa: Optional[RsaEncryptionProvider] = None
@@ -218,6 +219,11 @@ class RemoteStorageManager:
 
         storage = config.storage_backend_class()
         storage.configure(config.storage_configs())
+        if hasattr(storage, "tracer"):
+            # A store that traces its own calls (S3Storage's `s3.*` spans,
+            # children of `storage.upload` and `storage.fetch_chunks`).
+            storage.tracer = self.tracer
+        self._storage_backend = storage
         storage = self._wrap_storage_resilience(config, storage)
         self._storage = storage
 
@@ -395,6 +401,14 @@ class RemoteStorageManager:
         None before `configure` — its `dispatch_stats` are what smoke runs
         and benchmarks report per phase."""
         return self._transform_backend
+
+    @property
+    def storage_backend(self) -> Optional[StorageBackend]:
+        """The configured store (`storage.backend.class`) beneath the
+        resilience decorators, or None before `configure`; where it counts
+        its requests (`S3Storage.counters()`) they are `/varz`'s `s3`
+        section."""
+        return self._storage_backend
 
     @property
     def chunk_cache(self) -> Optional[ChunkCache]:

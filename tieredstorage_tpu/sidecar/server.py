@@ -86,12 +86,12 @@ def main(argv: Optional[list[str]] = None) -> None:
         # recorder adds the per-request `flight` section (ISSUE 14), the
         # chunk cache tier its `chunk_cache` counts, the transform backend
         # its windows' (`dispatch`), the gateway its bodies' and replies'
-        # bytes (`gateway`).
+        # bytes (`gateway`), an S3 store its requests' (`s3`).
         exporter = PrometheusExporter(
             [rsm.metrics.registry], port=args.metrics_port, host=args.host,
             tracer=rsm.tracer, flight_recorder=rsm.flight_recorder,
             chunk_cache=rsm.chunk_cache, transform_backend=rsm.transform_backend,
-            gateway=gateway,
+            gateway=gateway, storage_backend=rsm.storage_backend,
         ).start()
     gateway.start()
     # Gossip membership starts only once the gateway can answer inbound

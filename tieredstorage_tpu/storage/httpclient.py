@@ -39,6 +39,7 @@ import io
 import random
 import socket
 import ssl
+import threading
 import time
 from datetime import datetime, timezone
 from email.utils import parsedate_to_datetime
@@ -370,6 +371,9 @@ class HttpClient:
         self.observer = observer
         self.retry = retry if retry is not None else RetryPolicy()
         self.pool_wait_timeout_s = pool_wait_timeout_s
+        #: Attempts beyond a call's first (the retry loops below), exact.
+        self.retries_total = 0
+        self._retries_lock = threading.Lock()
         # Late-bound factory: tests monkeypatch `_new_connection` per
         # instance after construction, and the pool must see the override.
         self._pool = _ConnectionPool(
@@ -446,6 +450,7 @@ class HttpClient:
                     raise
                 time.sleep(delay)
                 retry_number += 1
+                self._count_retry()
                 continue
             if (
                 replay_safe
@@ -458,6 +463,7 @@ class HttpClient:
                 if deadline is None or time.monotonic() + delay <= deadline:
                     time.sleep(delay)
                     retry_number += 1
+                    self._count_retry()
                     continue
             return resp
 
@@ -529,6 +535,7 @@ class HttpClient:
                     raise
                 time.sleep(delay)
                 retry_number += 1
+                self._count_retry()
                 continue
             if status in policy.retry_statuses and retry_number < policy.max_attempts - 1:
                 retry_after = _parse_retry_after(hdrs.get("retry-after", ""))
@@ -537,6 +544,7 @@ class HttpClient:
                     stream.close()
                     time.sleep(delay)
                     retry_number += 1
+                    self._count_retry()
                     continue
             return status, hdrs, stream
 
@@ -560,6 +568,10 @@ class HttpClient:
         return resp.status, hdrs, _StreamedBody(resp, conn, self._pool)
 
     _IDEMPOTENT = frozenset({"GET", "HEAD", "PUT", "DELETE"})
+
+    def _count_retry(self) -> None:
+        with self._retries_lock:
+            self.retries_total += 1
 
     @staticmethod
     def _effective_deadline(policy: RetryPolicy) -> Optional[float]:

@@ -82,6 +82,14 @@ class RequestMetricCollector:
         )
         return sensor
 
+    def total(self, name: str) -> float:
+        """The cumulative metric `name` of this group, 0 where nothing has
+        been recorded under it yet (sensors are made on first use)."""
+        try:
+            return self.registry.value(MetricName.of(name, self.group))
+        except KeyError:
+            return 0.0
+
     def observe(
         self,
         method: str,

@@ -11,6 +11,8 @@ AbortMultipartUpload.
 from __future__ import annotations
 
 import hashlib
+import io
+import threading
 import xml.etree.ElementTree as ET
 from typing import BinaryIO, Mapping, Optional
 from urllib.parse import quote
@@ -23,6 +25,7 @@ from tieredstorage_tpu.storage.httpclient import (
     SocketFactory,
 )
 from tieredstorage_tpu.storage.s3.signer import SigV4Signer
+from tieredstorage_tpu.utils.tracing import NOOP_TRACER, Span, Tracer
 
 
 class S3ApiError(Exception):
@@ -44,6 +47,49 @@ def _parse_error(resp: HttpResponse) -> S3ApiError:
     return S3ApiError(resp.status, code, message)
 
 
+class _ObjectBody(io.RawIOBase):
+    """A GetObject reply's body: counts what is read of it, and ends the
+    call's `s3.get_object` span at its last read or its close, whichever is
+    later. Closing closes the body underneath (which hands its pooled
+    connection back, httpclient._StreamedBody)."""
+
+    def __init__(self, body: BinaryIO, client: "S3Client", span: Optional[Span],
+                 ranged: bool) -> None:
+        self._body = body
+        self._client = client
+        self._span = span
+        self._ranged = ranged
+        self._bytes = 0
+
+    def readable(self) -> bool:
+        return True
+
+    def _took(self, n: int) -> None:
+        self._bytes += n
+        self._client.tracer.extend(self._span)
+
+    def read(self, size: int = -1) -> bytes:
+        data = self._body.read(size)
+        self._took(len(data))
+        return data
+
+    def readinto(self, b) -> int:
+        n = self._body.readinto(b)
+        self._took(n or 0)
+        return n
+
+    def close(self) -> None:
+        if self.closed:
+            return
+        try:
+            self._body.close()
+        finally:
+            if self._ranged:
+                self._client.count_bytes(received_ranged=self._bytes)
+            self._client.tracer.extend(self._span, bytes=self._bytes)
+            super().close()
+
+
 class S3Client:
     def __init__(
         self,
@@ -60,8 +106,17 @@ class S3Client:
         socket_factory: Optional[SocketFactory] = None,
         observer: Optional[Observer] = None,
         retry: Optional[RetryPolicy] = None,
+        tracer: Tracer = NOOP_TRACER,
     ) -> None:
         self.bucket = bucket
+        #: The `s3.*` spans: one around each operation below, `s3.sign` a
+        #: child of each, so that an operation's self time is the wire.
+        self.tracer = tracer
+        #: Exact counts: body bytes sent as parts (UploadPart calls answered
+        #: 200) and body bytes read of ranged GetObject replies (206).
+        self.bytes_sent_as_parts = 0
+        self.bytes_received_ranged = 0
+        self._counts_lock = threading.Lock()
         self.checksum_check = checksum_check
         if endpoint_url is None:
             host = (
@@ -112,8 +167,14 @@ class S3Client:
         if extra:
             headers.update(extra)
         if self.signer is not None:
-            headers = self.signer.sign(method, path, query, headers, payload)
+            with self.tracer.span("s3.sign"):
+                headers = self.signer.sign(method, path, query, headers, payload)
         return headers
+
+    def count_bytes(self, *, sent_as_parts: int = 0, received_ranged: int = 0) -> None:
+        with self._counts_lock:
+            self.bytes_sent_as_parts += sent_as_parts
+            self.bytes_received_ranged += received_ranged
 
     @staticmethod
     def _query_string(query: Mapping[str, str]) -> str:
@@ -156,7 +217,8 @@ class S3Client:
             import base64
 
             extra["Content-MD5"] = base64.b64encode(hashlib.md5(data).digest()).decode()
-        self._call("PUT", key, body=data, extra_headers=extra)
+        with self.tracer.span("s3.put_object", bytes=len(data)):
+            self._call("PUT", key, body=data, extra_headers=extra)
 
     def get_object_stream(
         self, key: str, byte_range: Optional[tuple[int, int]] = None
@@ -165,8 +227,15 @@ class S3Client:
         extra: dict[str, str] = {}
         if byte_range is not None:
             extra["Range"] = f"bytes={byte_range[0]}-{byte_range[1]}"
-        headers = self._headers("GET", path, {}, b"", extra)
-        return self.http.request_stream("GET", path, headers=headers)
+        # The span ends where the body does: `_ObjectBody` moves its end.
+        with self.tracer.span("s3.get_object", ranged=byte_range is not None) as span:
+            headers = self._headers("GET", path, {}, b"", extra)
+            status, reply_headers, body = self.http.request_stream(
+                "GET", path, headers=headers
+            )
+            if span is not None:
+                span.attributes["status"] = status
+        return status, reply_headers, _ObjectBody(body, self, span, status == 206)
 
     def delete_object(self, key: str) -> None:
         self._call("DELETE", key, ok=(204, 200))
@@ -238,7 +307,8 @@ class S3Client:
         # just opens a second upload id whose parts are never completed, and
         # the abort-on-error path (multipart.py) cleans the one we keep a
         # handle to; the AWS SDK retries this call for the same reason.
-        resp = self._call("POST", key, query={"uploads": ""}, idempotent=True)
+        with self.tracer.span("s3.create_multipart_upload"):
+            resp = self._call("POST", key, query={"uploads": ""}, idempotent=True)
         root = ET.fromstring(resp.body)
         ns = root.tag.partition("}")[0] + "}" if root.tag.startswith("{") else ""
         upload_id = root.findtext(f"{ns}UploadId")
@@ -252,13 +322,15 @@ class S3Client:
             import base64
 
             extra["Content-MD5"] = base64.b64encode(hashlib.md5(data).digest()).decode()
-        resp = self._call(
-            "PUT",
-            key,
-            query={"partNumber": str(part_number), "uploadId": upload_id},
-            body=data,
-            extra_headers=extra,
-        )
+        with self.tracer.span("s3.upload_part", part=part_number, bytes=len(data)):
+            resp = self._call(
+                "PUT",
+                key,
+                query={"partNumber": str(part_number), "uploadId": upload_id},
+                body=data,
+                extra_headers=extra,
+            )
+        self.count_bytes(sent_as_parts=len(data))
         etag = resp.header("etag", "")
         if not etag:
             # Fail here, not at CompleteMultipartUpload, where a blank ETag
@@ -278,7 +350,8 @@ class S3Client:
             ET.SubElement(part, "PartNumber").text = str(number)
             ET.SubElement(part, "ETag").text = etag
         body = ET.tostring(root, encoding="utf-8", xml_declaration=True)
-        resp = self._call("POST", key, query={"uploadId": upload_id}, body=body)
+        with self.tracer.span("s3.complete_multipart_upload", parts=len(etags)):
+            resp = self._call("POST", key, query={"uploadId": upload_id}, body=body)
         # Complete can return 200 with an error document.
         try:
             doc = ET.fromstring(resp.body)
@@ -288,6 +361,7 @@ class S3Client:
             raise _parse_error(resp)
 
     def abort_multipart_upload(self, key: str, upload_id: str) -> None:
+        self.tracer.event("s3.abort_multipart_upload", key=key)
         self._call("DELETE", key, query={"uploadId": upload_id}, ok=(204, 200))
 
     def close(self) -> None:

@@ -39,13 +39,21 @@ class S3MultiPartOutputStream(io.RawIOBase):
     def write(self, data) -> int:
         if self.closed or self._aborted:
             raise ValueError("Stream is closed")
-        view = memoryview(bytes(data))
-        n = len(view)
+        # `s3.part_buffer` spans: this stream's own copies of every byte,
+        # into and out of the part buffer; the client's calls have theirs.
+        span = self.client.tracer.span
+        with span("s3.part_buffer"):
+            view = memoryview(bytes(data))
+            n = len(view)
         try:
-            self._buffer.extend(view)
+            with span("s3.part_buffer"):
+                self._buffer.extend(view)
             while len(self._buffer) >= self.part_size:
-                self._flush_part(self._buffer[: self.part_size])
-                del self._buffer[: self.part_size]
+                with span("s3.part_buffer"):
+                    part = self._buffer[: self.part_size]
+                self._flush_part(part)
+                with span("s3.part_buffer"):
+                    del self._buffer[: self.part_size]
         except Exception:
             self.abort()
             raise
@@ -56,7 +64,9 @@ class S3MultiPartOutputStream(io.RawIOBase):
         if self._upload_id is None:
             self._upload_id = self.client.create_multipart_upload(self.key)
         self._part_number += 1
-        etag = self.client.upload_part(self.key, self._upload_id, self._part_number, bytes(data))
+        with self.client.tracer.span("s3.part_buffer"):
+            body = bytes(data)
+        etag = self.client.upload_part(self.key, self._upload_id, self._part_number, body)
         self._etags.append((self._part_number, etag))
 
     def abort(self) -> None:
@@ -86,7 +96,9 @@ class S3MultiPartOutputStream(io.RawIOBase):
                 if self._upload_id is None:
                     # Whole object fit in one buffer: plain PutObject
                     # (cheaper than a 1-part multipart round trip).
-                    self.client.put_object(self.key, bytes(self._buffer))
+                    with self.client.tracer.span("s3.part_buffer"):
+                        body = bytes(self._buffer)
+                    self.client.put_object(self.key, body)
                 else:
                     if self._buffer:
                         self._flush_part(self._buffer)

@@ -22,8 +22,16 @@ from tieredstorage_tpu.storage.proxy import ProxyConfig, socks5_socket_factory
 from tieredstorage_tpu.storage.s3.client import S3ApiError, S3Client
 from tieredstorage_tpu.storage.s3.config import S3StorageConfig
 from tieredstorage_tpu.storage.s3.multipart import S3MultiPartOutputStream
+from tieredstorage_tpu.utils.tracing import NOOP_TRACER, Tracer
 
 _COPY_BUFFER = 1024 * 1024
+
+#: Request classes and error kinds of `S3MetricCollector` that `counters()` reports.
+_REQUEST_CLASSES = (
+    "upload-part", "put-object", "get-object", "create-multipart-upload",
+    "complete-multipart-upload", "abort-multipart-upload",
+)
+_ERROR_KINDS = ("throttling", "server", "io")
 
 
 class S3Storage(StorageBackend):
@@ -31,6 +39,19 @@ class S3Storage(StorageBackend):
         self.client: Optional[S3Client] = None
         self.part_size = 0
         self._metric_collector = None
+        self._tracer: Tracer = NOOP_TRACER
+
+    @property
+    def tracer(self) -> Tracer:
+        """The tracer of the client's `s3.*` spans; the RSM hands its own
+        over after `configure` (rsm.py), and until then nothing is traced."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer: Tracer) -> None:
+        self._tracer = tracer
+        if self.client is not None:
+            self.client.tracer = tracer
 
     def configure(self, configs: Mapping[str, object]) -> None:
         config = S3StorageConfig(configs)
@@ -68,6 +89,7 @@ class S3Storage(StorageBackend):
             socket_factory=socks5_socket_factory(proxy),
             observer=self._metric_collector.observe,
             retry=retry,
+            tracer=self._tracer,
         )
 
     def _require_client(self) -> S3Client:
@@ -158,6 +180,25 @@ class S3Storage(StorageBackend):
     @property
     def metrics(self):
         return self._metric_collector
+
+    def counters(self) -> dict:
+        """Exact counts of the S3 path as they stand (`/varz` `s3`): attempts
+        by request class and error totals by kind (the collector the client
+        feeds, one observation an attempt), connections the pool has dialled,
+        retries (attempts beyond a call's first), body bytes sent as parts
+        and body bytes read of ranged GetObject replies."""
+        client, collector = self._require_client(), self._metric_collector
+        out = {
+            f"{name}-requests": int(collector.total(f"{name}-requests-total"))
+            for name in _REQUEST_CLASSES
+        }
+        for kind in _ERROR_KINDS:
+            out[f"{kind}-errors"] = int(collector.total(f"{kind}-errors-total"))
+        out["connections_created"] = client.http.pool.created_total
+        out["retries"] = client.http.retries_total
+        out["bytes_sent_as_parts"] = client.bytes_sent_as_parts
+        out["bytes_received_ranged"] = client.bytes_received_ranged
+        return out
 
     def close(self) -> None:
         if self.client is not None:

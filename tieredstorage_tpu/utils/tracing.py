@@ -270,6 +270,18 @@ class Tracer:
         self._record(s)
         return s
 
+    def extend(self, span: Optional[Span], **attributes) -> None:
+        """Move a recorded span's end to now (and add `attributes`): for work
+        that outlives the block that opened the span, such as a streamed
+        body read after the call that returned it. The span keeps its place
+        in the tree and is off every thread's stack, so it may be extended
+        from any thread and any number of times; None (tracing off) is a
+        no-op."""
+        if span is None:
+            return
+        span.attributes.update(attributes)
+        span.end_s = time.perf_counter()
+
     # --------------------------------------------------------------- readers
     def spans(self, name: Optional[str] = None) -> list[Span]:
         with self._lock:
