@@ -137,12 +137,12 @@ int ts_crypto_available() { return crypto().ok ? 1 : 0; }
 // Worst-case compressed size for a chunk of `size` bytes.
 size_t ts_zstd_bound(size_t size) { return ZSTD_compressBound(size); }
 
-// Compress n chunks. Inputs are consecutive in `in` at `in_offsets[i]` with
-// `in_sizes[i]`; chunk i's frame is written at out + i*out_stride, its size
-// into out_sizes[i]. Returns 0 on success, or 1+index of the failing chunk.
-int ts_zstd_compress_batch(const uint8_t *in, const uint64_t *in_offsets,
-                           const uint64_t *in_sizes, int n, int level,
-                           uint8_t *out, uint64_t out_stride,
+// Compress n chunks. Chunk i is the `in_sizes[i]` bytes at `in[i]`, read
+// where the caller holds them (no gathered copy); its frame is written at
+// out + i*out_stride, its size into out_sizes[i]. Returns 0 on success, or
+// 1+index of the failing chunk.
+int ts_zstd_compress_batch(const uint8_t *const *in, const uint64_t *in_sizes,
+                           int n, int level, uint8_t *out, uint64_t out_stride,
                            uint64_t *out_sizes, int n_threads) {
   std::atomic<int> err{0};
   parallel_for(n, n_threads, [&](int i) {
@@ -151,7 +151,7 @@ int ts_zstd_compress_batch(const uint8_t *in, const uint64_t *in_offsets,
     // (content size pledged in the frame header, like the reference's
     // setPledgedSrcSize + setContentSize(true)).
     size_t written = ZSTD_compress(out + static_cast<size_t>(i) * out_stride, out_stride,
-                                   in + in_offsets[i], in_sizes[i], level);
+                                   in[i], in_sizes[i], level);
     if (ZSTD_isError(written)) {
       int expected = 0;
       err.compare_exchange_strong(expected, 1 + i);

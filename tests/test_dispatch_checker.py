@@ -152,7 +152,7 @@ class TestSeededRegression:
         root = self._real_copy(tmp_path)
         tpu = root / "tieredstorage_tpu/transform/tpu.py"
         src = tpu.read_text()
-        anchor = "staged = self._dispatch_encrypt_window(chunks, w_opts) if chunks else None\n"
+        anchor = "staged = self._dispatch_encrypt_window(chunks, w_opts, frame_buffer)\n"
         assert anchor in src
         src = src.replace(
             anchor,

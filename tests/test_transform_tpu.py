@@ -278,7 +278,7 @@ class TestPipelinedWindows:
 
         def fake_compress(self, chunks, opts):
             time.sleep(compress_s)
-            return chunks
+            return chunks, None
 
         def fake_dispatch(self, chunks, opts):
             return (time.monotonic() + device_s, list(chunks))
@@ -546,3 +546,263 @@ class TestStagingRing:
             assert tpu.detransform(wire, DetransformOptions(encryption=key_pair)) == window
         if aligned and jax.default_backend() == "cpu":
             assert all(zero_copy)  # the staged arrays were the ring's buffers
+
+
+def _compressible(rng, size):
+    """Half noise, half scaffolding: a frame of about 3/4 the source, so the
+    frames of two windows differ in size as well as in bytes."""
+    return b"".join(bytes([rng.getrandbits(8)]) + b"k" for _ in range(size // 2))
+
+
+@pytest.mark.skipif(
+    TpuTransformBackend.zstd_engine() != "native", reason="native zstd unavailable"
+)
+class TestCodecFrameBuffer:
+    """The native codec writes a window's frames into a ring buffer and the
+    pack reads them as views: a view is dead once its row is packed, the
+    buffer goes back when `_encrypt_dispatch` returns, and whoever keeps
+    frames longer gets `bytes` of its own."""
+
+    ROWS = 4
+
+    def _window(self, rng, rows=ROWS):
+        return [_compressible(rng, CHUNK) for _ in range(rows)]
+
+    def _frame_buffers(self, monkeypatch):
+        """The `out` arrays the codec was handed, in order."""
+        from tieredstorage_tpu import native
+
+        seen, real = [], native.zstd_compress_into
+
+        def spy(chunks, out, **kwargs):
+            seen.append(out)
+            return real(chunks, out, **kwargs)
+
+        monkeypatch.setattr(native, "zstd_compress_into", spy)
+        return seen
+
+    # ---------------------------------------------------------- (a) lifetime
+    def test_window_a_survives_window_b_in_the_same_frame_buffer(
+        self, key_pair, monkeypatch
+    ):
+        seen = self._frame_buffers(monkeypatch)
+        rng = random.Random(361)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        a, b = self._window(rng), self._window(rng)
+        opts = TransformOptions(compression=True, encryption=key_pair, ivs=det_ivs(8))
+        wire_a, wire_b = tpu.transform_windows(iter([a, b]), opts)
+        assert len(seen) == 2 and seen[0] is seen[1]  # B's frames overwrote A's
+        d_opts = DetransformOptions(compression=True, encryption=key_pair)
+        assert cpu.detransform(wire_a, d_opts) == a
+        assert cpu.detransform(wire_b, d_opts) == b
+        assert wire_a + wire_b == cpu.transform(a + b, opts)
+        assert all(type(c) is bytes for c in wire_a + wire_b)
+
+    def test_no_view_of_the_frame_buffer_outlives_encrypt_dispatch(
+        self, key_pair, monkeypatch
+    ):
+        seen = self._frame_buffers(monkeypatch)
+        tpu = TpuTransformBackend()
+        opts = TransformOptions(compression=True, encryption=key_pair, ivs=det_ivs(4))
+        frames, frame_buffer = tpu._compress_batch(self._window(random.Random(362)), opts)
+        assert frame_buffer is seen[0]
+        assert all(np.shares_memory(f, frame_buffer) for f in frames)
+        assert frame_buffer.shape not in tpu._staging_free  # held while the views live
+        staged = tpu._dispatch_encrypt_window(frames, opts, frame_buffer)
+        # back in the ring, and nothing of the staged window is a view of it
+        assert tpu._staging_free[frame_buffer.shape] == [frame_buffer]
+        ivs, sizes, _, _, staging = staged
+        assert sizes == [len(f) for f in frames]
+        assert not any(np.shares_memory(x, frame_buffer) for x in (ivs, *staging))
+        wire = tpu._encrypt_finish(staged)
+        frame_buffer[:] = 0  # the next window's codec may write anything here
+        assert tpu.detransform(
+            wire, DetransformOptions(compression=True, encryption=key_pair)
+        ) == self._window(random.Random(362))
+
+    def test_a_failed_dispatch_still_hands_the_frame_buffer_back(
+        self, key_pair, monkeypatch
+    ):
+        tpu = TpuTransformBackend()
+        opts = TransformOptions(compression=True, encryption=key_pair)
+        frames, frame_buffer = tpu._compress_batch(self._window(random.Random(363)), opts)
+
+        def refuse(*args, **kwargs):
+            raise RuntimeError("no device")
+
+        monkeypatch.setattr(tpu, "_stage_packed", refuse)
+        with pytest.raises(RuntimeError, match="no device"):
+            tpu._dispatch_encrypt_window(frames, opts, frame_buffer)
+        assert tpu._staging_free[frame_buffer.shape] == [frame_buffer]
+
+    @pytest.mark.parametrize("route", ["transform", "transform_windows", "batcher"])
+    def test_frames_that_leave_the_backend_are_owned_bytes(
+        self, key_pair, monkeypatch, route
+    ):
+        """Compression-only output, and what the batcher is given to queue,
+        are unchanged after the next window has reused the frame buffer."""
+        import zstandard
+
+        seen = self._frame_buffers(monkeypatch)
+        rng = random.Random(364)
+        tpu = TpuTransformBackend()
+        a, b = self._window(rng), self._window(rng)
+        one_shot = zstandard.ZstdCompressor(level=3, write_content_size=True)
+        expected = [one_shot.compress(c) for c in a]
+        if route == "batcher":
+            queued = []
+
+            class Batcher:
+                def submit_encrypt(self, chunks, opts):
+                    queued.append(chunks)
+                    return None
+
+                def stop(self):
+                    pass
+
+            tpu.batcher = Batcher()
+            opts = TransformOptions(compression=True, encryption=key_pair)
+            assert list(tpu.transform_windows(iter([a, b]), opts)) == [[], []]
+            tpu.close()
+            kept = queued[0]
+        elif route == "transform":
+            opts = TransformOptions(compression=True)
+            kept = tpu.transform(a, opts)
+            tpu.transform(b, opts)
+        else:
+            kept, _ = tpu.transform_windows(iter([a, b]), TransformOptions(compression=True))
+        assert len(seen) == 2 and seen[0] is seen[1]
+        assert all(type(f) is bytes for f in kept)
+        assert not any(np.shares_memory(np.frombuffer(f, np.uint8), seen[0]) for f in kept)
+        assert kept == expected
+        stats = tpu.dispatch_stats
+        assert stats.codec_bytes_in == sum(map(len, a + b))
+        assert stats.codec_bytes_copied == sum(map(len, kept)) + sum(
+            len(one_shot.compress(c)) for c in b
+        )
+
+    # ---------------------------------------------------------- (b) the ring
+    def test_second_windows_frame_buffer_is_the_firsts(self, key_pair, monkeypatch):
+        from tieredstorage_tpu import native
+
+        seen = self._frame_buffers(monkeypatch)
+        rng = random.Random(365)
+        tpu = TpuTransformBackend()
+        opts = TransformOptions(compression=True, encryption=key_pair)
+        tpu.transform(self._window(rng), opts)
+        after_first = tpu.dispatch_stats.staging_reused
+        tpu.transform(self._window(rng), opts)
+        assert seen[0] is seen[1]
+        assert seen[0].shape == (self.ROWS, native.zstd_bound(CHUNK))
+        # the frame buffer and the packed window both came from the ring
+        assert tpu.dispatch_stats.staging_reused == after_first + 2
+        assert tpu.dispatch_stats.staging_acquired == 4
+        # a ragged last window of fewer rows is a shape of its own
+        tpu.transform(self._window(rng, rows=2) + [_compressible(rng, 300)], opts)
+        assert seen[2].shape == (3, native.zstd_bound(CHUNK))
+
+    def test_free_bytes_stay_under_the_bound_with_four_windows_in_flight(
+        self, key_pair, monkeypatch
+    ):
+        seen = self._frame_buffers(monkeypatch)
+        rng = random.Random(366)
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        # four windows' staging and a frame buffer are about 24 KiB: a bound of
+        # 32 KiB is one they fit under without the ring giving way
+        tpu.pipeline_depth, tpu.preferred_batch_bytes = 3, 4096
+        bound = tpu._staging_bound()
+        assert bound == 2 * 4 * 4096
+        windows = [self._window(rng) for _ in range(12)]
+        opts = TransformOptions(compression=True, encryption=key_pair, ivs=det_ivs(48))
+        d_opts = DetransformOptions(compression=True, encryption=key_pair)
+        for i, wire in enumerate(tpu.transform_windows(iter(windows), opts)):
+            assert tpu._staging_free_bytes <= bound
+            assert tpu._staging_free_bytes == sum(
+                b.nbytes for bufs in tpu._staging_free.values() for b in bufs
+            )
+            assert cpu.detransform(wire, d_opts) == windows[i]
+        assert len({id(b) for b in seen}) == 1  # one frame buffer served all twelve
+        stats = tpu.dispatch_stats
+        # twelve frame buffers and twelve packed windows taken, all from the
+        # ring but the first frame buffer and the packed windows of the first
+        # four in flight (`pipeline_depth + 1`)
+        assert stats.staging_acquired == 24
+        assert stats.staging_reused == 24 - 1 - (tpu.pipeline_depth + 1)
+
+    def test_concurrent_copies_each_pop_a_frame_buffer_of_their_own(self, key_pair):
+        cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+        d_opts = DetransformOptions(compression=True, encryption=key_pair)
+        errors: list = []
+        start = threading.Barrier(4)
+
+        def work(seed):
+            rng = random.Random(seed)
+            try:
+                start.wait()
+                windows = [self._window(rng) for _ in range(5)]
+                opts = TransformOptions(compression=True, encryption=key_pair)
+                for window, wire in zip(windows, tpu.transform_windows(iter(windows), opts)):
+                    assert cpu.detransform(wire, d_opts) == window
+            except Exception as exc:  # noqa: BLE001 - reported below
+                errors.append(exc)
+
+        threads = [threading.Thread(target=work, args=(s,)) for s in range(4)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        assert tpu.dispatch_stats.codec_bytes_copied == 0
+
+    # ------------------------------------------------------- (c) the counter
+    def test_native_path_copies_no_byte_around_the_codec(self, key_pair):
+        rng = random.Random(367)
+        tpu = TpuTransformBackend()
+        windows = [self._window(rng) for _ in range(3)]
+        opts = TransformOptions(compression=True, encryption=key_pair)
+        list(tpu.transform_windows(iter(windows), opts))
+        tpu.transform(windows[0], opts)
+        from tieredstorage_tpu.metrics.prometheus import PrometheusExporter
+
+        as_dict = PrometheusExporter([], transform_backend=tpu).varz()["dispatch"]
+        assert as_dict == tpu.dispatch_counts()
+        assert as_dict["codec_bytes_copied"] == 0
+        assert as_dict["codec_bytes_in"] == 4 * self.ROWS * CHUNK
+        # encrypt-only windows hand the codec nothing
+        tpu.transform(windows[0], TransformOptions(encryption=key_pair))
+        assert tpu.dispatch_stats.codec_bytes_in == 4 * self.ROWS * CHUNK
+        retired = tpu.reset_dispatch_stats()
+        assert (retired.codec_bytes_in, tpu.dispatch_stats.codec_bytes_in) == (
+            4 * self.ROWS * CHUNK, 0
+        )
+
+
+@pytest.mark.parametrize("codec", ["zstd", "tpu-huff-v1"])
+def test_codecs_without_a_frame_buffer_count_their_frames_as_copied(
+    key_pair, monkeypatch, codec
+):
+    """The `python-pool` fallback and the device codecs return `bytes` of
+    their own: every frame byte is in fresh memory, and the ring is not
+    asked for a frame buffer."""
+    if codec == "zstd":
+        pytest.importorskip("zstandard")
+    monkeypatch.setattr(TpuTransformBackend, "_use_native", staticmethod(lambda: False))
+    rng = random.Random(368)
+    cpu, tpu = CpuTransformBackend(), TpuTransformBackend()
+    window = [_compressible(rng, CHUNK) for _ in range(4)]
+    opts = TransformOptions(
+        compression=True, compression_codec=codec, encryption=key_pair, ivs=det_ivs(4)
+    )
+    wire = tpu.transform(window, opts)
+    assert wire == cpu.transform(window, opts)
+    stats = tpu.dispatch_stats
+    assert stats.codec_bytes_in == 4 * CHUNK
+    assert stats.codec_bytes_copied == sum(len(c) - IV_SIZE - 16 for c in wire) > 0
+    assert stats.staging_acquired == 1  # the packed window alone
+    tpu.close()

@@ -210,7 +210,8 @@ class PrometheusExporter:
         tasks' own windows and rows), and `dispatch` where the transform
         backend counts its windows (`DispatchStats.as_dict()`: windows,
         rows, launches, transfers, `bytes_in` beside `padded_bytes`,
-        `varlen_windows`, the staging ring's counts), and `gateway` where
+        `varlen_windows`, the staging ring's counts, the compress codec's
+        `codec_bytes_in` and `codec_bytes_copied`), and `gateway` where
         one is wired (`SidecarHttpGateway.counters()`: the bytes of whole
         copy bodies and those written locally, the bytes of streamed
         replies and those of them handed to the socket as views), and `s3`

@@ -48,15 +48,14 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.ts_crypto_available.restype = ctypes.c_int
     lib.ts_zstd_bound.restype = ctypes.c_size_t
     lib.ts_zstd_bound.argtypes = [ctypes.c_size_t]
-    common_zstd = [
-        _u8p, _u64p, _u64p, ctypes.c_int,
-    ]
     lib.ts_zstd_compress_batch.restype = ctypes.c_int
-    lib.ts_zstd_compress_batch.argtypes = common_zstd + [
+    lib.ts_zstd_compress_batch.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), _u64p, ctypes.c_int,  # in, sizes, n
         ctypes.c_int, _u8p, ctypes.c_uint64, _u64p, ctypes.c_int,
     ]
     lib.ts_zstd_decompress_batch.restype = ctypes.c_int
-    lib.ts_zstd_decompress_batch.argtypes = common_zstd + [
+    lib.ts_zstd_decompress_batch.argtypes = [
+        _u8p, _u64p, _u64p, ctypes.c_int,  # in, offsets, sizes, n
         _u8p, ctypes.c_uint64, _u64p, ctypes.c_int,
     ]
     aes_common = [
@@ -144,26 +143,66 @@ class NativeAuthenticationError(NativeTransformError):
     """GCM tag verification failed for at least one chunk."""
 
 
-def zstd_compress_batch(chunks: list[bytes], level: int = 3, n_threads: int = 0) -> list[bytes]:
+def zstd_bound(size: int) -> int:
+    """The worst-case frame size of a chunk of `size` bytes: the row width a
+    `zstd_compress_into` buffer needs."""
     lib = load()
     if lib is None:
         raise NativeTransformError(f"native library unavailable: {_load_error}")
-    if not chunks:
+    return int(lib.ts_zstd_bound(size))
+
+
+def zstd_compress_into(
+    chunks: list, out: np.ndarray, level: int = 3, n_threads: int = 0
+) -> list[np.ndarray]:
+    """Compress each chunk where it lies into the caller's `uint8[rows, stride]`
+    array: chunk i's frame goes to row i, and the frames come back as views of
+    `out`, alive only as long as the caller leaves the array alone. A `bytes`
+    chunk is handed to the codec as it is, any other contiguous buffer through
+    `np.frombuffer`; nothing is gathered and no frame is copied out. Refused
+    before any write where a row could not hold the worst frame of the
+    largest chunk (`ts_zstd_bound`)."""
+    lib = load()
+    if lib is None:
+        raise NativeTransformError(f"native library unavailable: {_load_error}")
+    n = len(chunks)
+    flags = out.flags
+    if out.dtype != np.uint8 or out.ndim != 2 or not (flags.c_contiguous and flags.writeable):
+        raise ValueError("out must be a writeable C-contiguous uint8[rows, stride] array")
+    if n == 0:
         return []
-    buf, offsets, sizes = _pack(chunks)
-    stride = int(lib.ts_zstd_bound(int(sizes.max())))
-    out = np.empty(len(chunks) * stride, dtype=np.uint8)
-    out_sizes = np.zeros(len(chunks), dtype=np.uint64)
+    sizes = np.array([len(c) for c in chunks], dtype=np.uint64)
+    rows, stride = out.shape
+    bound = int(lib.ts_zstd_bound(int(sizes.max())))
+    if rows < n or stride < bound:
+        raise ValueError(
+            f"out is uint8[{rows}, {stride}]: {n} chunks of up to "
+            f"{int(sizes.max())} bytes need at least uint8[{n}, {bound}]"
+        )
+    pointers = (ctypes.c_char_p * n)()
+    held = []  # the arrays whose addresses `pointers` carries
+    for i, chunk in enumerate(chunks):
+        if isinstance(chunk, bytes):
+            pointers[i] = chunk
+        else:
+            held.append(np.frombuffer(chunk, dtype=np.uint8))
+            pointers[i] = held[-1].ctypes.data
+    out_sizes = np.zeros(n, dtype=np.uint64)
     rc = lib.ts_zstd_compress_batch(
-        _as_u8p(buf), _as_u64p(offsets), _as_u64p(sizes), len(chunks),
+        pointers, _as_u64p(sizes), n,
         level, _as_u8p(out), stride, _as_u64p(out_sizes), n_threads,
     )
     if rc != 0:
         raise NativeTransformError(f"zstd compress failed on chunk {rc - 1}")
-    return [
-        out[i * stride : i * stride + int(out_sizes[i])].tobytes()
-        for i in range(len(chunks))
-    ]
+    return [out[i, : int(out_sizes[i])] for i in range(n)]
+
+
+def zstd_compress_batch(chunks: list[bytes], level: int = 3, n_threads: int = 0) -> list[bytes]:
+    """`zstd_compress_into` a buffer of its own, the frames as owned `bytes`."""
+    if not chunks:
+        return []
+    out = np.empty((len(chunks), zstd_bound(max(len(c) for c in chunks))), dtype=np.uint8)
+    return [bytes(view) for view in zstd_compress_into(chunks, out, level, n_threads)]
 
 
 #: Absolute sanity ceiling on a single frame's declared content size, used

@@ -123,7 +123,17 @@ class DispatchStats:
     holds one (`staging_reused`), freshly allocated otherwise. A buffer goes
     back to the ring only when its window is over: in `_encrypt_finish` once
     the wire chunks are built, at the end of `_decrypt_window` once the
-    plaintext is. A window that is abandoned never returns its buffer."""
+    plaintext is. A window that is abandoned never returns its buffer. The
+    native zstd codec's frame buffer (`_compress_batch`) is the ring's too
+    and is counted with them: it goes back as soon as the window's rows
+    are packed.
+
+    `codec_bytes_in` and `codec_bytes_copied` count the host codec's side
+    of a compress: the source bytes handed to it, and the bytes the host put
+    into fresh memory around it: a gathered copy of the input (none: each
+    chunk is compressed where it lies) and every frame that ends as an
+    owned `bytes` where the native codec's, as views of its frame buffer,
+    are read by the pack and copied nowhere else."""
 
     windows: int = 0
     #: Chunk rows of those windows, before any mesh padding: over `windows`
@@ -161,6 +171,11 @@ class DispatchStats:
     #: cost (before any mesh or kernel row padding).
     varlen_windows: int = 0
     padded_bytes: int = 0
+    #: Source bytes handed to the compress codec, and the bytes the host
+    #: copied into fresh memory around it (0 on the native zstd path whose
+    #: frames the window's pack reads).
+    codec_bytes_in: int = 0
+    codec_bytes_copied: int = 0
 
     @property
     def dispatches_per_window(self) -> float:
@@ -356,11 +371,14 @@ class TpuTransformBackend(TransformBackend):
         out = list(chunks)
         if not out:
             return []
+        frame_buffer = None
         if opts.compression:
-            out = self._compress_batch(out, opts)
+            out, frame_buffer = self._compress_batch(out, opts)
         if opts.encryption is not None:
-            out = self._finish_or_empty(self._dispatch_encrypt_window(out, opts))
-        return out
+            return self._finish_or_empty(
+                self._dispatch_encrypt_window(out, opts, frame_buffer)
+            )
+        return self._own_frames(out, frame_buffer)
 
     #: Staged windows kept in flight before blocking on the oldest: at depth
     #: N the host compresses window k while the device encrypts k-1..k-N+1
@@ -400,16 +418,22 @@ class TpuTransformBackend(TransformBackend):
                     opts, ivs=opts.ivs[iv_offset : iv_offset + len(chunks)]
                 )
                 iv_offset += len(chunks)
-            if opts.compression:
-                chunks = self._compress_batch(chunks, w_opts)
-            staged = self._dispatch_encrypt_window(chunks, w_opts) if chunks else None
+            staged = None
+            if chunks:
+                frame_buffer = None
+                if opts.compression:
+                    chunks, frame_buffer = self._compress_batch(chunks, w_opts)
+                staged = self._dispatch_encrypt_window(chunks, w_opts, frame_buffer)
             pending.append(staged)
             while len(pending) > max(1, self.pipeline_depth):
                 yield self._finish_or_empty(pending.popleft())
         while pending:
             yield self._finish_or_empty(pending.popleft())
 
-    def _dispatch_encrypt_window(self, chunks: list[bytes], opts: TransformOptions):
+    def _dispatch_encrypt_window(
+        self, chunks: list, opts: TransformOptions,
+        frame_buffer: Optional[np.ndarray] = None,
+    ):
         """Dispatch one encrypt window asynchronously. With the batcher
         enabled the window joins the shared work-class-aware device queue
         (`submit_encrypt` — idle batchers dispatch inline, CONCURRENT
@@ -417,11 +441,22 @@ class TpuTransformBackend(TransformBackend):
         for windows with zero-length chunks (excluded by the merged
         launch's varlen contract), it stages directly. Either way the
         return is un-materialized: `_finish_or_empty` blocks pipeline_depth
-        windows later."""
+        windows later.
+
+        `frame_buffer` is the ring buffer that `chunks` are views of, where
+        the native codec made them (`_compress_batch`). A frame view is dead
+        once `_build_packed` has copied its row into the staged window, so
+        the buffer goes back to the ring when `_encrypt_dispatch` returns;
+        the batcher may queue its chunks, so it is given owned `bytes`. No
+        view leaves the backend."""
         batcher = self.batcher
         if batcher is not None and min(len(c) for c in chunks) > 0:
-            return batcher.submit_encrypt(chunks, opts)
-        return self._encrypt_dispatch(chunks, opts)
+            return batcher.submit_encrypt(self._own_frames(chunks, frame_buffer), opts)
+        try:
+            return self._encrypt_dispatch(chunks, opts)
+        finally:
+            if frame_buffer is not None:
+                self._release_staging(frame_buffer)
 
     def _finish_or_empty(self, staged) -> list[bytes]:
         if staged is None:
@@ -430,21 +465,46 @@ class TpuTransformBackend(TransformBackend):
             return staged.wait()
         return self._encrypt_finish(staged)
 
-    @_spanned("transform.compress")
-    def _compress_batch(self, chunks: list[bytes], opts: TransformOptions) -> list[bytes]:
+    def _compress_batch(
+        self, chunks: list[bytes], opts: TransformOptions
+    ) -> tuple[list, Optional[np.ndarray]]:
+        """One frame a chunk, and the ring buffer the frames are views of
+        where the native zstd codec wrote them: each chunk is compressed
+        where it lies, into a row of a `uint8[rows, zstd_bound(largest)]`
+        buffer that an earlier window has mapped. Whoever takes the frames
+        hands that buffer back (`_dispatch_encrypt_window`, `_own_frames`).
+        Every other codec returns owned `bytes` and no buffer."""
+        with self.tracer.span("transform.compress", chunks=len(chunks)) as span:
+            frames, frame_buffer = self._compress_frames(chunks, opts)
+            bytes_in = sum(len(c) for c in chunks)
+            bytes_out = sum(len(f) for f in frames)
+            with self._stats_lock:
+                self.dispatch_stats.codec_bytes_in += bytes_in
+                if frame_buffer is None:
+                    self.dispatch_stats.codec_bytes_copied += bytes_out
+                note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+            if span is not None:
+                span.attributes["bytes_in"] = bytes_in
+                span.attributes["bytes_out"] = bytes_out
+        return frames, frame_buffer
+
+    def _compress_frames(self, chunks: list[bytes], opts: TransformOptions):
         if opts.compression_codec == THUFF:
             from tieredstorage_tpu.transform import thuff
 
-            return thuff.compress_batch(chunks)
+            return thuff.compress_batch(chunks), None
         if opts.compression_codec == TLZHUFF:
             from tieredstorage_tpu.transform import lzhuff
 
-            return lzhuff.compress_batch(chunks)
+            return lzhuff.compress_batch(chunks), None
         if opts.compression_codec != ZSTD:
             raise ValueError(f"Codec {opts.compression_codec!r} not implemented")
         level = opts.compression_level
         if self._use_native():
-            return native.zstd_compress_batch(chunks, level=level)
+            frame_buffer = self._acquire_staging(
+                (len(chunks), native.zstd_bound(max(len(c) for c in chunks)))
+            )
+            return native.zstd_compress_into(chunks, frame_buffer, level=level), frame_buffer
         if zstandard is None:
             raise ModuleNotFoundError(
                 "The 'zstandard' package is required for the 'zstd' codec "
@@ -457,7 +517,20 @@ class TpuTransformBackend(TransformBackend):
                 ).compress(c),
                 chunks,
             )
-        )
+        ), None
+
+    def _own_frames(self, frames: list, frame_buffer: Optional[np.ndarray]) -> list[bytes]:
+        """The frames as `bytes` of their own, for whoever keeps them past
+        this window (a compression-only transform's caller, the batcher's
+        queue), and their buffer back to the ring."""
+        if frame_buffer is None:
+            return frames
+        owned = [frame.tobytes() for frame in frames]
+        self._release_staging(frame_buffer)
+        with self._stats_lock:
+            self.dispatch_stats.codec_bytes_copied += sum(len(f) for f in owned)
+            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+        return owned
 
     @staticmethod
     def _use_native() -> bool:
