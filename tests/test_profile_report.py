@@ -157,6 +157,41 @@ def test_idle_gaps_go_piece_by_piece_to_the_innermost_span(sound):
     assert idle["uncovered_share"] == pytest.approx(16.3 / 26.2)
 
 
+def test_ready_lateness_pairs_the_watchs_stamps_with_the_programs_ends(tmp_path, sound):
+    """The window programs end at 3.5, 13.0 and 31.0 ms; the device watch's
+    `device.ready` annotations lie 200, 250 and 400 us after them, on a
+    thread of their own, and hold no idle time."""
+    assert sound["ready_lateness_us"] == {"count": 0, "unpaired": 3}
+    readies = [("device-watch", at, 0.001, "device.ready") for at in (3.7, 13.25, 31.4)]
+    report = profile_report.reduce(
+        write_xplane(tmp_path / "ready.xplane.pb", host=HOST + readies)
+    )
+    assert report["ready_lateness_us"] == pytest.approx(
+        {"count": 3, "unpaired": 0, "min": 200.0, "p50": 250.0, "p95": 400.0, "max": 400.0}
+    )
+    assert report["program_spans"] == sound["program_spans"]
+    assert report["idle_gaps"]["by_span_s"] == pytest.approx(sound["idle_gaps"]["by_span_s"])
+    # a stamp more than programs: counted from the end, the odd one reported
+    report = profile_report.reduce(write_xplane(
+        tmp_path / "odd.xplane.pb", host=HOST + readies + [("device-watch", 0.2, 0.001, "device.ready")]
+    ))
+    assert (report["ready_lateness_us"]["count"], report["ready_lateness_us"]["unpaired"]) == (3, 1)
+    assert report["ready_lateness_us"]["max"] == pytest.approx(400.0)
+
+
+def test_a_gap_is_its_launchers_where_a_window_program_ended_it():
+    """Launches and window programs pair from the end; a gap that a program
+    no window launched ends (or that nothing ends) has no launcher."""
+    ran = [[100, 200, "jit__packed_fixed_impl(1)"], [400, 450, "jit__take(2)"],
+           [700, 800, "jit__packed_fixed_impl(1)"]]
+    programs = [[100, 200], [700, 800]]
+    launches = [(5, "warm-up"), (90, "http_0"), (690, "http_1")]  # one launch too many: the first
+    gaps = [(0, 100), (200, 400), (450, 700), (800, 900)]
+    assert profile_report.launcher_of_gap(gaps, ran, programs, launches) == [
+        (0, 100, "http_0"), (200, 400, None), (450, 700, "http_1"), (800, 900, None),
+    ]
+
+
 def test_device_seconds_by_scope(sound):
     assert sound["device_s_by_scope"] == pytest.approx({
         "gcm.ctr": 2.6e-3, "gcm.ghash": 1.4e-3, "unscoped": 0.7e-3, "gcm.pack": 0.1e-3,

@@ -135,6 +135,10 @@ SANCTIONED_THREAD_SPAWNS = {
     "tieredstorage_tpu/transform/batcher.py:WindowBatcher.start":
         "cross-request GCM flush daemon (one device queue per backend, "
         "stopped via stop)",
+    "tieredstorage_tpu/transform/device_watch.py:DeviceWatch.__init__":
+        "device watch (one per backend and only under an enabled tracer: "
+        "waits for each launched window off every request's path, stopped "
+        "via stop from the backend's close)",
 }
 
 

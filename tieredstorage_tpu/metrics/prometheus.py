@@ -211,7 +211,9 @@ class PrometheusExporter:
         backend counts its windows (`DispatchStats.as_dict()`: windows,
         rows, launches, transfers, `bytes_in` beside `padded_bytes`,
         `varlen_windows`, the staging ring's counts, the compress codec's
-        `codec_bytes_in` and `codec_bytes_copied`), and `gateway` where
+        `codec_bytes_in` and `codec_bytes_copied`, and `device_seen_ns`,
+        what the device watch's `device.window` spans add up to under an
+        enabled tracer), and `gateway` where
         one is wired (`SidecarHttpGateway.counters()`: the bytes of whole
         copy bodies and those written locally, the bytes of streamed
         replies and those of them handed to the socket as views), and `s3`

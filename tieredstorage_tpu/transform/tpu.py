@@ -46,6 +46,7 @@ from tieredstorage_tpu.parallel.mesh import MeshPlan
 from tieredstorage_tpu.security.aes import IV_SIZE, TAG_SIZE
 from tieredstorage_tpu.utils.locks import new_lock, note_mutation
 from tieredstorage_tpu.utils.platforms import thread_program_traces
+from tieredstorage_tpu.transform.device_watch import DeviceWatch
 from tieredstorage_tpu.transform.api import (
     THUFF,
     TLZHUFF,
@@ -176,6 +177,11 @@ class DispatchStats:
     #: frames the window's pack reads).
     codec_bytes_in: int = 0
     codec_bytes_copied: int = 0
+    #: Nanoseconds of the `device.window` spans the device watch recorded
+    #: (transform/device_watch.py): the host's upper bound of the device's
+    #: time on the windows launched under an enabled tracer. 0 for ever with
+    #: tracing off.
+    device_seen_ns: int = 0
 
     @property
     def dispatches_per_window(self) -> float:
@@ -207,6 +213,10 @@ class TpuTransformBackend(TransformBackend):
     #: allocation — decrypt donates the STAGED ciphertext input, never
     #: this — so retention can never alias a donated operand.
     on_decrypt_window = None
+
+    #: The device watch (transform/device_watch.py): None until the first
+    #: window launched under an enabled tracer, and again after `close()`.
+    device_watch: Optional[DeviceWatch] = None
 
     preferred_batch_chunks = 256
     # Window byte cap. With pipeline_depth=3 up to 4 windows are in flight
@@ -362,6 +372,10 @@ class TpuTransformBackend(TransformBackend):
         if self.batcher is not None:
             self.batcher.stop()
             self.batcher = None
+        with self._stats_lock:
+            watch, self.device_watch = self.device_watch, None
+        if watch is not None:
+            watch.stop()
         if self._pool is not None:
             self._pool.shutdown(wait=False)
             self._pool = None
@@ -716,7 +730,50 @@ class TpuTransformBackend(TransformBackend):
             out.copy_to_host_async()
             if span is not None:
                 span.attributes["traced"] = thread_program_traces() > traces
+                self._watch_window(out, span, varlen, decrypt)
         return out
+
+    def _watch_window(self, out, launch, varlen: bool, decrypt: bool) -> None:
+        """Hand a window launched under an enabled tracer to the device
+        watch, which the first such window starts: `rows` and `bytes` are
+        the staged window's, mesh and rung padding included (what the
+        program was given, not the payload)."""
+        watch = self.device_watch
+        if watch is None:
+            with self._stats_lock:
+                watch = self.device_watch
+                if watch is None:
+                    watch = self.device_watch = DeviceWatch(
+                        self.tracer, self._note_device_seen
+                    )
+        watch.watch(
+            out, launch, rows=out.shape[0], bytes=out.nbytes, decrypt=decrypt,
+            varlen=varlen,
+        )
+
+    def _note_device_seen(self, nanoseconds: int) -> None:
+        with self._stats_lock:
+            self.dispatch_stats.device_seen_ns += nanoseconds
+            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+
+    def _split_wait(self, wait, out) -> None:
+        """A finished `transform.d2h_wait`, split at the moment the window's
+        result was ready (the device watch's stamp, or the wait's own end
+        where that came first): `transform.ready_wait` up to it, the program
+        still running, and `transform.collect` after it, what the thread
+        still paid once the result existed (the rest of the copy back, being
+        woken, being given the interpreter, `np.asarray`). The two tile the
+        wait; a result that was ready before the wait began leaves no
+        `ready_wait`."""
+        watch = self.device_watch
+        ready_s = wait.end_s if watch is None else watch.ready_by(out, wait.end_s)
+        if ready_s > wait.start_s:
+            self.tracer.record(
+                "transform.ready_wait", wait.start_s, ready_s, parent=wait
+            )
+        self.tracer.record(
+            "transform.collect", max(ready_s, wait.start_s), wait.end_s, parent=wait
+        )
 
     @_spanned("transform.encrypt_dispatch")
     def _encrypt_dispatch(self, chunks: list[bytes], opts: TransformOptions):
@@ -743,8 +800,10 @@ class TpuTransformBackend(TransformBackend):
         payload per chunk. The window is over then, and its host staging
         buffer goes back to the ring."""
         ivs, sizes, n_bytes, out, staging = staged
-        with self.tracer.span("transform.d2h_wait"):
+        with self.tracer.span("transform.d2h_wait") as wait:
             host = np.asarray(out)
+        if wait is not None:
+            self._split_wait(wait, out)
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
@@ -853,8 +912,10 @@ class TpuTransformBackend(TransformBackend):
         out = self._launch_packed(ctx, staged, varlen, decrypt=True)
         self._note_window(sum(sizes), len(sizes), n_bytes, varlen)
 
-        with self.tracer.span("transform.d2h_wait"):
+        with self.tracer.span("transform.d2h_wait") as wait:
             host = np.asarray(out)
+        if wait is not None:
+            self._split_wait(wait, out)
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")

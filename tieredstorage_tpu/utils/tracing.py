@@ -15,6 +15,10 @@ RemoteStorageManager.java:218,549,598); this build adds a real span system:
   (``tools/profile_report.py`` checks one against the other); outside a
   session an annotation costs nothing measurable, and a process without
   ``jax`` (a client-side tracer) never imports it for this;
+- spans with given times (`Tracer.record`) for what was seen from outside:
+  the device's time on each launched window (`device.window`, from the TPU
+  backend's device watch), from which `summary()` puts every second the
+  device idled down to the host span that held it (`device_idle_s`);
 - a bounded ring-buffer recorder (newest spans win; evictions are counted in
   `dropped_spans`) with per-name p50/p95/p99 summaries and a Chrome
   trace-event JSON exporter (loadable in Perfetto / ``chrome://tracing``,
@@ -33,6 +37,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import json
 import math
 import os
@@ -50,12 +55,42 @@ _TRACEPARENT_VERSION = "00"
 _HEX = set("0123456789abcdef")
 
 
-def _gen_trace_id() -> str:
-    return os.urandom(16).hex()
+#: Odd, so that `seed + n * STRIDE` visits every value of its width once
+#: (2**64 and 2**128 over the golden ratio).
+_SPAN_ID_STRIDE = 0x9E3779B97F4A7C15
+_TRACE_ID_STRIDE = 0x9E3779B97F4A7C15F39CC0605CEDC835
+
+#: The device's side of the ring (transform/device_watch.py): one span per
+#: launched window, and the row of `summary()` for idle time nobody held.
+DEVICE_WINDOW = "device.window"
+DEVICE_UNCLAIMED = "device.unclaimed"
+#: The watch's event at the moment it saw a window ready: a stamp, not a span
+#: that can hold idle time.
+DEVICE_READY = "device.ready"
 
 
-def _gen_span_id() -> str:
-    return os.urandom(8).hex()
+class _Ids:
+    """Span and trace ids of one tracer: one `os.urandom` seed, then a
+    counter, so that a span costs no system call. Unique within the tracer
+    (a stride that is odd walks the whole ring of values before it repeats),
+    never all zero, and as hard to guess across tracers as their seeds."""
+
+    def __init__(self) -> None:
+        seed = int.from_bytes(os.urandom(24), "big")
+        self._span_seed, self._trace_seed = seed >> 128, seed & ((1 << 128) - 1)
+        self._next = itertools.count(1).__next__  # atomic under the interpreter lock
+
+    def span_id(self) -> str:
+        value = 0
+        while not value:
+            value = (self._span_seed + self._next() * _SPAN_ID_STRIDE) & ((1 << 64) - 1)
+        return f"{value:016x}"
+
+    def trace_id(self) -> str:
+        value = 0
+        while not value:
+            value = (self._trace_seed + self._next() * _TRACE_ID_STRIDE) & ((1 << 128) - 1)
+        return f"{value:032x}"
 
 
 def format_traceparent(trace_id: str, span_id: str) -> str:
@@ -143,6 +178,101 @@ def _percentile(sorted_durations: list[float], q: float) -> float:
     return sorted_durations[min(rank, len(sorted_durations)) - 1]
 
 
+def merge(intervals: list, bridge=0) -> list:
+    """Sorted, disjoint intervals covering the same points, and the gaps no
+    longer than `bridge` between them."""
+    merged: list = []
+    for start, end in sorted(intervals):
+        if merged and start <= merged[-1][1] + bridge:
+            merged[-1][1] = max(merged[-1][1], end)
+        else:
+            merged.append([start, end])
+    return merged
+
+
+def label_gaps(gaps: list, spans: list) -> dict:
+    """Why the device waited: each gap of its timeline put down, piece by
+    piece, to the span that held it. `gaps` are disjoint `(start, end)` or
+    `(start, end, thread)`, the thread being the one that launched the window
+    which ended the gap; `spans` are `(start, end, name)` or `(start, end,
+    name, thread)` of every host thread; any one unit of time throughout.
+
+    A gap is cut at every span edge inside it, and each piece goes to (i) the
+    innermost (shortest) span that covers it on the gap's thread: the thread
+    the device was waiting for, and what it was doing; else (ii) the innermost
+    span that covers it on any thread: a second between two copies is then the
+    next copy's spool, decode and first context build, not one `gateway.copy`,
+    and the tail of a copy lies with its handler before the next copy's
+    thread exists; else (iii) nobody in the program wanted the device.
+    Returns `by_span` (time by span name) and `uncovered` (the pieces of
+    iii, each one's length)."""
+    ordered = sorted((s for s in spans if s[1] > s[0]), key=lambda s: s[0])
+    by_span: dict = collections.defaultdict(int)
+    uncovered: list = []
+    live: list = []
+    following = 0
+    for gap in sorted(gaps, key=lambda g: g[0]):
+        gap_start, gap_end = gap[0], gap[1]
+        thread = gap[2] if len(gap) > 2 else None
+        while following < len(ordered) and ordered[following][0] < gap_end:
+            live.append(ordered[following])
+            following += 1
+        live = [s for s in live if s[1] > gap_start]  # gaps come in order: gone for good
+        cuts = sorted({
+            gap_start, gap_end,
+            *(t for s in live for t in s[:2] if gap_start < t < gap_end),
+        })
+        for piece_start, piece_end in zip(cuts, cuts[1:]):
+            covering = [s for s in live if s[0] <= piece_start and s[1] >= piece_end]
+            launcher = [
+                s for s in covering if thread is not None and len(s) > 3 and s[3] == thread
+            ]
+            if covering:
+                held = min((s[1] - s[0], s[2]) for s in launcher or covering)
+                by_span[held[1]] += piece_end - piece_start
+            else:
+                uncovered.append(piece_end - piece_start)
+    return {"by_span": by_span, "uncovered": uncovered}
+
+
+def device_idle(spans: list, since_s: float = float("-inf")) -> Optional[dict]:
+    """`label_gaps` over a ring that holds the device's windows: the gaps are
+    what the merged `device.window` spans leave of the stretch from the first
+    span's start to the last span's end; the one before a window is the
+    launching thread's (the thread of the window's `transform.launch`, its
+    parent), the one after the last window nobody's. The stretch begins no
+    earlier than `since_s`, the moment the ring was last cleared: a span that
+    was open then lands in the ring later with its whole length, and what
+    the device did before the clear is not in the ring to hold against it.
+    None where the ring has no `device.window`."""
+    windows = sorted(
+        (s for s in spans if s.name == DEVICE_WINDOW), key=lambda s: s.start_s
+    )
+    if not windows:
+        return None
+    first = max(min(s.start_s for s in spans), since_s)
+    last = max(s.end_s for s in spans)
+    by_id = {s.span_id: s for s in spans}
+    launched_by: dict = {}
+    for window in reversed(windows):  # the earliest window of a start wins
+        launch = by_id.get(window.parent_id)
+        launched_by[window.start_s] = None if launch is None else launch.thread_id
+    busy = [
+        interval for interval in merge([(w.start_s, w.end_s) for w in windows])
+        if interval[1] > first
+    ]
+    edges = [first, *(t for interval in busy for t in interval), last]
+    threads = [*(launched_by[interval[0]] for interval in busy), None]
+    gaps = [
+        (start, end, thread)
+        for start, end, thread in zip(edges[0::2], edges[1::2], threads)
+        if end > start
+    ]
+    return label_gaps(
+        gaps, [(s.start_s, s.end_s, s.name, s.thread_id) for s in spans]
+    )
+
+
 class Tracer:
     """Nested span recorder; thread-safe, cheap when disabled.
 
@@ -161,6 +291,7 @@ class Tracer:
         self.dropped_spans = 0
         self._lock = new_lock("tracing.Tracer._lock")
         self._local = threading.local()
+        self._ids = _Ids()
         # Pinned once so Chrome-trace timestamps from several tracers in one
         # process (client + sidecar in tests/demos) land on one shared
         # timeline. Monotonic, not wall clock: Perfetto only needs a
@@ -168,6 +299,8 @@ class Tracer:
         # against their perf_counter-measured durations.
         self._epoch_perf = time.perf_counter()
         self._epoch_mono = time.monotonic()
+        #: When the ring was last cleared: idle time is attributed from here.
+        self._cleared_s = float("-inf")
 
     # ---------------------------------------------------------------- context
     def _stack(self) -> list[Span]:
@@ -184,7 +317,7 @@ class Tracer:
         remote = getattr(self._local, "remote", None)
         if remote is not None:
             return remote
-        return _gen_trace_id(), None
+        return self._ids.trace_id(), None
 
     def current_traceparent(self) -> Optional[str]:
         """``traceparent`` value for the active context, for injection into
@@ -232,7 +365,7 @@ class Tracer:
         trace_id, parent_id = self._parent_context()
         s = Span(
             name=name, start_s=time.perf_counter(), depth=len(stack),
-            attributes=attributes, trace_id=trace_id, span_id=_gen_span_id(),
+            attributes=attributes, trace_id=trace_id, span_id=self._ids.span_id(),
             parent_id=parent_id, thread_id=threading.get_ident(),
         )
         stack.append(s)
@@ -258,7 +391,7 @@ class Tracer:
         trace_id, parent_id = self._parent_context()
         s = Span(
             name=name, start_s=now, end_s=now, depth=len(self._stack()),
-            attributes=attributes, trace_id=trace_id, span_id=_gen_span_id(),
+            attributes=attributes, trace_id=trace_id, span_id=self._ids.span_id(),
             parent_id=parent_id, thread_id=threading.get_ident(),
         )
         # Zero-duration annotation: timeline parity with span(), so events
@@ -267,6 +400,32 @@ class Tracer:
         if ctx is not None:
             with ctx:
                 pass
+        self._record(s)
+        return s
+
+    def record(
+        self, name: str, start_s: float, end_s: float, *,
+        parent: Optional[Span] = None, **attributes,
+    ) -> Optional[Span]:
+        """Record a span whose times are given (`time.perf_counter()`
+        readings): something that was seen from outside and not lived
+        through, such as the device's time on a window, or the two halves of
+        a wait that only its end can tell apart. Child of `parent` (a span of
+        any thread, open or recorded), else of the calling thread's context
+        as `event` is. It was on nobody's stack, has no annotation in a
+        profiler session (that has no given times), and meets the ring and
+        `dropped_spans` as any span does. None with tracing off."""
+        if not self.enabled:
+            return None
+        if parent is not None:
+            trace_id, parent_id, depth = parent.trace_id, parent.span_id, parent.depth + 1
+        else:
+            (trace_id, parent_id), depth = self._parent_context(), len(self._stack())
+        s = Span(
+            name=name, start_s=start_s, end_s=end_s, depth=depth,
+            attributes=attributes, trace_id=trace_id, span_id=self._ids.span_id(),
+            parent_id=parent_id, thread_id=threading.get_ident(),
+        )
         self._record(s)
         return s
 
@@ -299,6 +458,7 @@ class Tracer:
         with self._lock:
             self._spans.clear()
             self.dropped_spans = 0
+            self._cleared_s = time.perf_counter()
 
     def summary(self) -> dict[str, dict[str, float]]:
         """Per-name count/total/avg/max plus p50/p95/p99 durations (seconds),
@@ -311,7 +471,19 @@ class Tracer:
         "absent" as "no data" without a sentinel check. A name with exactly
         one span reports that span's duration as count=1, avg, max, and
         every percentile (nearest-rank: one sample is every quantile of
-        itself)."""
+        itself).
+
+        Where the ring holds `device.window` spans (a TPU backend's device
+        watch, transform/device_watch.py) every row also has
+        ``device_idle_s``: the seconds the device ran no window while that
+        name's spans held it (`device_idle`, `label_gaps`), and the pieces
+        that no span held are the row ``device.unclaimed`` (``count`` of
+        them, ``total_s`` = ``self_s`` = ``device_idle_s``, the percentiles
+        over the pieces; absent where there is none). The rows'
+        ``device_idle_s`` and the merged windows add up to the ring's
+        stretch: first start, or the last ``clear()`` where a span that was
+        open then reaches back before it, to last end. Without such a span
+        no row has the field."""
         spans = self.spans()
         children: dict[str, list[tuple[float, float]]] = {}
         for s in spans:
@@ -322,6 +494,10 @@ class Tracer:
         for s in spans:
             agg.setdefault(s.name, []).append(s.duration_s)
             self_s[s.name] += _self_seconds(s, children.get(s.span_id, ()))
+        idle = device_idle(spans, self._cleared_s)
+        if idle is not None and idle["uncovered"]:
+            agg[DEVICE_UNCLAIMED] = idle["uncovered"]
+            self_s[DEVICE_UNCLAIMED] = idle["by_span"][DEVICE_UNCLAIMED] = sum(idle["uncovered"])
         out: dict[str, dict[str, float]] = {}
         for name, ds in agg.items():
             ds.sort()
@@ -335,6 +511,8 @@ class Tracer:
                 "p95_s": _percentile(ds, 0.95),
                 "p99_s": _percentile(ds, 0.99),
             }
+            if idle is not None:
+                out[name]["device_idle_s"] = float(idle["by_span"].get(name, 0.0))
         return out
 
     # ---------------------------------------------------------------- export

@@ -33,9 +33,16 @@ clock. `reduce(xplane_path)` reads that file and nothing else:
   clocks that the check could not have seen.
 - **idle_gaps**: stretches of the traced window longer than 1 ms with no
   device operation, cut at the program's span edges, each piece put down to
-  the innermost program span that covers it on any host thread, summed by
-  span name, with the share that no span covers. Labelled only where the
-  clock check found no violation.
+  the innermost program span that covers it on the thread that launched the
+  program which ended the gap, else on any host thread, summed by span name,
+  with the share that no span covers: `utils/tracing.py` `label_gaps`, the
+  rule by which the program itself fills `device_idle_s` from its
+  `device.window` spans. Labelled only where the clock check found no
+  violation. `main` puts the program's own table of the same run beside it
+  (`program_by_span_s`).
+- **ready_lateness_us**: how late the device watch's stamps are. The k-th
+  `device.ready` annotation less the end of the k-th window program on the
+  device plane, both sorted: the error of every `device.window` span's end.
 - **device_s_by_scope**: device seconds by `gcm.*` named scope of the window
   program (ops/gcm.py), read from the op name that the profiler keeps with
   each operation's metadata.
@@ -56,6 +63,12 @@ import tempfile
 import time
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(REPO_ROOT) not in sys.path:
+    sys.path.insert(0, str(REPO_ROOT))
+
+from tieredstorage_tpu.utils.tracing import (  # noqa: E402
+    DEVICE_READY, _percentile, label_gaps, merge,
+)
 
 #: A device gap shorter than this is not reported, and a burst of device work
 #: is operations no further apart: a window program's operations follow each
@@ -89,18 +102,6 @@ def is_program_line(plane: str, line: str) -> bool:
 
 def is_host_plane(plane: str) -> bool:
     return plane == "/host:CPU"
-
-
-def merge(intervals: list, bridge: int = 0) -> list:
-    """Sorted, disjoint intervals covering the same points, and the gaps no
-    longer than `bridge` between them."""
-    merged: list = []
-    for start, end in sorted(intervals):
-        if merged and start <= merged[-1][1] + bridge:
-            merged[-1][1] = max(merged[-1][1], end)
-        else:
-            merged.append([start, end])
-    return merged
 
 
 def _varint(buf, at: int) -> tuple[int, int]:
@@ -206,34 +207,42 @@ def clock_check(launches: list, waits: list, programs: list) -> dict:
     }
 
 
-def label_gaps(gaps: list, spans: list) -> dict:
-    """Idle nanoseconds by span name. A gap is cut at every span edge inside
-    it, and each piece goes to the innermost (shortest) span that covers it
-    on any host thread: a second between two copies is then the next copy's
-    spool, decode and first context build, not one `gateway.copy`. `spans`
-    are (start, end, name) of every host thread."""
-    starts = sorted(spans)
-    longest = max((end - start for start, end, _ in spans), default=0)
-    by_span: dict = collections.defaultdict(int)
-    uncovered = 0
+def ready_lateness(readies: list, programs: list) -> dict:
+    """Microseconds from a window program's end on the device plane to the
+    `device.ready` annotation of the watch that waited for it, k-th to k-th
+    of both sorted; counted from the end where the trace holds more of one
+    than of the other (a program that ended before the trace began)."""
+    stamps = sorted(start for start, _ in readies)
+    ends = sorted(end for _, end in programs)
+    paired = min(len(stamps), len(ends))
+    late = sorted(
+        (stamp - end) / 1e3
+        for stamp, end in zip(stamps[len(stamps) - paired:], ends[len(ends) - paired:])
+    )
+    out: dict = {"count": paired, "unpaired": len(stamps) + len(ends) - 2 * paired}
+    if late:
+        out.update(
+            min=late[0], p50=_percentile(late, 0.50), p95=_percentile(late, 0.95), max=late[-1]
+        )
+    return out
+
+
+def launcher_of_gap(gaps: list, ran: list, programs: list, launches: list) -> list:
+    """`gaps` with the thread that launched the program which ended each:
+    the first program to start after the gap began, if a window launched it.
+    Launches and window programs pair k-th to k-th from the end, as in the
+    clock check; `launches` are (start, thread)."""
+    starts = sorted(start for start, _ in programs)
+    threads = [thread for _, thread in sorted(launches)]
+    paired = min(len(starts), len(threads))
+    launched_by = dict(zip(starts[len(starts) - paired:], threads[len(threads) - paired:]))
+    ran_starts = sorted(run[0] for run in ran)
+    out = []
     for gap_start, gap_end in gaps:
-        low = bisect.bisect_left(starts, (gap_start - longest,))
-        high = bisect.bisect_left(starts, (gap_end,))
-        over = [span for span in starts[low:high] if span[1] > gap_start]
-        cuts = sorted({
-            gap_start, gap_end,
-            *(t for start, end, _ in over for t in (start, end) if gap_start < t < gap_end),
-        })
-        for piece_start, piece_end in zip(cuts, cuts[1:]):
-            covering = [
-                (end - start, name) for start, end, name in over
-                if start <= piece_start and end >= piece_end
-            ]
-            if covering:
-                by_span[min(covering)[1]] += piece_end - piece_start
-            else:
-                uncovered += piece_end - piece_start
-    return {"by_span": by_span, "uncovered": uncovered}
+        at = bisect.bisect_left(ran_starts, gap_start)
+        ender = ran_starts[at] if at < len(ran_starts) else None
+        out.append((gap_start, gap_end, launched_by.get(ender)))
+    return out
 
 
 def reduce(xplane_path) -> dict:
@@ -242,10 +251,10 @@ def reduce(xplane_path) -> dict:
 
     profile = ProfileData.from_file(str(xplane_path))
     scopes = op_scopes(xplane_path)
-    device, modules, scoped_starts, spans = [], [], [], []
+    device, modules, scoped_starts, spans, readies = [], [], [], [], []
     by_scope: dict = collections.defaultdict(int)
     for plane in profile.planes:
-        for line in plane.lines:
+        for thread, line in enumerate(plane.lines):
             if is_device_line(plane.name, line.name):
                 for event in line.events:
                     start = int(event.start_ns)
@@ -260,9 +269,11 @@ def reduce(xplane_path) -> dict:
                     modules.append([start, start + int(event.duration_ns), event.name])
             elif is_host_plane(plane.name):
                 for event in line.events:
-                    if PROGRAM_SPAN.fullmatch(event.name):
-                        start = int(event.start_ns)
-                        spans.append((start, start + int(event.duration_ns), event.name))
+                    start = int(event.start_ns)
+                    if event.name == DEVICE_READY:
+                        readies.append((start, start + int(event.duration_ns)))
+                    elif PROGRAM_SPAN.fullmatch(event.name):
+                        spans.append((start, start + int(event.duration_ns), event.name, thread))
     busy = merge(device)
     bursts = merge(device, bridge=GAP_NS)
     # The programs the chip ran: the trace's own line of them, else the bursts
@@ -282,7 +293,7 @@ def reduce(xplane_path) -> dict:
     )
     check["programs_from"] = "XLA Modules" if modules else "bursts of XLA Ops"
     check["other_device_programs"] = len(ran) - len(programs)
-    edges = [t for start, end, *_ in spans + device for t in (start, end)]
+    edges = [t for start, end, *_ in spans + readies + device for t in (start, end)]
     window = (min(edges), max(edges)) if edges else (0, 0)
     # `bursts` are what gaps of at most GAP_NS bridge: between them, and
     # before the first and after the last, lie the gaps to report.
@@ -294,12 +305,13 @@ def reduce(xplane_path) -> dict:
     idle_ns = sum(end - start for start, end in gaps)
     idle: dict = {"longer_than_ms": GAP_NS / 1e6, "count": len(gaps), "idle_s": idle_ns / 1e9}
     if check["violations"] == 0 and check["launches"]:
-        labelled = label_gaps(gaps, spans)
+        launches = [(s[0], s[3]) for s in spans if s[2] == "transform.launch"]
+        labelled = label_gaps(launcher_of_gap(gaps, ran, programs, launches), spans)
         idle["by_span_s"] = {
             name: ns / 1e9
             for name, ns in sorted(labelled["by_span"].items(), key=lambda kv: -kv[1])
         }
-        idle["uncovered_share"] = labelled["uncovered"] / idle_ns if idle_ns else 0.0
+        idle["uncovered_share"] = sum(labelled["uncovered"]) / idle_ns if idle_ns else 0.0
     else:
         idle["unlabelled"] = "the clock check did not pass"
     return {
@@ -309,6 +321,7 @@ def reduce(xplane_path) -> dict:
         "program_spans": len(spans),
         "clock_check": check,
         "idle_gaps": idle,
+        "ready_lateness_us": ready_lateness(readies, programs),
         "device_s_by_scope": {
             name: ns / 1e9 for name, ns in sorted(by_scope.items(), key=lambda kv: -kv[1])
         },
@@ -424,6 +437,9 @@ def main(argv: list[str] | None = None) -> int:
             finally:
                 seconds = time.perf_counter() - begin
                 jax.profiler.stop_trace()
+            watch = deployment.rsm.transform_backend.device_watch
+            if watch is not None:
+                watch.settle()
             summary = deployment.rsm.tracer.summary()
             counted = {name: value - before[name] for name, value in counters().items()}
         finally:
@@ -433,6 +449,13 @@ def main(argv: list[str] | None = None) -> int:
             args.keep_trace.parent.mkdir(parents=True, exist_ok=True)
             shutil.copy(xplane, args.keep_trace)
         report = reduce(xplane)
+    # The same table as the program makes it from its own `device.window`
+    # spans (utils/tracing.py `device_idle`), with no profiler.
+    report["idle_gaps"]["program_by_span_s"] = {
+        name: row["device_idle_s"]
+        for name, row in sorted(summary.items(), key=lambda kv: -kv[1].get("device_idle_s", 0.0))
+        if row.get("device_idle_s")
+    }
     for name, row in sorted(summary.items()):
         smoke.emit({"span": name, **{k: round(v, 6) for k, v in row.items()}})
     print(json.dumps({
