@@ -11,6 +11,7 @@ import hashlib
 import hmac
 import re
 import threading
+import time
 import uuid
 import xml.etree.ElementTree as ET
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -25,6 +26,13 @@ class S3State:
         self.lock = threading.Lock()
         # Fault injection queue: (matcher(method, path) -> bool, status, body)
         self.fail_next: list[tuple] = []
+        # Delay queue: (matcher(method, path) -> bool, seconds); the request
+        # that matches is held that long before it is looked at.
+        self.delay_next: list[tuple] = []
+        # One record a request answered, in the order of the replies: method,
+        # path, status, the monotonic times it was met and answered; a part's
+        # number, bytes and SHA-256; a Complete's part numbers as listed.
+        self.requests: list[dict] = []
         # (access_key, secret_key) — when set, every request's SigV4
         # signature is verified against an independent reconstruction from
         # the raw wire request (the way real S3 does; LocalStack-style
@@ -63,6 +71,11 @@ class _Handler(BaseHTTPRequestHandler):
         return self.rfile.read(length) if length else b""
 
     def _reply(self, status: int, body: bytes = b"", headers: dict | None = None) -> None:
+        with self.state.lock:
+            self.state.requests.append({
+                "method": self.command, "path": self.path, "status": status,
+                "met": self._met, "answered": time.monotonic(), **self._noted,
+            })
         self.send_response(status)
         for k, v in (headers or {}).items():
             self.send_header(k, v)
@@ -72,6 +85,16 @@ class _Handler(BaseHTTPRequestHandler):
             self.wfile.write(body)
 
     def _maybe_fail(self) -> bool:
+        self._met, self._noted = time.monotonic(), {}
+        part = re.search(r"partNumber=(\d+)", self.path)
+        if part:
+            self._noted["part"] = int(part.group(1))
+        with self.state.lock:
+            held = next((d for d in self.state.delay_next if d[0](self.command, self.path)), None)
+            if held is not None:
+                self.state.delay_next.remove(held)
+        if held is not None:
+            time.sleep(held[1])
         with self.state.lock:
             for i, entry in enumerate(self.state.fail_next):
                 matcher, status, body = entry[:3]
@@ -162,6 +185,8 @@ class _Handler(BaseHTTPRequestHandler):
                     self._reply(404, _error_xml("NoSuchUpload", upload_id))
                     return
                 self.state.uploads[upload_id][part] = body
+            self._noted = {"part": part, "bytes": len(body),
+                           "sha256": hashlib.sha256(body).hexdigest()}
             etag = f'"{uuid.uuid5(uuid.NAMESPACE_OID, str(hash(body)))}"'
             self._reply(200, headers={"ETag": etag})
             return
@@ -276,8 +301,10 @@ class _Handler(BaseHTTPRequestHandler):
                 if parts is None or target is None:
                     self._reply(404, _error_xml("NoSuchUpload", upload_id))
                     return
-                blob = b"".join(parts[n] for n in sorted(parts))
+                listed = [int(p.findtext("PartNumber")) for p in ET.fromstring(body).iter("Part")]
+                blob = b"".join(parts[n] for n in listed)
                 self.state.objects[target] = blob
+            self._noted = {"parts": listed}
             self._reply(
                 200,
                 _xml("CompleteMultipartUploadResult", {"Bucket": bucket, "Key": key}),
@@ -332,3 +359,9 @@ class S3Emulator:
             self.state.fail_next.append(
                 (matcher, status, _error_xml(code, message), headers)
             )
+
+    def delay(self, seconds: float, when) -> None:
+        """Hold the next request matching `when(method, path)` for `seconds`
+        before it is looked at (so that a later request overtakes it)."""
+        with self.state.lock:
+            self.state.delay_next.append((when, seconds))

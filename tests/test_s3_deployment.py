@@ -12,9 +12,13 @@ reference reads the three objects from the endpoint's directory, the replies
 equal the source, the request counts of a copy are exact, no multipart upload
 is left open, the manifest's Put is last, the altered chunk is refused, every
 `s3.*` span lies under its parent, `/varz` has `s3`, an untraced deployment
-records nothing. Then the endpoint alone: a wrong secret or an altered body is
-403, a short middle part `EntityTooSmall`, Range gives 206 and 416, an object
-is absent until Complete.
+records nothing. Then the part pipeline against the same endpoint at 5 MiB
+parts (PR 38): objects of two parts, three with a short last one, an exact
+multiple and under a part are stored as the serial stream stored them, a part
+that fails leaves no upload open and is aborted after the others returned, a
+skipped `_flush_part` leaves the object one part short. Then the endpoint
+alone: a wrong secret or an altered body is 403, a short middle part
+`EntityTooSmall`, Range gives 206 and 416, an object is absent until Complete.
 """
 
 from __future__ import annotations
@@ -24,7 +28,9 @@ import importlib.util
 import json
 import pathlib
 import subprocess
+import io
 import sys
+import threading
 import time
 import types
 
@@ -198,7 +204,8 @@ def _delta(run, name: str) -> int:
 
 # ------------------------------------------------------------ the deployment
 def test_two_full_parts_and_a_short_last_one_go_out(scenario):
-    parts = _of(scenario.journal, scenario.traced.name, {"UploadPart"})
+    parts = sorted(_of(scenario.journal, scenario.traced.name, {"UploadPart"}),
+                   key=lambda r: r["part"])  # the journal's order is the replies'
     stored = (scenario.traced.name.path(scenario.bucket_dir, "log")).stat().st_size
     assert [(r["part"], r["status"]) for r in parts] == [(1, 200), (2, 200), (3, 200)]
     assert [r["bytes"] for r in parts] == [PART, PART, stored - 2 * PART]
@@ -295,24 +302,63 @@ def test_every_s3_span_lies_under_its_parent(scenario):
     by_id = {s.span_id: s for s in spans}
     names = {s.name for s in spans}
     assert {"s3.upload_part", "s3.put_object", "s3.create_multipart_upload",
-            "s3.complete_multipart_upload", "s3.sign", "s3.part_buffer", "s3.get_object"} <= names
+            "s3.complete_multipart_upload", "s3.sign", "s3.part_buffer", "s3.get_object",
+            "s3.part_wait", "s3.part_handover"} <= names
     assert "s3.abort_multipart_upload" not in names
     calls = {"s3.upload_part", "s3.put_object", "s3.create_multipart_upload",
              "s3.complete_multipart_upload", "s3.get_object"}
     for span in spans:
         if not span.name.startswith("s3."):
             continue
-        parent = by_id[span.parent_id].name
+        parent = by_id[span.parent_id]
         if span.name == "s3.sign":
-            assert parent in calls
+            assert parent.name in calls
         elif span.name == "s3.get_object":
-            assert parent in ("storage.fetch_chunks", "storage.fetch_manifest", "rsm.fetch_index")
+            assert parent.name in ("storage.fetch_chunks", "storage.fetch_manifest", "rsm.fetch_index")
+        elif span.name == "s3.upload_part":
+            # on a worker, in the copy's trace, under the writer's event of no
+            # extent: not a child that `storage.upload`'s own time loses
+            upload = by_id[parent.parent_id]
+            assert (parent.name, upload.name) == ("s3.part_handover", "storage.upload")
+            assert parent.duration_s == 0 and parent.attributes["part"] == span.attributes["part"]
+            assert span.trace_id == upload.trace_id
+            assert span.thread_id != upload.thread_id == parent.thread_id
         else:
-            assert parent == "storage.upload"
+            assert parent.name == "storage.upload" and span.thread_id == parent.thread_id
     # one signature a request
     assert sum(s.name == "s3.sign" for s in spans) == sum(s.name in calls for s in spans)
-    parts = [s for s in spans if s.name == "s3.upload_part"]
-    assert [(s.attributes["part"], s.attributes["bytes"]) for s in parts][:2] == [(1, PART), (2, PART)]
+    parts = sorted((s.attributes["part"], s.attributes["bytes"]) for s in spans
+                   if s.name == "s3.upload_part")
+    assert parts[:2] == [(1, PART), (2, PART)] and len(parts) == 3
+    # a multipart copy's close records its wait, short or long: the row is always there
+    log_upload = by_id[next(s for s in spans if s.name == "s3.part_handover").parent_id]
+    assert [s.name for s in spans if s.parent_id == log_upload.span_id].count("s3.part_wait") >= 2
+
+
+def test_store_write_stays_the_upload_threads_own_time(scenario):
+    """`store_write_s_per_gib.copy` reads `storage.upload`'s `self_s`: the
+    total less what the spans on the upload's own thread cover, whatever the
+    workers' PUTs overlapped."""
+    spans = scenario.traced.spans
+    uploads = [s for s in spans if s.name == "storage.upload"]
+    own = 0.0
+    for upload in uploads:
+        children = [s for s in spans if s.parent_id == upload.span_id]
+        assert all(s.thread_id == upload.thread_id for s in children)
+        covered = sum(s.duration_s for s in children)  # one thread: they do not overlap
+        assert covered <= upload.duration_s
+        own += upload.duration_s - covered
+    assert own > 0
+    assert sum(s.duration_s for s in spans if s.name == "s3.upload_part") > 0
+
+
+def test_the_part_counts_are_in_the_stores_counters(scenario):
+    for run in (scenario.traced, scenario.untraced):
+        assert _delta(run, "part_put_ns") > 0 and _delta(run, "part_wait_ns") > 0
+        assert _delta(run, "part_wait_ns") < _delta(run, "part_put_ns") * 3
+        assert 1 <= run.at_end["parts_in_flight_max"] <= 4
+    waits = sum(s.duration_s for s in scenario.traced.spans if s.name == "s3.part_wait")
+    assert _delta(scenario.traced, "part_wait_ns") == pytest.approx(waits * 1e9, rel=0.25)
 
 
 def test_a_get_objects_span_ends_where_its_body_does(scenario):
@@ -333,7 +379,8 @@ def test_varz_has_s3_and_only_under_that_store(scenario):
     section = scenario.traced.varz["s3"]
     assert section == scenario.traced.at_end
     assert {"upload-part-requests", "get-object-requests", "io-errors", "connections_created",
-            "retries", "bytes_sent_as_parts", "bytes_received_ranged"} <= set(section)
+            "retries", "bytes_sent_as_parts", "bytes_received_ranged",
+            "part_put_ns", "part_wait_ns", "parts_in_flight_max"} <= set(section)
     assert "s3" not in PrometheusExporter([]).varz()
 
     class AnotherStore:
@@ -360,6 +407,144 @@ def test_the_tracer_reaches_the_store_only_through_the_rsm():
     store.tracer = tracer
     assert store.client.tracer is tracer
     assert store.counters()["upload-part-requests"] == 0
+
+
+# ------------------------------------------- the part pipeline, 5 MiB parts
+SIZES = {"two-parts": 2 * PART, "three-parts-short-last": 2 * PART + 4321,
+         "exact-multiple": 3 * PART, "under-a-part": PART - 1}
+
+
+def _blob(n: int, salt: int) -> bytes:
+    return (bytes((i * 7 + salt) % 251 for i in range(4099)) * (n // 4099 + 1))[:n]
+
+
+@pytest.fixture(scope="module")
+def pipeline(tmp_path_factory):
+    """`S3Storage.upload` against the endpoint, once: the four sizes, two
+    uploads at once, one whose second part fails while the third is still on
+    its way, one whose second `_flush_part` is skipped as
+    `benchmark/controls/part_dropped.py` skips it; then the journal."""
+    from tieredstorage_tpu.storage.core import ObjectKey, StorageBackendException
+    from tieredstorage_tpu.storage.s3 import S3Storage
+    from tieredstorage_tpu.storage.s3.multipart import S3MultiPartOutputStream
+
+    e = Endpoint(tmp_path_factory.mktemp("s3-pipeline"))
+    store = S3Storage()
+    store.configure({
+        "s3.bucket.name": BUCKET, "s3.region": "us-east-1", "s3.endpoint.url": e.url,
+        "s3.path.style.access.enabled": True,
+        "aws.access.key.id": ACCESS, "aws.secret.access.key": SECRET,
+    })
+    out = types.SimpleNamespace(sources={}, uploaded={}, bucket_dir=e.bucket_dir)
+    try:
+        for salt, (case, size) in enumerate(SIZES.items()):
+            out.sources[case] = _blob(size, salt)
+            out.uploaded[case] = store.upload(io.BytesIO(out.sources[case]), ObjectKey(f"pipe/{case}"))
+
+        twins = {f"twin-{i}": _blob(4 * PART + i, 40 + i) for i in range(2)}
+        out.sources.update(twins)
+        threads = [threading.Thread(target=store.upload, args=(io.BytesIO(data), ObjectKey(f"pipe/{case}")))
+                   for case, data in twins.items()]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        out.twins_done = not any(t.is_alive() for t in threads)
+
+        put = store.client.upload_part
+
+        def failing(key, upload_id, number, data):
+            if number == 2:
+                raise S3ApiError(500, "InternalError", "injected")
+            if number == 3:
+                time.sleep(0.5)  # still on its way when part 2 has failed
+            return put(key, upload_id, number, data)
+
+        store.client.upload_part = failing
+        try:
+            with pytest.raises(StorageBackendException) as raised:
+                store.upload(io.BytesIO(_blob(6 * PART, 50)), ObjectKey("pipe/failed"))
+            out.failure = raised.value
+        finally:
+            store.client.upload_part = put
+
+        flush = S3MultiPartOutputStream._flush_part
+        _load("controls/part_dropped").apply()
+        try:
+            out.sources["dropped"] = _blob(7 * PART + 99, 60)
+            out.uploaded["dropped"] = store.upload(io.BytesIO(out.sources["dropped"]), ObjectKey("pipe/dropped"))
+        finally:
+            S3MultiPartOutputStream._flush_part = flush
+        out.counters = store.counters()
+        started = list(store._part_workers._executor._threads)
+        store.close()
+        out.workers_joined = bool(started) and not any(t.is_alive() for t in started)
+    finally:
+        store.close()
+        out.journal = e.stop()
+    return out
+
+
+def _pipe(pipeline, case, ops=None):
+    return [r for r in pipeline.journal if r["key"] == f"pipe/{case}" and (ops is None or r["op"] in ops)]
+
+
+@pytest.mark.parametrize("case", list(SIZES))
+def test_an_object_is_stored_as_the_serial_stream_stored_it(pipeline, case):
+    source = pipeline.sources[case]
+    assert pipeline.uploaded[case] == len(source)
+    assert (pipeline.bucket_dir / "pipe" / case).read_bytes() == source
+    full, last = divmod(len(source), PART)
+    answered = sorted((r["op"], r["part"] or 0, r["bytes"], r["status"]) for r in _pipe(pipeline, case))
+    if len(source) < PART:
+        assert answered == [("PutObject", 0, len(source), 200)]
+        return
+    parts = [("UploadPart", n + 1, PART, 200) for n in range(full)]
+    if last:
+        parts.append(("UploadPart", full + 1, last, 200))
+    assert [a for a in answered if a[0] == "UploadPart"] == parts
+    assert sorted(a[0] for a in answered if a[0] != "UploadPart") == [
+        "CompleteMultipartUpload", "CreateMultipartUpload"]
+    # Complete is the last, and the short last part goes out after every full one
+    assert _pipe(pipeline, case)[-1]["op"] == "CompleteMultipartUpload"
+    if last:
+        assert _pipe(pipeline, case, {"UploadPart"})[-1]["part"] == full + 1
+
+
+def test_two_uploads_at_once_keep_their_parts_apart(pipeline):
+    assert pipeline.twins_done
+    for case in ("twin-0", "twin-1"):
+        assert (pipeline.bucket_dir / "pipe" / case).read_bytes() == pipeline.sources[case]
+        assert len({r["upload_id"] for r in _pipe(pipeline, case, {"UploadPart"})}) == 1
+
+
+def test_a_failed_part_is_aborted_after_the_others_returned(pipeline):
+    assert type(pipeline.failure.__cause__) is S3ApiError and pipeline.failure.__cause__.status == 500
+    answered = _pipe(pipeline, "failed")
+    ops = [r["op"] for r in answered]
+    assert ops.count("AbortMultipartUpload") == 1 and ops[-1] == "AbortMultipartUpload"
+    assert "CompleteMultipartUpload" not in ops
+    assert 3 in {r["part"] for r in answered if r["op"] == "UploadPart"}  # waited for, then aborted
+    assert 2 not in {r["part"] for r in answered}
+    assert not (pipeline.bucket_dir / "pipe" / "failed").exists()
+
+
+def test_a_skipped_flush_leaves_the_object_one_part_short(pipeline):
+    source = pipeline.sources["dropped"]
+    assert pipeline.uploaded["dropped"] == len(source)
+    assert sorted(r["part"] for r in _pipe(pipeline, "dropped", {"UploadPart"})) == [1, 3, 4, 5, 6, 7, 8]
+    assert (pipeline.bucket_dir / "pipe" / "dropped").read_bytes() == source[:PART] + source[2 * PART:]
+
+
+def test_the_pipelines_journal_is_clean_and_its_workers_joined(pipeline):
+    assert s3_endpoint.journal_uploads_left_open(pipeline.journal) == 0
+    assert s3_endpoint.journal_requests_refused(pipeline.journal) == 0
+    assert s3_endpoint.journal_parts_under_minimum(pipeline.journal) == 0
+    assert not [p for p in (pipeline.bucket_dir.parent / s3_endpoint.INCOMING).iterdir()]
+    assert pipeline.workers_joined
+    assert pipeline.counters["parts_in_flight_max"] == 4
+    assert pipeline.counters["part_put_ns"] > 0
+    assert pipeline.counters["retries"] == 0
 
 
 # --------------------------------------------------------- the endpoint alone

@@ -8,7 +8,11 @@ plus SigV4 signing vectors and multipart behavior.
 from __future__ import annotations
 
 import datetime
+import hashlib
 import io
+import sys
+import threading
+import time
 
 import pytest
 
@@ -16,8 +20,10 @@ from tests.emulators.s3_emulator import S3Emulator
 from tests.storage_contract import StorageContract
 from tieredstorage_tpu.config.configdef import ConfigException
 from tieredstorage_tpu.metrics.core import MetricName
-from tieredstorage_tpu.storage.core import ObjectKey
+from tieredstorage_tpu.storage.core import ObjectKey, StorageBackendException
 from tieredstorage_tpu.storage.s3 import S3Storage, S3StorageConfig
+from tieredstorage_tpu.storage.s3 import storage as s3_storage
+from tieredstorage_tpu.storage.s3.multipart import S3MultiPartOutputStream
 from tieredstorage_tpu.storage.s3.metrics import GROUP as S3_GROUP
 from tieredstorage_tpu.storage.s3.signer import SigV4Signer
 
@@ -123,6 +129,363 @@ class TestS3Multipart:
             MetricName.of("put-object-requests-total", S3_GROUP)
         )
         assert put_total >= 1.0
+
+
+PART = 1024  # under the configuration's floor: set on the backend, as above
+DEPTH = s3_storage._PARTS_IN_FLIGHT
+
+
+def _pattern(n: int) -> bytes:
+    return (bytes(range(251)) * (n // 251 + 1))[:n]
+
+
+def _is_part(number=None):
+    def matches(method, path):
+        if method != "PUT" or "partNumber=" not in path:
+            return False
+        return number is None or f"partNumber={number}&" in path
+    return matches
+
+
+def _part_workers_alive() -> list:
+    return [t for t in threading.enumerate() if t.name.startswith("s3-part") and t.is_alive()]
+
+
+@pytest.fixture
+def part_backend(emulator):
+    """A backend of 1 KiB parts over a clean emulator; closed afterwards, so
+    that no test leaves a part worker behind."""
+    with emulator.state.lock:
+        emulator.state.objects.clear()
+        emulator.state.requests.clear()
+        emulator.state.fail_next.clear()
+        emulator.state.delay_next.clear()
+    others = set(_part_workers_alive())  # of stores that earlier tests left open
+    backend = make_backend(emulator)
+    backend.part_size = PART
+    yield backend
+    backend.close()
+    assert not set(_part_workers_alive()) - others
+
+
+def _requests(emulator, op=None) -> list:
+    """The emulator's record, classified as the store's collector does."""
+    def kind(r):
+        if r["method"] == "PUT":
+            return "upload-part" if "partNumber=" in r["path"] else "put-object"
+        if r["method"] == "POST":
+            return "create" if r["path"].endswith("?uploads") else "complete"
+        return "abort" if r["method"] == "DELETE" and "uploadId=" in r["path"] else r["method"]
+    with emulator.state.lock:
+        rows = [dict(r, op=kind(r)) for r in emulator.state.requests]
+    return [r for r in rows if op is None or r["op"] == op]
+
+
+def _stored(emulator, key: str) -> bytes:
+    with emulator.state.lock:
+        return emulator.state.objects[("test-bucket", key)]
+
+
+class TestS3PartPipeline:
+    """Parts are put on the store's workers while the writer fills the next:
+    the object, the part boundaries and the requests stay the serial stream's."""
+
+    @pytest.mark.parametrize("size,expected", [
+        (2 * PART, {"create": 1, "upload-part": 2, "complete": 1}),
+        (2 * PART + 77, {"create": 1, "upload-part": 3, "complete": 1}),
+        (3 * PART, {"create": 1, "upload-part": 3, "complete": 1}),
+        (PART - 1, {"put-object": 1}),
+        (0, {"put-object": 1}),
+    ], ids=["two-parts", "three-parts-short-last", "exact-multiple", "under-a-part", "empty"])
+    def test_object_and_requests_are_the_serial_streams(self, emulator, part_backend, size, expected):
+        data = _pattern(size)
+        assert part_backend.upload(io.BytesIO(data), ObjectKey("pipe/object.log")) == size
+        assert _stored(emulator, "pipe/object.log") == data
+        sent = _requests(emulator)
+        assert {op: sum(r["op"] == op for r in sent) for op in {r["op"] for r in sent}} == expected
+        assert all(r["status"] == 200 for r in sent)
+        parts = sorted(_requests(emulator, "upload-part"), key=lambda r: r["part"])
+        assert [r["part"] for r in parts] == list(range(1, len(parts) + 1))
+        assert [r["sha256"] for r in parts] == [
+            hashlib.sha256(data[i : i + PART]).hexdigest() for i in range(0, size, PART)
+        ][: len(parts)]
+        for complete in _requests(emulator, "complete"):
+            assert complete["parts"] == [r["part"] for r in parts]
+        counts = part_backend.counters()
+        assert counts["upload-part-requests"] == expected.get("upload-part", 0)
+        assert counts["bytes_sent_as_parts"] == (size if "create" in expected else 0)
+
+    @pytest.mark.parametrize("part_size,block,size", [
+        (64, 1, 333), (64, 7, 333),
+        (5 << 20, 1 << 20, (12 << 20) + 77), (5 << 20, 7 << 20, (12 << 20) + 77),
+        (5 << 20, 16 << 20, (12 << 20) + 77),
+    ], ids=["1-byte", "7-bytes", "1-MiB", "7-MiB", "whole"])
+    def test_blocks_of_any_size_give_the_same_parts(self, emulator, part_backend, part_size, block, size):
+        part_backend.part_size = part_size
+        data = _pattern(size)
+        out = S3MultiPartOutputStream(
+            part_backend.client, "pipe/blocks.log", part_size, part_backend._part_workers
+        )
+        for i in range(0, size, block):
+            assert out.write(memoryview(data)[i : i + block]) == len(data[i : i + block])
+        out.close()
+        assert out.processed_bytes == size
+        assert _stored(emulator, "pipe/blocks.log") == data
+        parts = sorted(_requests(emulator, "upload-part"), key=lambda r: r["part"])
+        assert [(r["bytes"], r["sha256"]) for r in parts] == [
+            (len(data[i : i + part_size]), hashlib.sha256(data[i : i + part_size]).hexdigest())
+            for i in range(0, size, part_size)
+        ]
+
+    def test_checksum_check_takes_a_view(self, emulator):
+        backend = make_backend(emulator, **{"aws.checksum.check.enabled": True})
+        backend.part_size = PART
+        try:
+            for size in (PART // 2, 2 * PART + 5):
+                data = _pattern(size)
+                backend.upload(io.BytesIO(data), ObjectKey(f"pipe/md5-{size}.log"))
+                assert _stored(emulator, f"pipe/md5-{size}.log") == data
+        finally:
+            backend.close()
+
+    def test_parts_that_finish_out_of_order_are_listed_ascending(self, emulator, part_backend):
+        emulator.delay(0.4, _is_part(1))
+        data = _pattern(3 * PART + 9)
+        part_backend.upload(io.BytesIO(data), ObjectKey("pipe/order.log"))
+        answered = [r["part"] for r in _requests(emulator, "upload-part")]
+        assert sorted(answered[:2]) == [2, 3] and answered[2:] == [1, 4]  # the short last one waits
+        (complete,) = _requests(emulator, "complete")
+        assert complete["parts"] == [1, 2, 3, 4]
+        assert _stored(emulator, "pipe/order.log") == data
+
+    def test_the_last_short_part_goes_out_after_every_full_one(self, emulator, part_backend):
+        emulator.delay(0.3, _is_part(2))
+        part_backend.upload(io.BytesIO(_pattern(2 * PART + 9)), ObjectKey("pipe/last.log"))
+        parts = {r["part"]: r for r in _requests(emulator, "upload-part")}
+        assert parts[3]["met"] >= parts[2]["answered"] and parts[3]["bytes"] == 9
+
+    @pytest.mark.parametrize("status,times", [(500, 3), (403, 1)],
+                             ids=["500-after-retries", "403-at-once"])
+    def test_a_failed_part_aborts_once_after_the_others_returned(
+        self, emulator, part_backend, status, times
+    ):
+        emulator.delay(0.5, _is_part(1))
+        for _ in range(times):  # 500: the transport's retries, exhausted
+            emulator.inject_error(status, "Injected", when=_is_part(2))
+        for _ in range(10):  # the writer is paced by the workers
+            emulator.delay(0.05, lambda m, p: _is_part()(m, p) and not _is_part(2)(m, p))
+        with pytest.raises(StorageBackendException) as raised:
+            part_backend.upload(io.BytesIO(_pattern(12 * PART)), ObjectKey("pipe/failed.log"))
+        assert type(raised.value.__cause__).__name__ == "S3ApiError"
+        assert raised.value.__cause__.status == status
+        sent = _requests(emulator)
+        assert not [r for r in sent if r["op"] == "complete"]
+        (abort,) = [r for r in sent if r["op"] == "abort"]
+        parts = [r for r in sent if r["op"] == "upload-part"]
+        assert {1, 2} <= {r["part"] for r in parts}
+        assert all(r["answered"] <= abort["met"] for r in parts)
+        if times == 1:
+            # the hand-over stopped: about the depth's worth went out, not the twelve
+            assert max(r["part"] for r in parts) <= DEPTH + 2
+        with emulator.state.lock:
+            assert not emulator.state.uploads and not emulator.state.fail_next
+            assert ("test-bucket", "pipe/failed.log") not in emulator.state.objects
+            emulator.state.delay_next.clear()
+        # no worker is left with a part, and the store takes the next upload
+        data = _pattern(2 * PART)
+        part_backend.upload(io.BytesIO(data), ObjectKey("pipe/after.log"))
+        assert _stored(emulator, "pipe/after.log") == data
+
+    def test_a_failed_last_part_raises_from_close(self, emulator, part_backend):
+        for _ in range(3):
+            emulator.inject_error(500, "InternalError", when=_is_part(3))
+        out = S3MultiPartOutputStream(
+            part_backend.client, "pipe/last-failed.log", PART, part_backend._part_workers
+        )
+        out.write(_pattern(2 * PART + 5))
+        with pytest.raises(Exception) as raised:
+            out.close()
+        assert type(raised.value).__name__ == "S3ApiError" and out.closed
+        out.abort()  # again, as `upload`'s except clause does: nothing more is sent
+        assert [r["op"] for r in _requests(emulator)].count("abort") == 1
+        assert not _requests(emulator, "complete")
+
+    def test_a_source_that_fails_leaves_no_object(self, emulator, part_backend):
+        class Source(io.RawIOBase):
+            left = 3
+
+            def read(self, n=-1):
+                if not self.left:
+                    raise OSError("the source broke")
+                self.left -= 1
+                return _pattern(PART)
+
+        with pytest.raises(OSError, match="the source broke"):
+            part_backend.upload(Source(), ObjectKey("pipe/source.log"))
+        ops = [r["op"] for r in _requests(emulator)]
+        assert ops.count("abort") == 1 and "complete" not in ops
+        with emulator.state.lock:
+            assert not emulator.state.uploads
+            assert ("test-bucket", "pipe/source.log") not in emulator.state.objects
+
+    def test_parts_in_flight_never_pass_the_constant(self, emulator, part_backend):
+        for _ in range(20):
+            emulator.delay(0.02, _is_part())
+        data = _pattern(20 * PART + 3)
+        part_backend.upload(io.BytesIO(data), ObjectKey("pipe/depth.log"))
+        assert _stored(emulator, "pipe/depth.log") == data
+        counts = part_backend.counters()
+        assert counts["parts_in_flight_max"] == DEPTH
+        assert counts["part_put_ns"] >= 20 * 0.02e9 and counts["part_wait_ns"] > 0
+        # and the store saw no more than that at once
+        edges = sorted(
+            edge for r in _requests(emulator, "upload-part")
+            for edge in ((r["met"], 1), (r["answered"], -1))
+        )
+        at_once = peak = 0
+        for _, step in edges:
+            at_once += step
+            peak = max(peak, at_once)
+        assert 2 <= peak <= DEPTH
+
+    def test_a_flush_that_is_skipped_leaves_the_object_one_part_short(self, emulator, part_backend, monkeypatch):
+        """The stream under `benchmark/controls/part_dropped.py` itself."""
+        import importlib.util
+        import pathlib
+
+        path = pathlib.Path(__file__).resolve().parents[1] / "benchmark/controls/part_dropped.py"
+        spec = importlib.util.spec_from_file_location("control_part_dropped", path)
+        control = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(control)
+        monkeypatch.setattr(S3MultiPartOutputStream, "_flush_part", S3MultiPartOutputStream._flush_part)
+        control.apply()
+        data = _pattern(9 * PART + 100)  # more parts than the stream has buffers
+        assert part_backend.upload(io.BytesIO(data), ObjectKey("pipe/dropped.log")) == len(data)
+        (complete,) = _requests(emulator, "complete")
+        assert complete["parts"] == [1, 3, 4, 5, 6, 7, 8, 9, 10]
+        assert _stored(emulator, "pipe/dropped.log") == data[:PART] + data[2 * PART :]
+
+    def test_two_uploads_at_once_keep_their_parts_apart(self, emulator, part_backend):
+        for _ in range(12):
+            emulator.delay(0.01, _is_part())
+        blobs = {f"pipe/twin-{i}.log": bytes([65 + i]) * (8 * PART + i) for i in range(2)}
+        failures = []
+
+        def upload(key, data):
+            try:
+                part_backend.upload(io.BytesIO(data), ObjectKey(key))
+            except BaseException as e:  # noqa: BLE001 — reported below
+                failures.append(e)
+
+        threads = [threading.Thread(target=upload, args=item) for item in blobs.items()]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not failures and not any(t.is_alive() for t in threads)
+        for key, data in blobs.items():
+            assert _stored(emulator, key) == data
+        assert len(_requests(emulator, "create")) == 2
+        assert part_backend.counters()["parts_in_flight_max"] <= DEPTH
+
+    def test_many_uploads_at_once_under_a_short_switch_interval(self, emulator, part_backend):
+        blobs = {f"pipe/stress-{i}.log": _pattern(5 * PART + 17 * i) for i in range(24)}
+        failures = []
+
+        def upload(key, data):
+            try:
+                part_backend.upload(io.BytesIO(data), ObjectKey(key))
+            except BaseException as e:  # noqa: BLE001 — reported below
+                failures.append(e)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=upload, args=item) for item in blobs.items()]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not failures and not any(t.is_alive() for t in threads)
+        for key, data in blobs.items():
+            assert _stored(emulator, key) == data
+        counts = part_backend.counters()
+        assert counts["upload-part-requests"] == sum(-(-len(d) // PART) for d in blobs.values())
+        assert counts["parts_in_flight_max"] <= DEPTH
+        assert 0 < len(part_backend._part_workers._executor._threads) <= 8
+
+    def test_close_joins_the_workers_and_a_small_object_starts_none(self, emulator):
+        backend = make_backend(emulator)
+        backend.part_size = PART
+        def ours() -> set:  # the emulator's handler threads come and go
+            return {t for t in threading.enumerate() if "process_request_thread" not in t.name}
+
+        before = ours()
+        backend.upload(io.BytesIO(b"tiny"), ObjectKey("pipe/tiny.log"))
+        assert ours() == before
+        assert {k: backend.counters()[k] for k in ("part_put_ns", "part_wait_ns", "parts_in_flight_max")} == {
+            "part_put_ns": 0, "part_wait_ns": 0, "parts_in_flight_max": 0,
+        }
+        backend.upload(io.BytesIO(_pattern(3 * PART)), ObjectKey("pipe/three.log"))
+        started = ours() - before
+        # tracing is off: nothing but the part workers, and no span
+        assert started and all(t.name.startswith("s3-part") for t in started)
+        assert backend.tracer.recorded_spans == 0
+        backend.close()
+        assert not any(t.is_alive() for t in started)
+        backend.close()  # and again
+
+    def test_spans_the_writers_and_the_workers(self, emulator, part_backend):
+        from tieredstorage_tpu.utils.tracing import Tracer
+
+        tracer = Tracer(enabled=True)
+        part_backend.tracer = tracer
+        emulator.delay(0.2, _is_part(3))
+        with tracer.span("storage.upload") as upload:
+            part_backend.upload(io.BytesIO(_pattern(3 * PART + 5)), ObjectKey("pipe/spans.log"))
+        spans = tracer.spans()
+        by_id = {s.span_id: s for s in spans}
+        assert {s.trace_id for s in spans} == {upload.trace_id}
+        puts = [s for s in spans if s.name == "s3.upload_part"]
+        assert sorted(s.attributes["part"] for s in puts) == [1, 2, 3, 4]
+        for put in puts:
+            handover = by_id[put.parent_id]
+            assert handover.name == "s3.part_handover" and handover.duration_s == 0
+            assert handover.attributes["part"] == put.attributes["part"]
+            assert handover.parent_id == upload.span_id and handover.thread_id == upload.thread_id
+            assert put.thread_id != upload.thread_id
+            assert [s.name for s in spans if s.parent_id == put.span_id] == ["s3.sign"]
+        waits = [s for s in spans if s.name == "s3.part_wait"]
+        assert len(waits) >= 2  # close's, before the short last part and after it
+        assert all(s.parent_id == upload.span_id and s.thread_id == upload.thread_id for s in waits)
+        assert sum(s.duration_s for s in waits) >= 0.15  # part 3 was waited for
+        copies = [s for s in spans if s.name == "s3.part_buffer"]
+        assert len(copies) == 4 and all(s.parent_id == upload.span_id for s in copies)
+        # the workers' seconds are not taken out of the upload thread's own
+        row = tracer.summary()["storage.upload"]
+        on_the_writer = sum(
+            s.duration_s for s in spans
+            if s.parent_id == upload.span_id and s.thread_id == upload.thread_id
+        )
+        assert row["self_s"] == pytest.approx(row["total_s"] - on_the_writer, abs=1e-6)
+        counts = part_backend.counters()
+        assert counts["part_wait_ns"] == pytest.approx(sum(s.duration_s for s in waits) * 1e9, rel=0.2)
+        assert counts["part_put_ns"] >= sum(s.duration_s for s in puts) * 1e9
+
+    def test_a_worker_runs_under_the_writers_deadline(self, emulator, part_backend):
+        from tieredstorage_tpu.utils.deadline import Deadline, deadline_scope
+
+        with deadline_scope(Deadline.after(-1.0)):
+            out = S3MultiPartOutputStream(
+                part_backend.client, "pipe/late.log", PART, part_backend._part_workers
+            )
+            out._upload_id = "never-created"  # Create has its own check: get past it
+            with pytest.raises(StorageBackendException, match="Deadline exceeded"):
+                out.write(_pattern(PART))
+                out.close()
+        assert not _requests(emulator, "upload-part")
 
 
 class TestS3Metrics:

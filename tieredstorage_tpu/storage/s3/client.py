@@ -160,7 +160,7 @@ class S3Client:
         method: str,
         path: str,
         query: Mapping[str, str],
-        payload: bytes,
+        payload: bytes | memoryview,
         extra: Optional[Mapping[str, str]] = None,
     ) -> dict[str, str]:
         headers: dict[str, str] = {"Host": self._host_header()}
@@ -191,7 +191,7 @@ class S3Client:
         key: str,
         *,
         query: Optional[Mapping[str, str]] = None,
-        body: bytes = b"",
+        body: bytes | memoryview = b"",
         extra_headers: Optional[Mapping[str, str]] = None,
         ok: tuple[int, ...] = (200,),
         idempotent: Optional[bool] = None,
@@ -211,7 +211,7 @@ class S3Client:
         return resp
 
     # ------------------------------------------------------------ operations
-    def put_object(self, key: str, data: bytes) -> None:
+    def put_object(self, key: str, data: bytes | memoryview) -> None:
         extra = {"Content-Length": str(len(data))}
         if self.checksum_check:
             import base64
@@ -316,7 +316,11 @@ class S3Client:
             raise S3ApiError(resp.status, "MalformedResponse", "no UploadId in response")
         return upload_id
 
-    def upload_part(self, key: str, upload_id: str, part_number: int, data: bytes) -> str:
+    def upload_part(
+        self, key: str, upload_id: str, part_number: int, data: bytes | memoryview
+    ) -> str:
+        """`data` may be a view of a buffer the caller reuses: it is hashed,
+        and sent as it lies (once more on a retry), before this returns."""
         extra = {"Content-Length": str(len(data))}
         if self.checksum_check:
             import base64

@@ -72,7 +72,10 @@ def _definition() -> ConfigDef:
             importance="medium",
             doc="Size of parts in bytes to use when uploading. All parts but the last one will "
             "have this size. The smaller the part size, the more calls to S3 are needed to "
-            "upload a file; increasing the size reduces calls but means buffering more bytes",
+            "upload a file; increasing the size reduces calls but means buffering more bytes. "
+            "An upload that goes multipart puts up to 4 parts at once while it fills the next "
+            "(a fixed depth, not a key): it buffers up to 5 parts of this size, 25 MiB at the "
+            "default, for each upload in flight",
         )
     )
     d.define(

@@ -42,7 +42,7 @@ class SigV4Signer:
         path: str,
         query: Mapping[str, str],
         headers: dict[str, str],
-        payload: bytes,
+        payload: bytes | memoryview,
         *,
         now: Optional[datetime.datetime] = None,
     ) -> dict[str, str]:

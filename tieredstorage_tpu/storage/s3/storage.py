@@ -21,10 +21,16 @@ from tieredstorage_tpu.storage.httpclient import HttpError, RetryPolicy
 from tieredstorage_tpu.storage.proxy import ProxyConfig, socks5_socket_factory
 from tieredstorage_tpu.storage.s3.client import S3ApiError, S3Client
 from tieredstorage_tpu.storage.s3.config import S3StorageConfig
-from tieredstorage_tpu.storage.s3.multipart import S3MultiPartOutputStream
+from tieredstorage_tpu.storage.s3.multipart import PartWorkers, S3MultiPartOutputStream
 from tieredstorage_tpu.utils.tracing import NOOP_TRACER, Tracer
 
 _COPY_BUFFER = 1024 * 1024
+
+#: Parts of one upload that may be on the store's part workers at once, while
+#: the upload's thread fills the next: 4 x `part_size` of buffers and the one
+#: being filled. Not a configuration key: the depth hides a part's PUT behind
+#: the pull of the next, and past that it buys nothing.
+_PARTS_IN_FLIGHT = 4
 
 #: Request classes and error kinds of `S3MetricCollector` that `counters()` reports.
 _REQUEST_CLASSES = (
@@ -40,6 +46,7 @@ class S3Storage(StorageBackend):
         self.part_size = 0
         self._metric_collector = None
         self._tracer: Tracer = NOOP_TRACER
+        self._part_workers = PartWorkers(_PARTS_IN_FLIGHT)
 
     @property
     def tracer(self) -> Tracer:
@@ -100,7 +107,7 @@ class S3Storage(StorageBackend):
     # --------------------------------------------------------------- upload
     def upload(self, input_stream: BinaryIO, key: ObjectKey) -> int:
         client = self._require_client()
-        out = S3MultiPartOutputStream(client, key.value, self.part_size)
+        out = S3MultiPartOutputStream(client, key.value, self.part_size, self._part_workers)
         try:
             while True:
                 block = input_stream.read(_COPY_BUFFER)
@@ -111,6 +118,11 @@ class S3Storage(StorageBackend):
         except (S3ApiError, HttpError) as e:
             out.abort()
             raise StorageBackendException(f"Failed to upload {key}") from e
+        except BaseException:
+            # The source failed, not the store: what was sent must not become
+            # an object when the stream is collected (`IOBase.__del__` closes).
+            out.abort()
+            raise
         return out.processed_bytes
 
     # ---------------------------------------------------------------- fetch
@@ -186,7 +198,10 @@ class S3Storage(StorageBackend):
         by request class and error totals by kind (the collector the client
         feeds, one observation an attempt), connections the pool has dialled,
         retries (attempts beyond a call's first), body bytes sent as parts
-        and body bytes read of ranged GetObject replies."""
+        and body bytes read of ranged GetObject replies; of the part workers,
+        nanoseconds of `upload_part` calls on them, nanoseconds the uploads'
+        threads stood in `s3.part_wait`, and the most parts one upload has
+        had in flight."""
         client, collector = self._require_client(), self._metric_collector
         out = {
             f"{name}-requests": int(collector.total(f"{name}-requests-total"))
@@ -198,9 +213,11 @@ class S3Storage(StorageBackend):
         out["retries"] = client.http.retries_total
         out["bytes_sent_as_parts"] = client.bytes_sent_as_parts
         out["bytes_received_ranged"] = client.bytes_received_ranged
+        out.update(self._part_workers.counters())
         return out
 
     def close(self) -> None:
+        self._part_workers.close()
         if self.client is not None:
             self.client.close()
 
