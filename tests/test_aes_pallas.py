@@ -281,3 +281,27 @@ class TestTstpuAesRValidation:
 
         with pytest.raises(ValueError):
             _validated_r(r)
+
+
+@pytest.mark.parametrize("step", [0, 1])
+def test_keyed_kernel_body_takes_its_steps_key(monkeypatch, step):
+    """`_aes_keyed_kernel` under the scalar-prefetched step -> slot map: the
+    grid step's own round keys, read from the launch's stacked table."""
+    rng = np.random.default_rng(4 + step)
+    keys = [bytes(range(32)), bytes(range(1, 33)), bytes(range(2, 34))]
+    table = jnp.stack([jnp.asarray(make_rk_planes(k)) for k in keys])  # [3, 15, 16, 8]
+    step_keys = jnp.asarray([2, 0], jnp.int32)
+    w = aes_pallas.WORDS_PER_STEP
+    state = jnp.asarray(rng.integers(0, 2**32, (16, 8, w), dtype=np.uint32))
+    monkeypatch.setattr(aes_pallas.pl, "program_id", lambda axis: step)
+    out_ref = _CollectRef()
+    aes_pallas._aes_keyed_kernel(
+        _ArrayRef(step_keys), _ArrayRef(table.reshape(-1, 128)),
+        _ArrayRef(state.reshape(16, 8, aes_pallas.R, 128)), out_ref,
+    )
+    got = jnp.stack([
+        jnp.stack([out_ref.out[(p, b)] for b in range(8)]) for p in range(16)
+    ]).reshape(16, 8, w)
+    slot = int(step_keys[step])
+    expected = np.asarray(jax.jit(aes_encrypt_planes)(table[slot], state))
+    np.testing.assert_array_equal(np.asarray(got), expected)

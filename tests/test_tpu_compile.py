@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from tieredstorage_tpu.ops import _preflight, aes_bitsliced, gcm, ghash_pallas
+from tieredstorage_tpu.ops import _preflight, aes_bitsliced, aes_pallas, gcm, ghash_pallas
 from tieredstorage_tpu.ops.aes_pallas import aes_encrypt_planes_pallas
 
 CHUNK = 4 << 20   # upstream's documented chunk.size
@@ -230,6 +230,60 @@ def test_varlen_window_program_compiles_one_bucket_down(one_chip, kernels_on):
     ).compile()
     assert kernel_calls(compiled) == 2
     assert compiled.memory_analysis().alias_size_in_bytes >= ROWS * (ctx.max_bytes + 16)
+
+
+@pytest.mark.parametrize("rows", [8, ROWS])
+def test_keyed_window_program_compiles(one_chip, kernels_on, rows):
+    """What a merged flush of several segments' keys launches
+    (`gcm_keyed_window_packed`): the keyed AES and GHASH level-1 kernels in
+    one program, the staged rows donated, a key table of `rows` slots. Its
+    rows reach the level-1 operand as [B, groups, 2048] (a split of the
+    byte axis) and not by a [B, m * 16] -> [B * groups, 2048] reshape,
+    which costs the compiler ~80 s."""
+    ctx = gcm.make_keyed_context(KEY, AAD, CHUNK)
+    slots = gcm.keyed_table_slots(rows)
+
+    def table(array):
+        return tuple(shaped(array, one_chip) for _ in range(slots))
+
+    args = (
+        jax.ShapeDtypeStruct((rows, CHUNK + 16), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+        table(ctx.round_keys), table(ctx.aad_group),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        tuple(table(m) for m in ctx.agg_mats),
+        table(ctx.h_mat), table(ctx.h2_mat), table(ctx.inv_mats),
+    )
+    compiled = gcm._keyed_jit(True).lower(
+        *args, max_bytes=ctx.max_bytes, m_max=ctx.m_max, m_pad=ctx.m_pad, decrypt=True,
+    ).compile()
+    assert kernel_calls(compiled) == 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= rows * (CHUNK + 16)
+    # 1130 MiB at 16 rows when this was written, beside the fixed 16-row
+    # program's 1537
+    assert memory.temp_size_in_bytes < 2 << 30
+
+
+def test_keyed_kernels_compile_at_the_window_width(one_chip):
+    """The two kernels alone: 16 rows of 4 MiB in 9 AES grid steps a row,
+    and 16 rows of 2176 level-1 groups in 128-group tiles."""
+    steps = ROWS * 9
+    aes = aes_pallas.aes_encrypt_planes_keyed_pallas.lower(
+        jax.ShapeDtypeStruct((ROWS, 15, 16, 8), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((steps,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((16, 8, steps * aes_pallas.WORDS_PER_STEP), jnp.uint32,
+                             sharding=one_chip),
+    ).compile()
+    assert kernel_calls(aes) == 1
+    tiles = ROWS * 17
+    ghash = ghash_pallas.ghash_level1_keyed_pallas.lower(
+        jax.ShapeDtypeStruct((tiles * ghash_pallas.KEYED_ROWS_PER_STEP, 2048), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((ROWS, 8, 2048, 128), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((tiles,), jnp.int32, sharding=one_chip),
+    ).compile()
+    assert kernel_calls(ghash) == 1
 
 
 def test_sharded_window_program_has_no_collective(topo, kernels_on):

@@ -345,37 +345,42 @@ class TestCoalescing:
         backend.close()
 
     def test_distinct_keys_never_share_a_launch(self):
+        """Encrypt windows still group by key: two produces under two keys
+        queue in two buckets and flush as two launches (decrypt windows of
+        distinct keys share one launch: TestKeyedMerge)."""
         backend = TpuTransformBackend()
         batcher = WindowBatcher(backend, wait_ms=50, max_windows=8)
+        backend.batcher = batcher
         release = park_fast_path(batcher)
         other_dk = AesEncryptionProvider.create_data_key_and_aad()
         rng = random.Random(22)
         chunks = [bytes(rng.getrandbits(8) for _ in range(800))]
-        enc = TpuTransformBackend()
-        other_wire = enc.transform(
-            chunks, TransformOptions(encryption=other_dk, ivs=[b"\x07" * 12])
-        )
-        enc.close()
-        _, wire = make_window(23, [800])
-        job_a = queued_submit(batcher, wire)
-        payloads, sizes, ivs, tags = parse_wire(other_wire)
-        box_b: list = [None, None]
+        ivs = [b"\x07" * 12]
+        results: dict = {}
 
-        def run_b():
-            try:
-                box_b[0] = batcher.submit(other_dk, payloads, sizes, ivs, tags)
-            except BaseException as exc:  # noqa: BLE001
-                box_b[1] = exc
+        def produce(dk):
+            results[dk.data_key] = backend.transform(
+                chunks, TransformOptions(encryption=dk, ivs=ivs)
+            )
 
-        t_b = threading.Thread(target=run_b)
-        t_b.start()
+        threads = [
+            threading.Thread(target=produce, args=(dk,)) for dk in (DK, other_dk)
+        ]
+        for t in threads:
+            t.start()
         wait_queued(batcher, 2)
+        with batcher._cond:
+            assert len(batcher._buckets) == 2
         assert batcher.flush_now() == 2  # same bucket bytes, distinct keys
         release()
-        job_a[0].join(timeout=30)
-        t_b.join(timeout=30)
-        assert job_a[1][1] is None and box_b[1] is None
-        assert box_b[0] == chunks
+        for t in threads:
+            t.join(timeout=30)
+        control = TpuTransformBackend()
+        for dk in (DK, other_dk):
+            assert results[dk.data_key] == control.transform(
+                chunks, TransformOptions(encryption=dk, ivs=ivs)
+            )
+        control.close()
         assert batcher.launches == 2
         backend.close()
 

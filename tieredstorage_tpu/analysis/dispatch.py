@@ -84,6 +84,7 @@ HOT_PATH_ROOTS = (
     "tieredstorage_tpu/transform/tpu.py:TpuTransformBackend._decrypt_batch",
     "tieredstorage_tpu/ops/gcm.py:gcm_window_packed",
     "tieredstorage_tpu/ops/gcm.py:gcm_varlen_window_packed",
+    "tieredstorage_tpu/ops/gcm.py:gcm_keyed_window_packed",
     "tieredstorage_tpu/fetch/cache/device_hot.py:DeviceHotCache.get_chunks",
     "tieredstorage_tpu/fetch/cache/device_hot.py:DeviceHotCache.device_rows",
     # The cross-request batcher (ISSUE 15) is the decrypt hot path under
@@ -135,6 +136,9 @@ SANCTIONED_MATERIALIZERS = {
 #: bucketed contexts).
 SANCTIONED_JIT_WRAPPERS = {
     "tieredstorage_tpu/ops/gcm.py:_packed_jit",
+    # The merged window of several keys: lru-cached per donation, its
+    # static shapes the bucketed varlen contexts' and the row ladder's.
+    "tieredstorage_tpu/ops/gcm.py:_keyed_jit",
 }
 
 #: Roots of the TRACE-scope closure (ISSUE 13): the packed window impls
@@ -144,6 +148,7 @@ SANCTIONED_JIT_WRAPPERS = {
 TRACE_CLOSURE_ROOTS = (
     "tieredstorage_tpu/ops/gcm.py:_packed_fixed_impl",
     "tieredstorage_tpu/ops/gcm.py:_packed_varlen_impl",
+    "tieredstorage_tpu/ops/gcm.py:_packed_keyed_impl",
 )
 
 #: Trace-scope parameters that carry static Python values (jit
@@ -157,6 +162,11 @@ TRACE_STATIC_PARAMS = {
 #: Trace-scope functions allowed to contain a staged matmul-reduction loop,
 #: with the reason. Burn down, never add without a sentence.
 SANCTIONED_STAGED_REDUCERS = {
+    "tieredstorage_tpu/ops/gcm.py:_ghash_keyed":
+        "a keyed merged window's levels above the first: per-row batched "
+        "matmuls of [B, G, k*128] node bits under each row's own key's "
+        "operand (no tree kernel takes per-row operands); counted by "
+        "planned_keyed_hbm_roundtrips",
     "tieredstorage_tpu/ops/gcm.py:_ghash_grouped":
         "the XLA grouped-power ladder is the TESTED FALLBACK when the "
         "fused GHASH tree kernel cannot engage (no Mosaic on this "
@@ -169,6 +179,8 @@ SANCTIONED_STAGED_REDUCERS = {
 #: the bound name for the rest of the function.
 DEVICE_PRODUCER_NAMES = {
     "gcm_window_packed", "gcm_varlen_window_packed",
+    "gcm_keyed_window_packed", "take_rows", "ctr_keystream_keyed",
+    "aes_encrypt_planes_keyed_pallas", "ghash_level1_keyed_pallas",
     "gcm_encrypt_chunks", "gcm_decrypt_chunks",
     "gcm_encrypt_varlen", "gcm_decrypt_varlen", "_run_varlen",
     "_launch_packed", "_stage_packed", "_encrypt_dispatch",

@@ -120,9 +120,12 @@ def capture_scope():
 def offer_decrypt_window(device, sizes, n_bytes: int, mesh_size: int = 1) -> None:
     """`TpuTransformBackend.on_decrypt_window` target: called with the
     still-device-resident packed output of a VERIFIED decrypt window
-    (``uint8[B(+pad), n_bytes+16]``, row-sharded under a mesh). Dropped
-    unless the calling thread armed a capture scope — unrelated decrypts
-    (scrubber passes, sibling requests) never leak into a window."""
+    (``uint8[B(+pad), n_bytes+16]``, row-sharded under a mesh), or, for a
+    window that rode a merged launch, with a zero-argument maker of a
+    device copy of its own rows, called only if the window is admitted.
+    Dropped unless the calling thread armed a capture scope — unrelated
+    decrypts (scrubber passes, sibling requests) never leak into a
+    window."""
     state = getattr(_CAPTURE, "state", None)
     if state is not None and state.armed:
         state.windows.append((device, tuple(sizes), int(n_bytes), int(mesh_size)))
@@ -543,13 +546,20 @@ class DeviceHotCache(ChunkManager):
             and not opts.compression
         ):
             buffer, sizes, cap_n_bytes, cap_mesh = captured.windows[0]
+            copied = callable(buffer)
+            if copied and sizes == lens:
+                # A merged launch's rows: their own copy, counted at what
+                # it holds on the device (tile padding included).
+                buffer = buffer()
             deleted = getattr(buffer, "is_deleted", None)
             if sizes == lens and not (deleted is not None and deleted()):
                 device = buffer
                 n_bytes = cap_n_bytes
                 mesh_size = cap_mesh
+                held = getattr(buffer, "on_device_size_in_bytes", None)
                 device_nbytes = int(
-                    getattr(buffer, "nbytes", 0)
+                    (held() if copied and held is not None else 0)
+                    or getattr(buffer, "nbytes", 0)
                     or len(lens) * (cap_n_bytes + _TAG_COLUMNS)
                 )
         return HotWindow(

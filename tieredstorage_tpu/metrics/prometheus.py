@@ -213,7 +213,11 @@ class PrometheusExporter:
         `varlen_windows`, the staging ring's counts, the compress codec's
         `codec_bytes_in` and `codec_bytes_copied`, and `device_seen_ns`,
         what the device watch's `device.window` spans add up to under an
-        enabled tracer), and `gateway` where
+        enabled tracer), and `batcher` where the backend has a
+        cross-request batcher (`WindowBatcher.counters()`: windows submitted
+        and taken inline, decrypt launches and their rows, merged decrypt
+        launches and the distinct keys they carried; `enabled` false and
+        nothing else without one), and `gateway` where
         one is wired (`SidecarHttpGateway.counters()`: the bytes of whole
         copy bodies and those written locally, the bytes of streamed
         replies and those of them handed to the socket as views), and `s3`
@@ -244,6 +248,12 @@ class PrometheusExporter:
         dispatch_counts = getattr(self.transform_backend, "dispatch_counts", None)
         if dispatch_counts is not None:
             out["dispatch"] = dispatch_counts()
+        if hasattr(self.transform_backend, "batcher"):
+            batcher = self.transform_backend.batcher
+            out["batcher"] = (
+                {"enabled": True, **batcher.counters()} if batcher is not None
+                else {"enabled": False}
+            )
         if self.gateway is not None:
             out["gateway"] = self.gateway.counters()
         store_counters = getattr(self.storage_backend, "counters", None)

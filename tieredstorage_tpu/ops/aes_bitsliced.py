@@ -475,20 +475,11 @@ def _use_pallas_circuit(n_words: int) -> bool:
     return use_pallas_aes(n_words) and pallas_aes_available()
 
 
-def ctr_keystream_batch(
-    round_keys: jnp.ndarray, ivs: jnp.ndarray, first_counter: int, n_blocks: int
-) -> jnp.ndarray:
-    """Keystream uint8[B, n_blocks, 16] for a batch of per-chunk IVs.
-
-    One bitsliced cipher evaluation covers the whole batch: each chunk's
-    blocks are packed into its own span of words (n_blocks rounded up to a
-    multiple of 32), with that chunk's IV planes broadcast across its span.
-    Replaces the vmapped per-chunk table cipher (gather-bound) with pure
-    XOR/AND on uint32 lanes. On TPU the boolean circuit itself runs as the
-    fused Pallas kernel (ops/aes_pallas.py)."""
-    rk_planes = rk_planes_from_round_keys(round_keys)
+def _ctr_state(ivs: jnp.ndarray, first_counter: int, w: int) -> jnp.ndarray:
+    """Counter-block planes uint32[B, 16, 8, w]: row b's IV bytes as
+    full-word masks, then the big-endian counters first_counter.. packed 32
+    blocks a word, the same for every row."""
     batch = ivs.shape[0]
-    w = (n_blocks + 31) // 32
     total = w * 32
     # Counter planes are identical for every chunk: [4 bytes, 8 bits, w].
     n = first_counter + jnp.arange(total, dtype=jnp.uint32).reshape(w, 32)
@@ -505,13 +496,43 @@ def ctr_keystream_batch(
     # IV planes per chunk: [B, 12, 8] masks broadcast over the chunk's words.
     iv_bits = (ivs.astype(jnp.uint32)[..., None] >> jnp.arange(8)) & 1
     iv_planes = iv_bits * jnp.uint32(0xFFFFFFFF)  # [B, 12, 8]
-    state = jnp.concatenate(
+    return jnp.concatenate(
         [
             jnp.broadcast_to(iv_planes[..., None], (batch, 12, 8, w)),
             jnp.broadcast_to(ctr[None], (batch, 4, 8, w)),
         ],
         axis=1,
     )  # [B, 16, 8, w]
+
+
+def _keystream_bytes(out: jnp.ndarray, n_blocks: int) -> jnp.ndarray:
+    """Cipher output planes uint32[16, 8, B, w] -> keystream uint8[B, n_blocks, 16]."""
+    _, _, batch, w = out.shape
+    j = jnp.arange(32, dtype=jnp.uint32)
+    bits = (out[..., None] >> j) & 1  # [16, 8, B, w, 32]
+    weights_b = (jnp.uint32(1) << jnp.arange(8, dtype=jnp.uint32))[
+        None, :, None, None, None
+    ]
+    bytes_ = jnp.sum(bits * weights_b, axis=1, dtype=jnp.uint32)  # [16, B, w, 32]
+    ks = bytes_.transpose(1, 2, 3, 0).reshape(batch, w * 32, 16).astype(jnp.uint8)
+    return ks[:, :n_blocks]
+
+
+def ctr_keystream_batch(
+    round_keys: jnp.ndarray, ivs: jnp.ndarray, first_counter: int, n_blocks: int
+) -> jnp.ndarray:
+    """Keystream uint8[B, n_blocks, 16] for a batch of per-chunk IVs.
+
+    One bitsliced cipher evaluation covers the whole batch: each chunk's
+    blocks are packed into its own span of words (n_blocks rounded up to a
+    multiple of 32), with that chunk's IV planes broadcast across its span.
+    Replaces the vmapped per-chunk table cipher (gather-bound) with pure
+    XOR/AND on uint32 lanes. On TPU the boolean circuit itself runs as the
+    fused Pallas kernel (ops/aes_pallas.py)."""
+    rk_planes = rk_planes_from_round_keys(round_keys)
+    batch = ivs.shape[0]
+    w = (n_blocks + 31) // 32
+    state = _ctr_state(ivs, first_counter, w)
     # Fold batch into the word axis: [16, 8, B*w].
     state = state.transpose(1, 2, 0, 3).reshape(16, 8, batch * w)
     n_words = batch * w
@@ -526,12 +547,43 @@ def ctr_keystream_batch(
     else:
         out = aes_encrypt_planes(rk_planes, state)
     # Unpack to bytes: [16, 8, B, w] → [B, w*32, 16].
-    out = out.reshape(16, 8, batch, w)
-    j = jnp.arange(32, dtype=jnp.uint32)
-    bits = (out[..., None] >> j) & 1  # [16, 8, B, w, 32]
-    weights_b = (jnp.uint32(1) << jnp.arange(8, dtype=jnp.uint32))[
-        None, :, None, None, None
-    ]
-    bytes_ = jnp.sum(bits * weights_b, axis=1, dtype=jnp.uint32)  # [16, B, w, 32]
-    ks = bytes_.transpose(1, 2, 3, 0).reshape(batch, total, 16).astype(jnp.uint8)
-    return ks[:, :n_blocks]
+    return _keystream_bytes(out.reshape(16, 8, batch, w), n_blocks)
+
+
+def ctr_keystream_keyed(
+    round_key_table: jnp.ndarray,
+    row_keys: jnp.ndarray,
+    ivs: jnp.ndarray,
+    first_counter: int,
+    n_blocks: int,
+) -> jnp.ndarray:
+    """`ctr_keystream_batch` for rows under different keys: round_key_table
+    uint8[slots, 15, 16], row_keys int32[B] the slot of each row.
+
+    On the kernel path every row's span of words is padded to whole grid
+    steps, so each step lies inside one row and the kernel takes the
+    step -> slot map (`aes_pallas.aes_encrypt_planes_keyed_pallas`); the
+    pad is under one step a row (a 4 MiB row: 8193 words in 9 steps of
+    1024). Off the kernel the XLA circuit runs once per row under that
+    row's round keys."""
+    from tieredstorage_tpu.ops import aes_pallas
+
+    batch = ivs.shape[0]
+    w = (n_blocks + 31) // 32
+    table = rk_planes_from_round_keys(round_key_table)  # [slots, 15, 16, 8]
+    if _use_pallas_circuit(batch * w):
+        steps_per_row = -(-w // aes_pallas.WORDS_PER_STEP)
+        w = steps_per_row * aes_pallas.WORDS_PER_STEP
+        state = _ctr_state(ivs, first_counter, w)
+        state = state.transpose(1, 2, 0, 3).reshape(16, 8, batch * w)
+        out = aes_pallas.aes_encrypt_planes_keyed_pallas(
+            table,
+            jnp.repeat(row_keys.astype(jnp.int32), steps_per_row),
+            state,
+            interpret=_preflight.interpret_off_device(),
+        ).reshape(16, 8, batch, w)
+    else:
+        state = _ctr_state(ivs, first_counter, w)
+        out = jax.vmap(aes_encrypt_planes)(table[row_keys], state)  # [B, 16, 8, w]
+        out = out.transpose(1, 2, 0, 3)
+    return _keystream_bytes(out, n_blocks)

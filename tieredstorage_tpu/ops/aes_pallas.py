@@ -111,9 +111,23 @@ def _mix_columns_vars(st: list) -> list:
 def _aes_kernel(rk_ref, in_ref, out_ref):
     """rk_ref: SMEM uint32[15, 128] round-key masks ([rnd, pos*8 + bit]);
     in_ref/out_ref: VMEM uint32[16, 8, R, 128] plane tiles."""
+    _aes_rounds(lambda rnd, i: rk_ref[rnd, i], in_ref, out_ref)
+
+
+def _aes_keyed_kernel(step_key_ref, rk_ref, in_ref, out_ref):
+    """The same circuit under the grid step's own key: step_key_ref is the
+    scalar-prefetched SMEM int32[steps] step -> key-slot map, rk_ref SMEM
+    uint32[slots * 15, 128] the launch's stacked round-key masks."""
+    base = step_key_ref[pl.program_id(0)] * (_NR + 1)
+    _aes_rounds(lambda rnd, i: rk_ref[base + rnd, i], in_ref, out_ref)
+
+
+def _aes_rounds(rk, in_ref, out_ref):
+    """The 14 rounds over one plane tile; ``rk(rnd, i)`` is the scalar mask
+    of round ``rnd``, position-bit ``i``."""
     tw = _tower()
     st = [
-        [in_ref[p, b] ^ rk_ref[0, p * 8 + b] for b in range(8)] for p in range(16)
+        [in_ref[p, b] ^ rk(0, p * 8 + b) for b in range(8)] for p in range(16)
     ]
     for rnd in range(1, _NR + 1):
         # SubBytes: all 16 positions stacked along sublanes, one circuit pass.
@@ -134,7 +148,7 @@ def _aes_kernel(rk_ref, in_ref, out_ref):
         if rnd != _NR:
             st = _mix_columns_vars(st)
         st = [
-            [st[p][b] ^ rk_ref[rnd, p * 8 + b] for b in range(8)] for p in range(16)
+            [st[p][b] ^ rk(rnd, p * 8 + b) for b in range(8)] for p in range(16)
         ]
     for p in range(16):
         for b in range(8):
@@ -215,3 +229,41 @@ def aes_encrypt_planes_pallas(
         interpret=interpret,
     )(rk, st4)
     return out.reshape(16, 8, padded)[:, :, :w]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def aes_encrypt_planes_keyed_pallas(
+    rk_table: jnp.ndarray,
+    step_keys: jnp.ndarray,
+    state: jnp.ndarray,
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Encrypt a bitsliced state uint32[16, 8, steps * WORDS_PER_STEP] in one
+    kernel whose grid steps may each run under a different key:
+    rk_table uint32[slots, 15, 16, 8] round-key masks, step_keys int32[steps]
+    the slot of each step. The caller lays every row's words out in whole
+    steps (a row's span padded to WORDS_PER_STEP), so a key never changes
+    inside a tile; the map is scalar-prefetched and selects the round keys
+    from SMEM, so the vector work is `aes_encrypt_planes_pallas`'s."""
+    w = state.shape[2]
+    if w <= 0 or w % WORDS_PER_STEP:
+        raise ValueError(f"W={w} is not a positive multiple of {WORDS_PER_STEP}")
+    steps = w // WORDS_PER_STEP
+    if step_keys.shape != (steps,):
+        raise ValueError(f"step_keys {step_keys.shape} do not match {steps} steps")
+    st4 = state.reshape(16, 8, steps * R, 128)
+    rk = rk_table.reshape(-1, 128)
+    tile = pl.BlockSpec((16, 8, R, 128), lambda s, keys: (0, 0, s, 0))
+    out = pl.pallas_call(
+        _aes_keyed_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(steps,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile],
+            out_specs=tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct((16, 8, steps * R, 128), jnp.uint32),
+        interpret=interpret,
+    )(step_keys.astype(jnp.int32), rk, st4)
+    return out.reshape(16, 8, w)

@@ -32,7 +32,7 @@ import numpy as np
 
 from tieredstorage_tpu.ops import _preflight, gf128
 from tieredstorage_tpu.ops.aes import aes_encrypt_block_host, key_expansion
-from tieredstorage_tpu.ops.aes_bitsliced import ctr_keystream_batch
+from tieredstorage_tpu.ops.aes_bitsliced import ctr_keystream_batch, ctr_keystream_keyed
 from tieredstorage_tpu.utils.caching import LoadingCache
 from tieredstorage_tpu.utils.locks import new_lock
 
@@ -572,6 +572,16 @@ def _device_consts(ctx, mesh=None) -> tuple:
                 put(ctx.final_mat),
                 put(ctx.const_bits),
             )
+    elif isinstance(ctx, GcmKeyedContext):
+        def build(put):
+            return (
+                put(ctx.round_keys),
+                put(ctx.aad_group),
+                tuple(put(m) for m in ctx.agg_mats),
+                put(ctx.h_mat),
+                put(ctx.h2_mat),
+                put(ctx.inv_mats),
+            )
     else:
         def build(put):
             return (
@@ -1088,6 +1098,313 @@ def gcm_varlen_window_packed(
         m_cap=ctx.m_cap,
         decrypt=decrypt,
     )
+
+
+# --- merged windows of several keys (the batcher's flush) ---
+#
+# Concurrent fetches of different segments hold different data keys and
+# AADs, so a merged launch of their rows takes a per-launch key table of
+# keyed contexts (one max_bytes rung) and a row -> slot index. What depends
+# on the key is read per row from that table: the round keys (the AES
+# kernel's grid steps each select theirs, a row's words filling whole
+# steps), the AAD, every GHASH level's operand and the powers of H.
+#
+# GHASH is taken over each row's blocks as they lie, with no per-row
+# rotation: its AAD right-aligned in one 2 KiB group ahead of its
+# ciphertext's groups, the ciphertext's zero tail after it. That gives
+# T(A||C) * H^d, d = the row's trailing zero blocks, which is multiplied by
+# H^-d (the key's H^(-2^k) matrices, one per bit of d); then
+# Y = T(A||C) * H^2 ^ L * H. GHASH level 1 runs with a row's groups on the
+# MXU's M axis under its own operand (`ghash_pallas.
+# ghash_level1_keyed_pallas`), so a row costs a row and not the eight of
+# the tree kernel's tile; the levels above it are per-row batched matmuls.
+
+#: The level-1 group: 128 blocks of 16 bytes.
+_GROUP_BYTES = 128 * 16
+
+
+@dataclasses.dataclass(frozen=True, eq=False)  # identity hash: weakly cacheable
+class GcmKeyedContext:
+    """Host-precomputed constants of one (key, aad) in a keyed window of
+    one `bucket_max_bytes` rung."""
+
+    round_keys: np.ndarray   # uint8[15,16]
+    aad_group: np.ndarray    # uint8[2048]: the AAD blocks ending the group
+    aad_bit_len: int
+    agg_mats: tuple          # per level: 1 + m_pad/128 groups of 128 blocks
+    h_mat: np.ndarray        # int8[128,128] transposed mult-by-H
+    h2_mat: np.ndarray       # int8[128,128] transposed mult-by-H^2
+    inv_mats: np.ndarray     # int8[bits, 128, 128]: mult-by-H^(-2^k)
+    max_bytes: int
+    m_max: int               # max data blocks
+    m_pad: int               # data blocks padded to whole groups
+
+
+@_counted_build
+def _build_keyed_context(key: bytes, aad: bytes, max_bytes: int) -> GcmKeyedContext:
+    table = _key_table(key)
+    if len(aad) > _GROUP_BYTES:
+        raise ValueError(f"a keyed window takes an AAD of at most {_GROUP_BYTES} bytes")
+    m_max = _ceil_div(max_bytes, 16)
+    m_pad = _ceil_div(m_max, 128) * 128
+    m_a = _ceil_div(len(aad), 16)
+    aad_group = np.zeros(_GROUP_BYTES, np.uint8)
+    if m_a:
+        aad_group[_GROUP_BYTES - m_a * 16 : _GROUP_BYTES - m_a * 16 + len(aad)] = (
+            np.frombuffer(aad, np.uint8)
+        )
+    inverse = gf128.mult_matrix(gf128.gcm_pow(table.h, (1 << 128) - 2)).T.astype(np.int64)
+    inv_mats = [inverse]
+    for _ in range(1, max(1, (m_pad - 1).bit_length())):
+        inv_mats.append((inv_mats[-1] @ inv_mats[-1]) & 1)
+    return GcmKeyedContext(
+        round_keys=table.round_keys,
+        aad_group=aad_group,
+        aad_bit_len=len(aad) * 8,
+        agg_mats=table.agg_mats(m_pad + 128),
+        h_mat=table.power_mat(1),
+        h2_mat=table.power_mat(2),
+        inv_mats=np.stack(inv_mats).astype(np.int8),
+        max_bytes=max_bytes,
+        m_max=m_max,
+        m_pad=m_pad,
+    )
+
+
+_KEYED_CONTEXTS = _single_flight_lru(64)
+
+
+def make_keyed_context(key: bytes, aad: bytes, max_bytes: int) -> GcmKeyedContext:
+    """The keyed-window constants of (key, aad) at `max_bytes`'s rung."""
+    if len(key) != 32:
+        raise ValueError("AES-256 key required")
+    key, aad, max_bytes = bytes(key), bytes(aad), bucket_max_bytes(max_bytes)
+    return _KEYED_CONTEXTS.get(
+        (key, aad, max_bytes), lambda: _build_keyed_context(key, aad, max_bytes)
+    )
+
+
+def _device_aad_len_blocks(lengths: jnp.ndarray, aad_bits: jnp.ndarray) -> jnp.ndarray:
+    """`_device_len_blocks` with a per-row AAD bit length int32[B]."""
+    aad_half = jnp.stack(
+        [
+            jnp.zeros_like(aad_bits, jnp.uint8) if shift >= 32
+            else ((aad_bits >> shift) & 0xFF).astype(jnp.uint8)
+            for shift in range(56, -8, -8)
+        ],
+        axis=1,
+    )
+    return jnp.concatenate([aad_half, _device_len_blocks(lengths, 0)[:, 8:]], axis=1)
+
+
+def _bytes_to_bits(blocks: jnp.ndarray) -> jnp.ndarray:
+    """uint8[B, 16] -> int8[B, 128], most significant bit of byte 0 first
+    (`_bits_to_bytes`' inverse)."""
+    return ((blocks[:, :, None] >> _BIT_SHIFTS) & 1).reshape(blocks.shape[0], 128).astype(jnp.int8)
+
+
+def _rows_times(bits: jnp.ndarray, mats: jnp.ndarray) -> jnp.ndarray:
+    """int8[B, 128] bits times each row's own int8[B, 128, 128] matrix, mod 2."""
+    return (
+        jax.lax.dot_general(
+            bits, mats, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32,
+        )
+        & 1
+    ).astype(jnp.int8)
+
+
+def _ghash_keyed(groups: jnp.ndarray, agg_mats: tuple, row_keys: jnp.ndarray):
+    """groups uint8[B, g, 2048] -> T = sum_i S_i H_row^(m-1-i) over each
+    row's g * 128 blocks, int8[B, 128], each row under its own key:
+    agg_mats holds per level a tuple of the table's operands (slot order)."""
+    from tieredstorage_tpu.ops import ghash_pallas
+
+    batch, g = groups.shape[:2]
+    w1 = jnp.stack(agg_mats[0])  # [slots, 8, 2048, 128]
+    if ghash_pallas.pallas_ghash_available():
+        tile = ghash_pallas.KEYED_ROWS_PER_STEP
+        gp = _ceil_div(g, tile) * tile
+        if gp != g:
+            groups = jnp.concatenate(
+                [jnp.zeros((batch, gp - g, _GROUP_BYTES), jnp.uint8), groups], axis=1
+            )
+        x = ghash_pallas.ghash_level1_keyed_pallas(
+            groups.reshape(batch * gp, _GROUP_BYTES), w1,
+            jnp.repeat(row_keys, gp // tile),
+            interpret=_preflight.interpret_off_device(),
+        ).reshape(batch, gp, 128)
+        # The tile pad's leading groups are zero nodes: level 2 pads to the
+        # same count (its group width is the tile's) or, where g < 128 and
+        # it contracts the g groups at once, takes the trailing g.
+        x = x[:, gp - (g if g < tile else gp):]
+    else:
+        planes = jnp.stack(
+            [(groups >> np.uint8(kbit)) & np.uint8(1) for kbit in range(8)], axis=2
+        ).astype(jnp.int8)  # [B, g, 8, 2048]
+        x = (
+            jax.lax.dot_general(
+                planes, w1[row_keys], (((2, 3), (1, 2)), ((0,), (0,))),
+                preferred_element_type=jnp.int32,
+            )
+            & 1
+        ).astype(jnp.int8)  # [B, g, 128]
+    for level in agg_mats[1:]:
+        w = jnp.stack(level)[row_keys]  # [B, k*128, 128]
+        k = w.shape[1] // 128
+        m = x.shape[1]
+        g = _ceil_div(m, k)
+        if g * k - m:
+            x = jnp.concatenate(
+                [jnp.zeros((batch, g * k - m, 128), jnp.int8), x], axis=1
+            )
+        x = (
+            jax.lax.dot_general(
+                x.reshape(batch, g, k * 128), w, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.int32,
+            )
+            & 1
+        ).astype(jnp.int8)
+    return x[:, 0, :]
+
+
+def _packed_keyed_impl(
+    data_packed, row_keys, round_keys, aad_groups, aad_bits, agg_mats, h_mats,
+    h2_mats, inv_mats,
+    *, max_bytes: int, m_max: int, m_pad: int, decrypt: bool,
+):
+    """A packed varlen window (per-row IV and length in the tail) over rows
+    of different keys: row_keys int32[B] indexes the slot tuples of the
+    key table (`GcmKeyedContext` fields) and aad_bits int32[slots]."""
+    batch = data_packed.shape[0]
+    with jax.named_scope("gcm.pack"):
+        ivs = data_packed[:, max_bytes : max_bytes + 12]
+        lb = data_packed[:, max_bytes + 12 : max_bytes + 16].astype(jnp.int32)
+        lengths = lb[:, 0] | (lb[:, 1] << 8) | (lb[:, 2] << 16) | (lb[:, 3] << 24)
+        data = data_packed[:, :max_bytes]
+
+    with jax.named_scope("gcm.ctr"):
+        ks = ctr_keystream_keyed(jnp.stack(round_keys), row_keys, ivs, 1, m_max + 1)
+        tag_mask = ks[:, 0, :]
+        keystream = ks[:, 1:, :].reshape(batch, m_max * 16)[:, :max_bytes]
+
+    with jax.named_scope("gcm.xor"):
+        byte_mask = (
+            jnp.arange(max_bytes, dtype=jnp.int32)[None, :] < lengths[:, None]
+        ).astype(jnp.uint8)
+        output = (data ^ keystream) * byte_mask
+
+    with jax.named_scope("gcm.ghash"):
+        ct = data if decrypt else output  # zero past each row's length
+        if m_pad * 16 != max_bytes:
+            ct = jnp.concatenate(
+                [ct, jnp.zeros((batch, m_pad * 16 - max_bytes), jnp.uint8)], axis=1
+            )
+        groups = jnp.concatenate(
+            [
+                jnp.stack(aad_groups)[row_keys][:, None, :],
+                ct.reshape(batch, m_pad // 128, _GROUP_BYTES),
+            ],
+            axis=1,
+        )
+        t = _ghash_keyed(groups, agg_mats, row_keys)  # T(A||C) * H^d
+        trailing = m_pad - _ceil_div_dev(lengths)
+        inverse = jnp.stack(inv_mats)[row_keys]  # [B, bits, 128, 128]
+        for bit in range(inverse.shape[1]):
+            t = jnp.where(
+                ((trailing >> bit) & 1)[:, None] == 1,
+                _rows_times(t, inverse[:, bit]), t,
+            )
+        len_bits = _bytes_to_bits(_device_aad_len_blocks(lengths, aad_bits[row_keys]))
+        ghash = (
+            _rows_times(t, jnp.stack(h2_mats)[row_keys])
+            ^ _rows_times(len_bits, jnp.stack(h_mats)[row_keys])
+        ).astype(jnp.uint8)
+    with jax.named_scope("gcm.tag"):
+        tags = _bits_to_bytes(ghash) ^ tag_mask
+    with jax.named_scope("gcm.pack"):
+        return jnp.concatenate([output, tags], axis=1)
+
+
+@functools.lru_cache(maxsize=4)
+def _keyed_jit(donate: bool):
+    return jax.jit(
+        _packed_keyed_impl,
+        static_argnames=("max_bytes", "m_max", "m_pad", "decrypt"),
+        donate_argnums=(0,) if donate else (),
+    )
+
+
+def planned_keyed_hbm_roundtrips(ctx: GcmKeyedContext) -> int:
+    """`planned_hbm_roundtrips` of the keyed program: the keystream
+    handoff, the level-1 operand the kernel reads (the rows' groups behind
+    their AAD groups), one per GHASH level above the first, and the plane
+    stack where level 1 is not the keyed kernel."""
+    from tieredstorage_tpu.ops import ghash_pallas
+
+    return 1 + len(ctx.agg_mats) + (not ghash_pallas.pallas_ghash_available())
+
+
+def keyed_table_slots(rows: int) -> int:
+    """Slots of a keyed launch's key table: its row count, so the table
+    adds no shape of its own to the program (a launch has at most as many
+    keys as rows)."""
+    return rows
+
+
+def gcm_keyed_window_packed(
+    ctxs, row_keys, data_packed, *, decrypt: bool, donate: bool = False,
+):
+    """A merged varlen window whose rows carry different keys and AADs:
+    data_packed uint8[B, max_bytes + 16] in the tail-metadata form of
+    `gcm_varlen_window_packed` (each row's IV and length in its tail),
+    ctxs the launch's key table (`GcmKeyedContext`s of one rung, at most B
+    of them), row_keys int[B] each row's slot. Returns packed `masked
+    output || tag` rows, byte-identical to each row's own key's varlen
+    window. One device dispatch; the table is padded to
+    `keyed_table_slots(B)` with its first entry, whose device constants
+    every padding slot shares."""
+    row_slots = np.asarray(row_keys, dtype=np.int32)
+    rows = len(row_slots)
+    slots = keyed_table_slots(rows)
+    if data_packed.shape[0] != rows:
+        raise ValueError(f"{rows} row keys for a {data_packed.shape[0]}-row window")
+    if not 0 < len(ctxs) <= slots:
+        raise ValueError(f"{len(ctxs)} keys for a {rows}-row window")
+    first = ctxs[0]
+    if any(c.max_bytes != first.max_bytes for c in ctxs):
+        raise ValueError("a keyed window's contexts share one rung")
+    table = list(ctxs) + [first] * (slots - len(ctxs))
+    aad_bits = np.asarray([ctx.aad_bit_len for ctx in table], dtype=np.int32)
+    consts = [_device_consts(ctx) for ctx in table]
+    _count_dispatch()
+    _count_roundtrips(planned_keyed_hbm_roundtrips(first))
+    return _keyed_jit(donate)(
+        jnp.asarray(data_packed, dtype=jnp.uint8),
+        jnp.asarray(row_slots),
+        tuple(c[0] for c in consts),
+        tuple(c[1] for c in consts),
+        jnp.asarray(aad_bits),
+        tuple(tuple(c[2][level] for c in consts) for level in range(len(first.agg_mats))),
+        tuple(c[3] for c in consts),
+        tuple(c[4] for c in consts),
+        tuple(c[5] for c in consts),
+        max_bytes=first.max_bytes,
+        m_max=first.m_max,
+        m_pad=first.m_pad,
+        decrypt=decrypt,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _take_rows_impl(out, first_row, *, n: int):
+    return jax.lax.dynamic_slice_in_dim(out, first_row, n, axis=0)
+
+
+def take_rows(out, first_row: int, n: int):
+    """A device copy of rows [first_row, first_row + n) of a packed window
+    output: a buffer of their own, which does not keep `out` alive. One
+    program per (window shape, n); the row is an argument."""
+    return _take_rows_impl(out, np.int32(first_row), n=n)
 
 
 #: Public alias for composing the GCM core under an outer jit/shard_map

@@ -324,3 +324,62 @@ def ghash_tree_pallas(
         interpret=interpret,
     )(data, w1, step_mat)
     return out[:rows]
+
+
+# -------------------------------------------------------------- keyed level 1
+
+#: Group rows per grid step of the keyed level-1 kernel: one row's groups
+#: are padded to whole tiles, so a tile never spans two keys. 128 is also
+#: the level-2 group width, so the pad is the leading zero groups level 2
+#: adds anyway (gcm.gcm_keyed_window_packed). A keyed window's groups are
+#: always the full 128 blocks (2 KiB) wide.
+KEYED_ROWS_PER_STEP = 128
+
+
+def _ghash_l1_keyed_kernel(tile_key_ref, x_ref, w_ref, o_ref):
+    """`_ghash_l1_kernel` under the tile's own key: tile_key_ref is the
+    scalar-prefetched tile -> key-slot map the weight block follows."""
+    del tile_key_ref  # read by the index maps
+    _ghash_l1_kernel(x_ref, w_ref, o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ghash_level1_keyed_pallas(
+    data: jnp.ndarray,
+    w1: jnp.ndarray,
+    tile_keys: jnp.ndarray,
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """data uint8[R, K] (R a multiple of KEYED_ROWS_PER_STEP, each tile of
+    R one row's groups), w1 int8[slots, 8, K, 128] the launch's level-1
+    operands, tile_keys int32[R / KEYED_ROWS_PER_STEP] -> node bits
+    int8[R, 128].
+
+    The level-1 kernel with the group axis of one GCM row on the MXU's M
+    axis: a row's groups stream through its own key's operand, which is
+    fetched again only where the key changes from one tile to the next.
+    Where the tree kernel pays one operand pass per group for eight rows,
+    this pays one per 128 groups of a row."""
+    rows, k = data.shape
+    t = KEYED_ROWS_PER_STEP
+    if rows <= 0 or rows % t:
+        raise ValueError(f"rows {rows} is not a positive multiple of {t}")
+    if w1.ndim != 4 or w1.shape[1:] != (8, k, 128):
+        raise ValueError(f"weights {w1.shape} do not match K={k}")
+    if tile_keys.shape != (rows // t,):
+        raise ValueError(f"tile_keys {tile_keys.shape} do not match {rows // t} tiles")
+    return pl.pallas_call(
+        _ghash_l1_keyed_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // t,),
+            in_specs=[
+                pl.BlockSpec((t, k), lambda i, keys: (i, 0)),
+                pl.BlockSpec((None, 8, k, 128), lambda i, keys: (keys[i], 0, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((t, 128), lambda i, keys: (i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int8),
+        interpret=interpret,
+    )(tile_keys.astype(jnp.int32), data, w1)

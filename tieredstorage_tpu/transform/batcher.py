@@ -18,13 +18,19 @@ queue under an explicit isolation policy (transform/scheduler.py):
   ``transform_windows`` routes encrypt windows through ``submit_encrypt``
   / ``_EncryptHandle.wait`` — async, so ``pipeline.depth`` overlap is
   preserved — and concurrent produces coalesce the same way.
-- Grouping is by ``(work_class, direction, data_key, aad,
+- Decrypt windows group by ``(work_class, direction, AAD block count,
   bucket_max_bytes(max_size))`` — the SAME jit-shape ladder the unbatched
   varlen path quantizes through (``ops/gcm.py``), so coalescing can never
   introduce a retrace; merged row counts are padded up a power-of-two
-  ladder for the same reason. Classes (and directions) structurally NEVER
-  share a merged launch: a launch failure in a background scrub flush
-  wakes background waiters only, never a latency-class fetch.
+  ladder for the same reason. Concurrent fetches of different segments
+  hold different data keys, so a flush packs a per-launch key table of
+  its windows' distinct ``(data_key, aad)`` and a row -> key index, and
+  one launch of the keyed program (``gcm.gcm_keyed_window_packed``)
+  decrypts them all; a flush of one key launches the single-key varlen
+  program as before. Encrypt windows still group by key as well.
+  Classes (and directions) structurally NEVER share a merged launch: a
+  launch failure in a background scrub flush wakes background waiters
+  only, never a latency-class fetch.
 - The flush policy is deadline-aware and class-aware: a bucket flushes
   when its queued windows or bytes reach the caps, when the oldest waiter
   aged past its class bound (``wait_ms`` for latency/throughput; the
@@ -49,6 +55,16 @@ queue under an explicit isolation policy (transform/scheduler.py):
   ``AuthenticationError``, never its batch-mates. A waiter whose deadline
   expired before launch fails fast with ``DeadlineExceededException`` and
   is excluded from the pack (it cannot poison the batch).
+- **Retention**: once woken, a decrypt waiter offers its own verified rows
+  to the hot tier's capture scope on its own thread, as a device copy of
+  those rows made only if the tier admits them (never a view that would
+  pin the whole merged buffer).
+- **Tracing** (the backend's tracer; nothing with tracing off): the span
+  ``transform.batch_wait`` on a queued waiter from enqueue to woken, and
+  ``transform.batch_flush`` on the flusher around a merged launch's pack,
+  launch, collect and demultiplex, the launch's ``transform.launch`` (and
+  so its ``device.window``) inside it. Exact counts in ``counters()``
+  (``/varz`` ``batcher``).
 
 Accounting: the flusher's launches land in the owning backend's
 ``DispatchStats`` (one launch, one staging transfer, one fetch per flush),
@@ -110,6 +126,13 @@ def bucket_rows(n: int) -> int:
     return 1 << max(3, (n - 1).bit_length())
 
 
+#: The keyed program's smallest row bucket: a merged decrypt flush of up to
+#: 16 rows is one program, so its AES kernel (whose trace and lowering are
+#: ~3-5 s a shape, which the persistent cache does not save) is traced once
+#: and not once for 8 rows and again for 16.
+KEYED_MIN_ROWS = 16
+
+
 @dataclasses.dataclass
 class _PendingWindow:
     """One caller's window, queued for a shared launch. Mutated by the
@@ -136,6 +159,14 @@ class _PendingWindow:
     #: per-class added-wait exemplars resolve a launch back to the
     #: concrete requests that rode it.
     trace_id: Optional[str] = None
+    #: The window's own key and AAD (None: the bucket key's).
+    data_key: Optional[bytes] = None
+    aad: Optional[bytes] = None
+    #: Distinct keys of the launch the window rode.
+    keys: int = 0
+    #: (merged output, first row, row width) of a verified decrypt window,
+    #: for the waiter's hot-tier offer; dropped once offered.
+    offer: Optional[tuple] = None
 
 
 class _EncryptHandle:
@@ -244,8 +275,9 @@ class WindowBatcher:
         #: the lock-order checker sees wait() release the held lock).
         self._cond = new_condition("batcher.WindowBatcher._cond")
         #: bucket key (work_class, decrypt, data_key, aad, bucket_bytes)
-        #: -> queued entries. One class + one direction per merged launch,
-        #: structurally.
+        #: -> queued entries; a decrypt bucket's data_key is None and its
+        #: aad the AAD's block count (every key of that shape shares it).
+        #: One class + one direction per merged launch, structurally.
         self._buckets: dict[tuple, list[_PendingWindow]] = {}
         self._launch_s: list[float] = []
         self._inflight = 0
@@ -281,6 +313,12 @@ class WindowBatcher:
         #: distinguishable from demanded background work (scrub).
         self.speculative_windows = 0
         self.speculative_bytes = 0
+        #: Decrypt launches (fast-path windows and merged flushes) and their
+        #: rows; merged decrypt launches and the distinct keys they carried.
+        self.decrypt_launches = 0
+        self.decrypt_launch_rows = 0
+        self.merged_launches = 0
+        self.merged_launch_keys = 0
 
     # --------------------------------------------------------------- lifecycle
     def start(self) -> "WindowBatcher":
@@ -354,6 +392,21 @@ class WindowBatcher:
             note_mutation("batcher.WindowBatcher._class_refill_at")
             self._cond.notify()
 
+    def counters(self) -> dict:
+        """Exact counts (``/varz`` ``batcher``): windows submitted and taken
+        by the fast path; decrypt launches and their rows, fast path and
+        merged flushes together; merged decrypt launches and the distinct
+        keys they carried."""
+        with self._cond:
+            return {
+                "windows_submitted": self.windows_submitted,
+                "fast_path_windows": self.fast_path_windows,
+                "decrypt_launches": self.decrypt_launches,
+                "decrypt_launch_rows": self.decrypt_launch_rows,
+                "merged_launches": self.merged_launches,
+                "merged_launch_keys": self.merged_launch_keys,
+            }
+
     def class_queued(self) -> dict[str, int]:
         """Currently queued windows per class (the queue-depth gauges)."""
         out = {cls: 0 for cls in WORK_CLASSES}
@@ -407,6 +460,10 @@ class WindowBatcher:
                 note_mutation("batcher.WindowBatcher._inflight")
                 self.fast_path_windows += 1
                 note_mutation("batcher.WindowBatcher.fast_path_windows")
+                self.decrypt_launches += 1
+                note_mutation("batcher.WindowBatcher.decrypt_launches")
+                self.decrypt_launch_rows += len(sizes)
+                note_mutation("batcher.WindowBatcher.decrypt_launch_rows")
         if fast:
             # Idle batcher: dispatch inline through the ordinary unbatched
             # window path — light load pays zero added latency and keeps
@@ -423,10 +480,23 @@ class WindowBatcher:
                     if self._buckets:
                         self._cond.notify()
 
-        entry = self._enqueue(
-            enc, payloads, sizes, ivs, tags, work_class, decrypt=True
-        )
-        return self._await_entry(entry)
+        with self._backend.tracer.span(
+            "transform.batch_wait", rows=len(sizes), fast=False
+        ) as span:
+            entry = self._enqueue(
+                enc, payloads, sizes, ivs, tags, work_class, decrypt=True
+            )
+            try:
+                plain = self._await_entry(entry)
+            finally:
+                if span is not None:
+                    span.attributes.update(
+                        keys=entry.keys, occupancy=entry.occupancy
+                    )
+        offer, entry.offer = entry.offer, None
+        if offer is not None:
+            self._backend._offer_rows(*offer, sizes)
+        return plain
 
     def submit_encrypt(self, chunks, opts) -> _EncryptHandle:
         """Encrypt one window, coalescing with CONCURRENT produces.
@@ -504,11 +574,14 @@ class WindowBatcher:
             decrypt=decrypt,
             trace_id=flightrecorder.current_trace_id(),
         )
+        entry.data_key, entry.aad = bytes(enc.data_key), bytes(enc.aad)
+        # A decrypt bucket holds every key of its shape (the flush's key
+        # table); the AAD's block count is part of the program's shape.
         key = (
             work_class,
             decrypt,
-            bytes(enc.data_key),
-            bytes(enc.aad),
+            None if decrypt else entry.data_key,
+            -(-len(entry.aad) // 16) if decrypt else entry.aad,
             gcm_ops.bucket_max_bytes(max(sizes)),
         )
         with self._cond:
@@ -707,15 +780,20 @@ class WindowBatcher:
             self.launch_retries += 1
             note_mutation("batcher.WindowBatcher.launch_retries")
 
-    def _launch_once(self, ctx, packed, decrypt: bool, work_class: str):
+    def _launch_once(
+        self, ctx, packed, decrypt: bool, work_class: str, row_keys=None
+    ):
         """One stage + launch attempt of a merged flush, replay-safe: each
         attempt re-stages from the host-side ``packed`` buffer because the
         staged device buffer is donated by the launch. ``device.launch`` is
-        the fault-injection seam (keyed by work class). Returns the device
-        output buffer; the caller owns the sanctioned ``np.asarray``."""
+        the fault-injection seam (keyed by work class). With ``row_keys``
+        ``ctx`` is the launch's key table. Returns the device output
+        buffer; the caller owns the sanctioned ``np.asarray``."""
         faults.fire("device.launch", work_class)
         staged = self._backend._stage_packed(packed, True)
-        return self._backend._launch_packed(ctx, staged, True, decrypt=decrypt)
+        return self._backend._launch_packed(
+            ctx, staged, True, decrypt=decrypt, row_keys=row_keys
+        )
 
     def _flush_group(self, key: tuple, entries: list) -> None:
         """ONE shared launch for a bucket's queued windows: merge rows into
@@ -756,127 +834,182 @@ class WindowBatcher:
             return
 
         backend = self._backend
-        try:
-            ctx = gcm_ops.make_varlen_context(key[2], key[3], key[4])
-            n_bytes = ctx.max_bytes
-            rows = sum(len(e.sizes) for e in live)
-            packed = np.zeros((bucket_rows(rows), n_bytes + TAG_SIZE), np.uint8)
+        table = list(dict.fromkeys(self._key_of(e, key) for e in live))
+        keyed = decrypt and backend.keyed_launches()
+        if len(table) > 1 and not keyed:
+            # A mesh shards the rows, and the keyed program has no sharded
+            # form: one launch per key.
+            for key_aad in table:
+                self._flush_group(
+                    key, [e for e in live if self._key_of(e, key) == key_aad]
+                )
+            return
+        with backend.tracer.span(
+            "transform.batch_flush", windows=len(live), keys=len(table)
+        ) as flush_span:
+            try:
+                # Decrypt rows run the keyed program under the flush's key
+                # table (one key or several); encrypt rows, of one key, the
+                # single-key varlen program.
+                make = gcm_ops.make_keyed_context if keyed else gcm_ops.make_varlen_context
+                ctxs = [make(k, a, key[4]) for k, a in table]
+                slot = {key_aad: i for i, key_aad in enumerate(table)}
+                n_bytes = ctxs[0].max_bytes
+                rows = sum(len(e.sizes) for e in live)
+                # A buffer of the backend's staging ring, already mapped (a
+                # fresh 64 MiB is ~60 ms of page faults on a gVisor host):
+                # every byte the program reads is written here.
+                n_rows = bucket_rows(rows)
+                packed = backend._acquire_staging(
+                    (max(n_rows, KEYED_MIN_ROWS) if keyed else n_rows, n_bytes + TAG_SIZE)
+                )
+                # Padding rows run under slot 0.
+                row_keys = np.zeros(len(packed), np.int32)
+                r = 0
+                for e in live:
+                    row_keys[r : r + len(e.sizes)] = slot[self._key_of(e, key)]
+                    for i, p in enumerate(e.payloads):
+                        packed[r, : e.sizes[i]] = np.frombuffer(p, np.uint8)
+                        packed[r, e.sizes[i] : n_bytes] = 0
+                        packed[r, n_bytes : n_bytes + IV_SIZE] = e.ivs[i]
+                        r += 1
+                    packed[r - len(e.sizes) : r, n_bytes + IV_SIZE :] = (
+                        np.asarray(e.sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
+                    )
+                # Row-ladder padding mirrors _stage_packed's mesh padding: one
+                # 16-byte block per dummy row (zero-length rows are excluded
+                # by the varlen contract).
+                packed[rows:] = 0
+                packed[rows:, n_bytes + IV_SIZE] = 16
+                if flush_span is not None:
+                    flush_span.attributes.update(rows=rows, bucket_rows=len(packed))
+                ctx = ctxs if keyed else ctxs[0]
+                slots = row_keys if keyed else None
+                t0 = self._now()
+                out = call_with_retry(
+                    lambda: self._launch_once(ctx, packed, decrypt, work_class, slots),
+                    policy=self._launch_policy,
+                    site="device.launch",
+                    on_retry=self._on_launch_retry,
+                )
+                with backend.tracer.span("transform.d2h_wait") as wait:
+                    host = np.asarray(out)
+                if wait is not None:
+                    backend._split_wait(wait, out)
+                launch_s = self._now() - t0
+            except BaseException as exc:  # noqa: BLE001 - every waiter must wake
+                with self._cond:
+                    self.launch_failures += 1
+                    note_mutation("batcher.WindowBatcher.launch_failures")
+                # Classes never share a merged launch, so this failure is
+                # delivered to THIS class's waiters alone.
+                for e in live:
+                    e.error = exc
+                    e.event.set()
+                return
+            backend._note_batched_fetch()
+            for e in live:
+                backend._note_window(e.n_bytes, len(e.sizes), n_bytes, True)
+
+            occupancy = len(live)
+            with self._cond:
+                self._batch_seq += 1
+                note_mutation("batcher.WindowBatcher._batch_seq")
+                batch_id = self._batch_seq
+                self.launches += 1
+                note_mutation("batcher.WindowBatcher.launches")
+                self.batched_windows += occupancy
+                note_mutation("batcher.WindowBatcher.batched_windows")
+                self.class_launches[work_class] += 1
+                note_mutation("batcher.WindowBatcher.class_launches")
+                self.class_flushed_windows[work_class] += occupancy
+                note_mutation("batcher.WindowBatcher.class_flushed_windows")
+                if decrypt:
+                    self.decrypt_launches += 1
+                    note_mutation("batcher.WindowBatcher.decrypt_launches")
+                    self.decrypt_launch_rows += rows
+                    note_mutation("batcher.WindowBatcher.decrypt_launch_rows")
+                    self.merged_launches += 1
+                    note_mutation("batcher.WindowBatcher.merged_launches")
+                    self.merged_launch_keys += len(table)
+                    note_mutation("batcher.WindowBatcher.merged_launch_keys")
+                self._launch_s.append(launch_s)
+                if len(self._launch_s) > self.LAUNCH_SAMPLES:
+                    del self._launch_s[0]
+
+            added_waits: list[float] = []
             r = 0
             for e in live:
-                for i, p in enumerate(e.payloads):
-                    packed[r, : e.sizes[i]] = np.frombuffer(p, np.uint8)
-                    packed[r, n_bytes : n_bytes + IV_SIZE] = e.ivs[i]
-                    r += 1
-                packed[r - len(e.sizes) : r, n_bytes + IV_SIZE :] = (
-                    np.asarray(e.sizes, dtype="<u4").view(np.uint8).reshape(-1, 4)
-                )
-            # Row-ladder padding mirrors _stage_packed's mesh padding: one
-            # 16-byte block per dummy row (zero-length rows are excluded
-            # by the varlen contract).
-            packed[rows:, n_bytes + IV_SIZE] = 16
-            t0 = self._now()
-            out = call_with_retry(
-                lambda: self._launch_once(ctx, packed, decrypt, work_class),
-                policy=self._launch_policy,
-                site="device.launch",
-                on_retry=self._on_launch_retry,
-            )
-            host = np.asarray(out)
-            launch_s = self._now() - t0
-        except BaseException as exc:  # noqa: BLE001 - every waiter must wake
-            with self._cond:
-                self.launch_failures += 1
-                note_mutation("batcher.WindowBatcher.launch_failures")
-            # Classes never share a merged launch, so this failure is
-            # delivered to THIS class's waiters alone.
-            for e in live:
-                e.error = exc
-                e.event.set()
-            return
-        backend._note_batched_fetch()
-        for e in live:
-            backend._note_window(e.n_bytes, len(e.sizes), n_bytes, True)
-
-        occupancy = len(live)
-        with self._cond:
-            self._batch_seq += 1
-            note_mutation("batcher.WindowBatcher._batch_seq")
-            batch_id = self._batch_seq
-            self.launches += 1
-            note_mutation("batcher.WindowBatcher.launches")
-            self.batched_windows += occupancy
-            note_mutation("batcher.WindowBatcher.batched_windows")
-            self.class_launches[work_class] += 1
-            note_mutation("batcher.WindowBatcher.class_launches")
-            self.class_flushed_windows[work_class] += occupancy
-            note_mutation("batcher.WindowBatcher.class_flushed_windows")
-            self._launch_s.append(launch_s)
-            if len(self._launch_s) > self.LAUNCH_SAMPLES:
-                del self._launch_s[0]
-
-        added_waits: list[float] = []
-        r = 0
-        for e in live:
-            n = len(e.sizes)
-            if decrypt:
-                bad = [
-                    i
-                    for i in range(n)
-                    if not hmac.compare_digest(
-                        host[r + i, n_bytes:].tobytes(), e.tags[i]
-                    )
-                ]
-                if bad:
-                    # Per-row error isolation: one forged row fails ITS
-                    # request; batch-mates still get their plaintext.
-                    e.error = AuthenticationError(
-                        f"GCM tag mismatch on chunks {bad}"
-                    )
-                else:
-                    e.result = [
-                        host[r + i, : e.sizes[i]].tobytes() for i in range(n)
+                n = len(e.sizes)
+                if decrypt:
+                    bad = [
+                        i
+                        for i in range(n)
+                        if not hmac.compare_digest(
+                            host[r + i, n_bytes:].tobytes(), e.tags[i]
+                        )
                     ]
-            else:
-                # Encrypt demux: the same wire assembly _encrypt_finish
-                # does — IV || ciphertext || tag per row.
-                e.result = [
-                    e.ivs[i].tobytes()
-                    + host[r + i, : e.sizes[i]].tobytes()
-                    + host[r + i, n_bytes:].tobytes()
-                    for i in range(n)
-                ]
-            r += n
-            e.batch_id = batch_id
-            e.occupancy = occupancy
-            e.added_wait_ms = max(0.0, (t0 - e.enqueued_at) * 1000.0)
-            added_waits.append(e.added_wait_ms)
-            e.event.set()
-        with self._cond:
-            self.class_added_wait_ms[work_class] += sum(added_waits)
-            note_mutation("batcher.WindowBatcher.class_added_wait_ms")
-        tl = self.timeline
-        if tl is not None:
-            # Outside _cond by design: the timeline ring has its own lock
-            # and class_queued() re-takes _cond for the depth snapshot.
-            tl.record_flush(
-                batch_id=batch_id,
-                work_class=work_class,
-                decrypt=decrypt,
-                bucket_bytes=key[4],
-                rows=rows,
-                n_bytes=sum(e.n_bytes for e in live),
-                occupancy=occupancy,
-                queued_age_ms=max(
-                    0.0, (t0 - min(e.enqueued_at for e in live)) * 1000.0
-                ),
-                begin_s=t0,
-                end_s=t0 + launch_s,
-                queue_depths=self.class_queued(),
-                trace_ids=[e.trace_id for e in live],
-            )
-        hook = self.on_flush
-        if hook is not None:
-            hook(
-                occupancy, added_waits, work_class,
-                batch_id, [e.trace_id for e in live],
-            )
+                    if bad:
+                        # Per-row error isolation: one forged row fails ITS
+                        # request; batch-mates still get their plaintext.
+                        e.error = AuthenticationError(
+                            f"GCM tag mismatch on chunks {bad}"
+                        )
+                    else:
+                        e.result = [
+                            host[r + i, : e.sizes[i]].tobytes() for i in range(n)
+                        ]
+                        e.offer = (out, r, n_bytes)
+                else:
+                    # Encrypt demux: the same wire assembly _encrypt_finish
+                    # does — IV || ciphertext || tag per row.
+                    e.result = [
+                        e.ivs[i].tobytes()
+                        + host[r + i, : e.sizes[i]].tobytes()
+                        + host[r + i, n_bytes:].tobytes()
+                        for i in range(n)
+                    ]
+                r += n
+                e.batch_id = batch_id
+                e.occupancy = occupancy
+                e.keys = len(table)
+                e.added_wait_ms = max(0.0, (t0 - e.enqueued_at) * 1000.0)
+                added_waits.append(e.added_wait_ms)
+                e.event.set()
+            backend._release_staging(packed)
+            with self._cond:
+                self.class_added_wait_ms[work_class] += sum(added_waits)
+                note_mutation("batcher.WindowBatcher.class_added_wait_ms")
+            tl = self.timeline
+            if tl is not None:
+                # Outside _cond by design: the timeline ring has its own lock
+                # and class_queued() re-takes _cond for the depth snapshot.
+                tl.record_flush(
+                    batch_id=batch_id,
+                    work_class=work_class,
+                    decrypt=decrypt,
+                    bucket_bytes=key[4],
+                    rows=rows,
+                    n_bytes=sum(e.n_bytes for e in live),
+                    occupancy=occupancy,
+                    queued_age_ms=max(
+                        0.0, (t0 - min(e.enqueued_at for e in live)) * 1000.0
+                    ),
+                    begin_s=t0,
+                    end_s=t0 + launch_s,
+                    queue_depths=self.class_queued(),
+                    trace_ids=[e.trace_id for e in live],
+                )
+            hook = self.on_flush
+            if hook is not None:
+                hook(
+                    occupancy, added_waits, work_class,
+                    batch_id, [e.trace_id for e in live],
+                )
+
+    @staticmethod
+    def _key_of(entry: _PendingWindow, key: tuple) -> tuple:
+        """(data_key, aad) of a queued window."""
+        if entry.data_key is None:
+            return key[2], key[3]
+        return entry.data_key, entry.aad

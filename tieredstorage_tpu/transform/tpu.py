@@ -37,6 +37,7 @@ except ImportError:  # pragma: no cover - exercised only without zstandard
 from tieredstorage_tpu import native
 from tieredstorage_tpu.ops import gcm as gcm_ops
 from tieredstorage_tpu.ops.gcm import (
+    gcm_keyed_window_packed,
     gcm_varlen_window_packed,
     gcm_window_packed,
     make_context,
@@ -689,7 +690,15 @@ class TpuTransformBackend(TransformBackend):
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
         return staged
 
-    def _launch_packed(self, ctx, staged, varlen: bool, *, decrypt: bool):
+    def keyed_launches(self) -> bool:
+        """Whether a merged window may carry rows of several keys in one
+        launch (`ops.gcm.gcm_keyed_window_packed`): on one device. The
+        keyed program has no sharded form."""
+        return self.mesh_plan().mesh is None
+
+    def _launch_packed(
+        self, ctx, staged, varlen: bool, *, decrypt: bool, row_keys=None
+    ):
         """ONE fused device dispatch for a staged window (keystream → XOR →
         GHASH → tag in a single program, `output || tag` packed into a
         single buffer), with the staged buffer donated back to XLA as the
@@ -703,13 +712,19 @@ class TpuTransformBackend(TransformBackend):
         `transform.launch` span is the host's enqueue time: placing the
         context's constants on their first use, the jitted call (`traced`
         when it traced a new program: seconds, where a launch is
-        milliseconds) and starting the copy back."""
+        milliseconds) and starting the copy back. With `row_keys` the
+        window's rows carry different keys: `ctx` is the launch's key table
+        and the keyed varlen program runs (`keyed_launches`)."""
         mesh = self.mesh_plan().mesh
         with self.tracer.span("transform.launch") as span:
             traces = thread_program_traces()
             before = gcm_ops.thread_dispatches()
             rt_before = gcm_ops.thread_hbm_roundtrips()
-            if varlen:
+            if row_keys is not None:
+                out = gcm_keyed_window_packed(
+                    ctx, row_keys, staged, decrypt=decrypt, donate=True,
+                )
+            elif varlen:
                 out = gcm_varlen_window_packed(
                     ctx, None, staged, None, decrypt=decrypt, donate=True,
                     mesh=mesh,
@@ -732,6 +747,20 @@ class TpuTransformBackend(TransformBackend):
                 span.attributes["traced"] = thread_program_traces() > traces
                 self._watch_window(out, span, varlen, decrypt)
         return out
+
+    def _offer_rows(self, out, first_row: int, n_bytes: int, sizes) -> None:
+        """Offer a verified decrypt window that rode a merged launch to the
+        retention hook, on the waiter's own thread (whose capture scope it
+        fills): its rows of the merged output `out`, from `first_row`. The
+        hook is handed a maker of a device copy of just those rows, so a
+        retained window holds its own device bytes and never pins the
+        merged buffer, and nothing is copied unless the tier admits."""
+        hook = self.on_decrypt_window
+        if hook is not None:
+            hook(
+                functools.partial(gcm_ops.take_rows, out, first_row, len(sizes)),
+                sizes, n_bytes, self.mesh_plan().size,
+            )
 
     def _watch_window(self, out, launch, varlen: bool, decrypt: bool) -> None:
         """Hand a window launched under an enabled tracer to the device
@@ -902,10 +931,10 @@ class TpuTransformBackend(TransformBackend):
         """The unbatched decrypt window: ONE staging transfer, ONE fused
         launch, ONE fetch for this caller's rows alone. Also the
         batcher's single-waiter fast path (zero added latency at light
-        load — including the hot-tier retention hook, which only fires
-        here: a merged buffer interleaves requests and is never offered
-        for retention). `compressed` is the manifest's word that the rows
-        are compressed chunks (`_window_context`)."""
+        load, the hot-tier retention hook included; a merged window's
+        rows are offered by `_offer_rows` instead). `compressed` is the
+        manifest's word that the rows are compressed chunks
+        (`_window_context`)."""
         ctx, n_bytes, varlen = self._window_context(enc, sizes, compressed)
         packed = self._build_packed(payloads, sizes, ivs, n_bytes, varlen)
         staged = self._stage_packed(packed, varlen)
@@ -973,7 +1002,13 @@ def _definition():
             "encrypts / background scrub verification — classes never "
             "share a merged launch) whose flush policy is deadline- and "
             "class-aware, grouped by the bucket_max_bytes jit-shape ladder "
-            "so coalescing never retraces. A foreground submit that finds "
+            "so coalescing never retraces. Decrypt windows of different "
+            "segments merge whatever their data keys: a flush carries a "
+            "per-launch key table and each row is decrypted and verified "
+            "under its own key and AAD (on one device; under a mesh each "
+            "key launches apart); encrypt windows merge within one key. A "
+            "merged window's verified rows are offered to the device hot "
+            "tier as a copy of their own. A foreground submit that finds "
             "the batcher idle dispatches inline (the single-waiter fast "
             "path), so light load pays zero added latency. Default off: "
             "every window dispatches unbatched, exactly the pre-batch "
