@@ -386,7 +386,7 @@ class TestSchedulerPolicy:
         plain, wire = make_window(106, [512])
         _, entry = self.inject(batcher, BACKGROUND, wire, now=0.0)
         assert batcher.flush_now() == 1
-        assert entry.error is None and entry.result == plain
+        assert entry.error is None and batcher._await_entry(entry) == plain
         batcher._backend.close()
 
 
@@ -545,7 +545,7 @@ class TestEncryptCoalescing:
         assert stats.windows == n
         assert stats.dispatches == 1
         assert stats.dispatches_per_window < 1.0
-        assert stats.d2h_fetches == 1
+        assert stats.d2h_fetches == n  # each handle fetches its own rows
         # Donation/roundtrip gates hold through the merge: the ONE merged
         # launch donated its staged buffer, and the shared program stays
         # within the per-window roundtrip budget.

@@ -284,9 +284,9 @@ def test_row_key_table_never_mixes_two_callers_keys(monkeypatch):
     launched = []
     real = backend._launch_packed
 
-    def spy(ctx, staged, varlen, *, decrypt, row_keys=None):
+    def spy(ctx, staged, varlen, *, decrypt, row_keys=None, **kw):
         launched.append((ctx, None if row_keys is None else list(row_keys)))
-        return real(ctx, staged, varlen, decrypt=decrypt, row_keys=row_keys)
+        return real(ctx, staged, varlen, decrypt=decrypt, row_keys=row_keys, **kw)
 
     monkeypatch.setattr(backend, "_launch_packed", spy)
     held = _Held(backend)
@@ -370,6 +370,7 @@ def test_merged_flush_spans_and_counts(traced):
     held.submit_all([(dk, w) for dk, (_, w) in zip(dks, windows)])
     counts = held.batcher.counters()
     assert counts["merged_launches"] == 1 and counts["merged_launch_keys"] == 3
+    assert counts["waiter_collected_rows"] == 3 and counts["overlapped_launches"] == 0
     if traced:
         assert backend.device_watch.settle(60)
         [flush] = backend.tracer.spans("transform.batch_flush")
@@ -384,6 +385,22 @@ def test_merged_flush_spans_and_counts(traced):
         assert launch.parent_id == flush.span_id
         [window] = backend.tracer.spans("device.window")
         assert window.parent_id == launch.span_id
+        # Each waiter's collect, on its own thread inside its batch_wait, and
+        # split at the ready stamp into ready_wait and collect, which tile it.
+        d2h = backend.tracer.spans("transform.d2h_wait")
+        assert sorted(d.parent_id for d in d2h) == sorted(w.span_id for w in waits)
+        by_wait = {w.span_id: w for w in waits}
+        for d in d2h:
+            assert d.thread_id == by_wait[d.parent_id].thread_id != flush.thread_id
+            parts = [
+                s for s in backend.tracer.spans()
+                if s.parent_id == d.span_id
+                and s.name in ("transform.ready_wait", "transform.collect")
+            ]
+            assert "transform.collect" in {p.name for p in parts}
+            assert sum(p.end_s - p.start_s for p in parts) == pytest.approx(
+                d.end_s - d.start_s, abs=1e-6
+            )
     else:
         assert backend.tracer.spans() == []
         assert backend.device_watch is None
@@ -402,6 +419,7 @@ def test_varz_has_the_batchers_counts():
         "enabled": True, "windows_submitted": 0, "fast_path_windows": 0,
         "decrypt_launches": 0, "decrypt_launch_rows": 0,
         "merged_launches": 0, "merged_launch_keys": 0,
+        "overlapped_launches": 0, "waiter_collected_rows": 0,
     }
     assert "batcher" not in PrometheusExporter([]).varz()
     backend.close()
@@ -416,6 +434,8 @@ def test_fast_path_window_counts_as_a_one_window_launch():
     counts = batcher.counters()
     assert counts["fast_path_windows"] == counts["decrypt_launches"] == 1
     assert counts["decrypt_launch_rows"] == 2 and counts["merged_launches"] == 0
+    # the inline window is collected by its own finish, not a waiter's
+    assert counts["waiter_collected_rows"] == counts["overlapped_launches"] == 0
     backend.close()
 
 
@@ -433,3 +453,290 @@ def test_a_mesh_flushes_one_key_a_launch():
     assert [b[0] for b in boxes] == [plain for plain, _ in windows]
     assert held.batcher.counters()["merged_launches"] == 2
     held.close()
+
+
+# ------------------------------------------------ the waiters' own collect
+def _aesgcm_rows(dk: DataKeyAndAAD, wire) -> list[bytes]:
+    """What `cryptography` AESGCM decrypts each wire chunk to."""
+    aead = AESGCM(dk.data_key)
+    return [aead.decrypt(c[:IV_SIZE], c[IV_SIZE:], dk.aad) for c in wire]
+
+
+@pytest.mark.parametrize("n_keys,rows_each,ragged,bucket", [
+    (1, 1, False, 16),
+    (5, 2, True, 16),
+    (16, 1, True, 16),
+    (9, 2, False, 32),
+    (2, 3, True, 8),
+], ids=["1key", "5keys-ragged", "16keys-ragged", "9keys-32rows", "mesh-8rows"])
+def test_each_waiter_collects_aesgcm_plaintext_row_by_row(n_keys, rows_each, ragged, bucket):
+    from tieredstorage_tpu.parallel.mesh import data_mesh
+
+    mesh = bucket == 8  # a mesh launches one key at a time, in 8-row buckets
+    backend = TpuTransformBackend(mesh=data_mesh() if mesh else None)
+    held = _Held(backend)
+    shapes = []
+    real = backend._acquire_staging
+
+    def spy(shape):
+        shapes.append(shape[0])
+        return real(shape)
+
+    backend._acquire_staging = spy
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(n_keys)]
+    sizes = [
+        [1000 - 37 * ((k + r) % 5) if ragged else 1000 for r in range(rows_each)]
+        for k in range(n_keys)
+    ]
+    windows = [_wire(dk, sizes[k], seed=100 + k) for k, dk in enumerate(dks)]
+    boxes = held.submit_all([(dk, w) for dk, (_, w) in zip(dks, windows)])
+    for dk, (plain, wire), (result, _) in zip(dks, windows, boxes):
+        assert result == _aesgcm_rows(dk, wire) == plain
+    assert set(shapes) == {bucket}
+    counts = held.batcher.counters()
+    assert counts["waiter_collected_rows"] == n_keys * rows_each
+    # one launch, or on the mesh one a key back to back
+    assert counts["overlapped_launches"] <= counts["merged_launches"] - 1
+    assert held.backend.dispatch_stats.d2h_fetches == n_keys  # one a waiter
+    held.close()
+
+
+class _Gate:
+    """Holds every waiter at the door of its collect until opened."""
+
+    def __init__(self, batcher: WindowBatcher) -> None:
+        self.open = threading.Event()
+        self.arrived = threading.Semaphore(0)
+        real = batcher._collect
+
+        def held_collect(entry):
+            self.arrived.release()
+            assert self.open.wait(60)
+            return real(entry)
+
+        batcher._collect = held_collect
+
+
+def _ring_holds(backend: TpuTransformBackend, rows: int) -> int:
+    with backend._stats_lock:
+        return sum(len(v) for k, v in backend._staging_free.items() if k[0] == rows)
+
+
+@pytest.mark.parametrize("case", ["clean", "forged", "take_fails", "launch_fails"])
+def test_staging_is_never_back_before_a_waiters_rows(monkeypatch, case):
+    held = _Held(TpuTransformBackend())
+    gate = _Gate(held.batcher)
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(3)]
+    windows = [_wire(dk, [800], seed=120 + i) for i, dk in enumerate(dks)]
+    jobs = [(dk, w) for dk, (_, w) in zip(dks, windows)]
+    if case == "forged":
+        forged = list(windows[1][1])
+        forged[0] = forged[0][:-1] + bytes([forged[0][-1] ^ 1])
+        jobs[1] = (dks[1], forged)
+    if case == "take_fails":
+        def no_take(out, first_row, n):
+            raise RuntimeError("take failed")
+
+        monkeypatch.setattr(gcm, "take_rows", no_take)
+    if case == "launch_fails":
+        def no_launch(*args, **kwargs):
+            raise RuntimeError("launch failed")
+
+        held.batcher._launch_policy = type(held.batcher._launch_policy)(max_attempts=1)
+        monkeypatch.setattr(held.backend, "_launch_packed", no_launch)
+    results: list = []
+    runner = threading.Thread(target=lambda: results.append(held.submit_all(jobs)))
+    runner.start()
+    if case != "launch_fails":
+        for _ in jobs:  # every waiter is at its collect: the launch is out
+            assert gate.arrived.acquire(timeout=60)
+        assert held.batcher.counters()["merged_launches"] == 1
+        assert _ring_holds(held.backend, 16) == 0
+    gate.open.set()
+    runner.join(60)
+    assert not runner.is_alive()
+    [boxes] = results
+    back = _ring_holds(held.backend, 16)
+    if case == "clean":
+        assert [b[0] for b in boxes] == [p for p, _ in windows] and back == 1
+    elif case == "forged":
+        assert isinstance(boxes[1][0], AuthenticationError) and back == 1
+        assert boxes[0][0] == windows[0][0] and boxes[2][0] == windows[2][0]
+    else:  # no waiter's rows came back: the buffer is dropped, never reused
+        assert all(isinstance(b[0], RuntimeError) for b in boxes) and back == 0
+    with held.batcher._cond:
+        assert held.batcher._uncollected == 0 and held.batcher._inflight == 1
+    held.close()
+
+
+def test_a_launch_being_collected_keeps_its_staging_buffer():
+    """A second flush of the same shape packs while the first launch's
+    second waiter is still held at its collect: the first launch's buffer is
+    not back in the ring until that waiter has left, so the pack never
+    writes over an output (on a zero-copy placement, the same memory) that
+    a waiter has still to read."""
+    held = _Held(TpuTransformBackend())
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(4)]
+    windows = [_wire(dk, [900], seed=160 + i) for i, dk in enumerate(dks)]
+    opened, arrived = threading.Event(), threading.Semaphore(0)
+    real = held.batcher._collect
+    held_key = bytes(dks[1].data_key)
+
+    def collect(entry):
+        if entry.data_key == held_key:
+            arrived.release()
+            assert opened.wait(60)
+        return real(entry)
+
+    held.batcher._collect = collect
+    first_jobs = [(dk, w) for dk, (_, w) in zip(dks[:2], windows[:2])]
+    results: list = []
+    runner = threading.Thread(target=lambda: results.append(held.submit_all(first_jobs)))
+    runner.start()
+    assert arrived.acquire(timeout=60)  # the second waiter is at its collect
+    deadline = time.monotonic() + 60
+    while held.batcher.counters()["waiter_collected_rows"] < 1:  # the first has its rows
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    assert _ring_holds(held.backend, 16) == 0
+    second = held.submit_all([(dk, w) for dk, (_, w) in zip(dks[2:], windows[2:])])
+    assert [b[0] for b in second] == [p for p, _ in windows[2:]]
+    assert held.batcher.counters()["overlapped_launches"] == 1
+    opened.set()
+    runner.join(60)
+    assert not runner.is_alive()
+    [first] = results
+    assert [b[0] for b in first] == [p for p, _ in windows[:2]]
+    assert _ring_holds(held.backend, 16) == 2
+    held.close()
+
+
+@pytest.mark.parametrize("path", ["merged_decrypt", "merged_encrypt", "unbatched"])
+def test_only_a_merged_decrypt_launch_skips_the_whole_output_copy(monkeypatch, path):
+    """A merged decrypt launch starts no copy back of its whole output (each
+    waiter takes only its own rows); a merged encrypt launch, whose handles
+    read the whole output, and every unbatched launch do."""
+    from jax._src.array import ArrayImpl
+
+    from tieredstorage_tpu.transform.api import DetransformOptions, TransformOptions
+
+    copied: list = []
+    real = ArrayImpl.copy_to_host_async
+
+    def spy(self):
+        copied.append(self.shape)
+        return real(self)
+
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", spy)
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(2)]
+    windows = [_wire(dk, [700], seed=170 + i) for i, dk in enumerate(dks)]
+    backend = TpuTransformBackend()
+    if path == "unbatched":
+        assert backend.detransform(windows[0][1], DetransformOptions(encryption=dks[0])) == windows[0][0]
+        assert len(copied) == 1
+        backend.close()
+        return
+    held = _Held(backend)
+    if path == "merged_decrypt":
+        boxes = held.submit_all([(dk, w) for dk, (_, w) in zip(dks, windows)])
+        assert [b[0] for b in boxes] == [p for p, _ in windows]
+        assert copied == []
+    else:
+        handles = [
+            held.batcher.submit_encrypt(plain, TransformOptions(encryption=dks[0]))
+            for plain, _ in windows
+        ]
+        assert held.batcher.flush_now() == 1
+        wires = [h.wait() for h in handles]
+        assert [
+            [AesEncryptionProvider.decrypt_chunk(c, dks[0].data_key, dks[0].aad) for c in w]
+            for w in wires
+        ] == [p for p, _ in windows]
+        assert len(copied) == 1
+    assert held.batcher.counters()["waiter_collected_rows"] == 2
+    held.close()
+
+
+def test_a_failed_take_fails_only_its_own_waiter(monkeypatch):
+    held = _Held(TpuTransformBackend())
+    real = gcm.take_rows
+
+    def take(out, first_row, n):
+        if first_row == 1:
+            raise RuntimeError("take failed")
+        return real(out, first_row, n)
+
+    monkeypatch.setattr(gcm, "take_rows", take)
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(3)]
+    windows = [_wire(dk, [500], seed=130 + i) for i, dk in enumerate(dks)]
+    boxes = held.submit_all([(dk, w) for dk, (_, w) in zip(dks, windows)])
+    failed = [i for i, b in enumerate(boxes) if isinstance(b[0], RuntimeError)]
+    assert len(failed) == 1
+    assert all(boxes[i][0] == windows[i][0] for i in range(3) if i not in failed)
+    held.close()
+
+
+@pytest.mark.parametrize("order", ["in_order", "reversed"])
+def test_merged_encrypt_handles_collect_the_unbatched_wire(order):
+    """Each handle builds its own IV || ct || tag on the thread that waits;
+    a handle not yet waited for holds neither the cap nor `_inflight`."""
+    from tieredstorage_tpu.transform.api import TransformOptions
+
+    dk = AesEncryptionProvider.create_data_key_and_aad()
+    rng = random.Random(140)
+    windows = [[rng.randbytes(s) for s in (700, 333)] for _ in range(3)]
+    ivs = [bytes([i + 1]) * IV_SIZE for i in range(6)]
+    opts = [TransformOptions(encryption=dk, ivs=ivs[2 * i : 2 * i + 2]) for i in range(3)]
+    control = TpuTransformBackend()
+    expect = [control.transform(w, o) for w, o in zip(windows, opts)]
+    control.close()
+    backend = TpuTransformBackend()
+    held = _Held(backend)
+    handles = [held.batcher.submit_encrypt(w, o) for w, o in zip(windows, opts)]
+    assert held.batcher.flush_now() == 1
+    with held.batcher._cond:
+        assert held.batcher._uncollected == 0 and held.batcher._inflight == 1
+    picks = range(3) if order == "in_order" else reversed(range(3))
+    got = {i: handles[i].wait() for i in picks}
+    assert [got[i] for i in range(3)] == expect
+    assert [
+        [AesEncryptionProvider.decrypt_chunk(c, dk.data_key, dk.aad) for c in wire]
+        for wire in expect
+    ] == windows
+    assert held.batcher.counters()["waiter_collected_rows"] == 6
+    held.close()
+
+
+@pytest.mark.parametrize("started", [True, False], ids=["flusher", "flush_now"])
+def test_inflight_returns_to_zero_after_every_flush(started):
+    backend = TpuTransformBackend()
+    dks = [AesEncryptionProvider.create_data_key_and_aad() for _ in range(6)]
+    windows = [_wire(dk, [600], seed=150 + i) for i, dk in enumerate(dks)]
+    if started:
+        batcher = backend.enable_batching(wait_ms=20)
+        results: list = [None] * 6
+        barrier = threading.Barrier(6)
+
+        def fetch(i):
+            barrier.wait(timeout=30)
+            results[i] = batcher.submit(dks[i], *_parse(windows[i][1]))
+
+        threads = [threading.Thread(target=fetch, args=(i,)) for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert results == [p for p, _ in windows]
+    else:
+        held = _Held(backend)
+        batcher = held.batcher
+        for _ in range(2):  # two rounds drained by flush_now
+            boxes = held.submit_all([(dk, w) for dk, (_, w) in zip(dks, windows)])
+            assert held.flushes == 1
+            assert [b[0] for b in boxes] == [p for p, _ in windows]
+        with batcher._cond:
+            batcher._inflight -= 1  # un-park
+    with batcher._cond:
+        assert batcher._inflight == 0 and batcher._uncollected == 0
+        assert not batcher._buckets
+    backend.close()

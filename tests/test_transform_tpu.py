@@ -95,7 +95,7 @@ class TestTagVerification:
         decision) but reopens the remote timing side channel the CPU path's
         `cryptography` verify closes, so pin it at the source level — at
         BOTH verify sites: the direct window path and the cross-request
-        batcher's merged-flush demux (ISSUE 15)."""
+        batcher's waiter-side collect of its own rows of a merged flush."""
         import inspect
 
         from tieredstorage_tpu.transform import batcher as batcher_mod
@@ -104,9 +104,9 @@ class TestTagVerification:
         src = inspect.getsource(tpu_mod.TpuTransformBackend._decrypt_window)
         assert "hmac.compare_digest" in src
         assert "!= received_tags" not in src
-        flush_src = inspect.getsource(batcher_mod.WindowBatcher._flush_group)
-        assert "hmac.compare_digest" in flush_src
-        assert "!= e.tags" not in flush_src
+        collect_src = inspect.getsource(batcher_mod.WindowBatcher._collect)
+        assert "hmac.compare_digest" in collect_src
+        assert "!= entry.tags" not in collect_src
 
 
 class TestMeshSharding:

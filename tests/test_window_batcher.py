@@ -258,8 +258,8 @@ class TestFlushPolicy:
         self.clock[0] = 3.5
         assert batcher.flush_now() == 1
         assert isinstance(boundary.error, DeadlineExceededException)
-        assert boundary.result is None
-        assert on_time.error is None and on_time.result == plain
+        assert boundary.launch is None  # nothing of the launch to collect
+        assert on_time.error is None and batcher._await_entry(on_time) == plain
         assert batcher.expired_windows == 1
 
     def test_added_wait_is_exact_on_a_fake_clock(self):
@@ -276,7 +276,7 @@ class TestFlushPolicy:
             batcher._buckets[key] = [entry]
         self.clock[0] = 3.5
         assert batcher.flush_now() == 1
-        assert entry.error is None and entry.result == plain
+        assert entry.error is None and batcher._await_entry(entry) == plain
         assert entry.added_wait_ms == pytest.approx(2500.0)
         assert waits == [pytest.approx(2500.0)]
 
@@ -319,7 +319,7 @@ class TestCoalescing:
         stats = backend.dispatch_stats
         assert stats.windows == 3
         assert stats.dispatches == 1
-        assert stats.d2h_fetches == 1
+        assert stats.d2h_fetches == 3  # each waiter fetches its own rows
         assert stats.dispatches_per_window == pytest.approx(1 / 3, abs=1e-3)
         backend.close()
 
@@ -488,6 +488,195 @@ class TestCoalescing:
             assert box[1] is boom
         assert batcher.launch_failures == 1
         assert batcher.launches == 0
+        backend.close()
+
+
+class TestWaiterCollect:
+    """The flusher stops at the launch: waiters collect their own rows, so
+    a second merged launch goes out while the first is being collected, up
+    to ``pipeline_depth`` launches uncollected."""
+
+    @staticmethod
+    def hold_collects(batcher: WindowBatcher):
+        """Every waiter stops at the door of its collect until released."""
+        gate, arrived = threading.Event(), threading.Semaphore(0)
+        real = batcher._collect
+
+        def held(entry):
+            arrived.release()
+            assert gate.wait(30)
+            return real(entry)
+
+        batcher._collect = held
+        return gate, arrived
+
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_launches_overlap_up_to_the_cap(self, depth):
+        backend = TpuTransformBackend()
+        backend.pipeline_depth = depth
+        batcher = WindowBatcher(backend, wait_ms=50, max_windows=8)
+        release = park_fast_path(batcher)
+        gate, arrived = self.hold_collects(batcher)
+        windows = [make_window(200 + i, [640]) for i in range(depth + 1)]
+        jobs = []
+        for i, (_, wire) in enumerate(windows[:depth]):
+            jobs.append(queued_submit(batcher, wire))
+            wait_queued(batcher, 1)
+            assert batcher.flush_now() == 1  # returns at the launch
+            assert arrived.acquire(timeout=30)  # its waiter holds its collect
+            assert batcher.counters()["overlapped_launches"] == i
+        with batcher._cond:
+            assert batcher._uncollected == depth
+            assert batcher._inflight == depth + 1  # + the parked fast path
+        # One more launch would pass the cap: the flush waits for a collect.
+        jobs.append(queued_submit(batcher, windows[depth][1]))
+        wait_queued(batcher, 1)
+        drained = []
+        flusher = threading.Thread(target=lambda: drained.append(batcher.flush_now()))
+        flusher.start()
+        flusher.join(0.3)
+        assert flusher.is_alive() and batcher.merged_launches == depth
+        gate.set()
+        flusher.join(30)
+        assert not flusher.is_alive() and drained == [1]
+        for (t, box), (plain, _) in zip(jobs, windows):
+            t.join(timeout=30)
+            assert box[1] is None and box[0] == plain
+        counts = batcher.counters()
+        assert counts["merged_launches"] == depth + 1
+        # the last went out once a collect had finished; others may not have
+        assert depth - 1 <= counts["overlapped_launches"] <= depth
+        assert counts["waiter_collected_rows"] == depth + 1
+        release()
+        with batcher._cond:
+            assert batcher._uncollected == 0 and batcher._inflight == 0
+        backend.close()
+
+    def test_flusher_leaves_due_windows_queued_at_the_cap(self):
+        backend = TpuTransformBackend()
+        backend.pipeline_depth = 1
+        batcher = WindowBatcher(backend, wait_ms=1, max_windows=8).start()
+        release = park_fast_path(batcher)
+        gate, arrived = self.hold_collects(batcher)
+        windows = [make_window(220 + i, [512]) for i in range(3)]
+        first = queued_submit(batcher, windows[0][1])
+        assert arrived.acquire(timeout=30)  # launched; its collect is held
+        later = [queued_submit(batcher, w) for _, w in windows[1:]]
+        wait_queued(batcher, 2)
+        time.sleep(0.3)  # due long since, but the cap holds them queued
+        with batcher._cond:
+            assert sum(len(q) for q in batcher._buckets.values()) == 2
+        assert batcher.merged_launches == 1
+        gate.set()
+        for (t, box), (plain, _) in zip([first] + later, windows):
+            t.join(timeout=30)
+            assert box[1] is None and box[0] == plain
+        assert batcher.merged_launches == 2  # the two queued ones merged
+        release()
+        backend.close()
+
+    @pytest.mark.parametrize("held_in", ["flusher", "flush_now"])
+    def test_a_deadline_passing_at_the_cap_fails_fast(self, held_in):
+        """A window held queued by the cap whose deadline passes meanwhile
+        fails fast with DeadlineExceeded, and is never launched: not woken
+        by the liveness backstop as if the flusher were dead."""
+        from tieredstorage_tpu.utils.deadline import Deadline, deadline_scope
+
+        backend = TpuTransformBackend()
+        backend.pipeline_depth = 1
+        batcher = WindowBatcher(backend, wait_ms=1, max_windows=8)
+        batcher.WAIT_GRACE_S = 30.0
+        if held_in == "flusher":
+            batcher.start()
+        release = park_fast_path(batcher)
+        gate, arrived = self.hold_collects(batcher)
+        (plain, wire), (_, late_wire) = make_window(240, [512]), make_window(241, [512])
+        first = queued_submit(batcher, wire)
+        if held_in == "flush_now":
+            wait_queued(batcher, 1)
+            assert batcher.flush_now() == 1
+        assert arrived.acquire(timeout=30)  # launched; its collect is held
+        payloads, sizes, ivs, tags = parse_wire(late_wire)
+        box: list = [None, None]
+
+        def late():
+            try:
+                with deadline_scope(Deadline.after(0.2)):
+                    box[0] = batcher.submit(DK, payloads, sizes, ivs, tags)
+            except BaseException as exc:  # noqa: BLE001 - asserted
+                box[1] = exc
+
+        t = threading.Thread(target=late)
+        t.start()
+        wait_queued(batcher, 1)
+        if held_in == "flush_now":
+            flusher = threading.Thread(target=batcher.flush_now)
+            flusher.start()
+            time.sleep(0.4)  # the deadline passes while the cap holds it
+            assert flusher.is_alive()
+            gate.set()
+            flusher.join(30)
+        t.join(10)
+        assert not t.is_alive() and isinstance(box[1], DeadlineExceededException), box
+        assert batcher.merged_launches == 1 and batcher.expired_windows == 1
+        gate.set()
+        first[0].join(timeout=30)
+        assert first[1][0] == plain
+        release()
+        with batcher._cond:
+            assert batcher._uncollected == 0 and batcher._inflight == 0
+            assert not batcher._buckets
+        batcher.stop()
+        backend.close()
+
+    def test_a_waiter_that_gave_up_holds_no_launch(self):
+        """A waiter whose liveness backstop fired before its flush is not
+        counted among the launch's collectors: nothing waits for it."""
+        clock = [0.0]
+        backend = TpuTransformBackend()
+        batcher = WindowBatcher(backend, wait_ms=50, time_source=lambda: clock[0])
+        batcher.WAIT_GRACE_S = 0.0
+        release = park_fast_path(batcher)
+        plain, wire = make_window(230, [512])
+        gave_up = _entry(wire, now=0.0, deadline_at=0.05)
+        kept = _entry(wire, now=0.0)
+        key = (LATENCY, True, bytes(DK.data_key), bytes(DK.aad), 1024)
+        with batcher._cond:
+            batcher._buckets[key] = [gave_up, kept]
+        with pytest.raises(BatcherStoppedError):
+            batcher._await_entry(gave_up)  # 50 ms on the frozen clock
+        assert batcher.flush_now() == 1  # still inside its deadline: launched
+        assert gave_up.left and gave_up.launch is None
+        with batcher._cond:
+            assert batcher._uncollected == 1 and kept.launch.waiters == 1
+        assert batcher._await_entry(kept) == plain
+        release()
+        with batcher._cond:
+            assert batcher._uncollected == 0 and batcher._inflight == 0
+        backend.close()
+
+    def test_launch_time_runs_to_the_last_waiters_rows(self):
+        backend = TpuTransformBackend()
+        batcher = WindowBatcher(backend, wait_ms=50, max_windows=8)
+        release = park_fast_path(batcher)
+        gate, arrived = self.hold_collects(batcher)
+        plains, wires = zip(*(make_window(210 + i, [500]) for i in range(2)))
+        jobs = [queued_submit(batcher, list(w)) for w in wires]
+        wait_queued(batcher, 2)
+        assert batcher.flush_now() == 1
+        for _ in jobs:
+            assert arrived.acquire(timeout=30)
+        with batcher._cond:
+            assert batcher._launch_s == []  # not sampled at the launch
+        time.sleep(0.2)
+        gate.set()
+        for (t, box), plain in zip(jobs, plains):
+            t.join(timeout=30)
+            assert box[0] == plain
+        with batcher._cond:
+            [launch_s] = batcher._launch_s
+        assert launch_s >= 0.2
+        release()
         backend.close()
 
 

@@ -125,10 +125,10 @@ SANCTIONED_MATERIALIZERS = {
         "verified host-side (the launch half is still checked upstream)",
     "tieredstorage_tpu/ops/aes_bitsliced.py:_forced_crosscheck_ok":
         "one-time forced-Pallas output cross-check at first use, memoized",
-    "tieredstorage_tpu/transform/batcher.py:WindowBatcher._flush_group":
-        "the merged flush's ONE device->host fetch, demultiplexed to every "
-        "coalesced waiter with per-row tag verification (the batched "
-        "counterpart of _decrypt_batch's finish half)",
+    "tieredstorage_tpu/transform/batcher.py:WindowBatcher._collect":
+        "a merged flush's waiter fetching its own rows on its own thread, "
+        "with per-row tag verification (the batched counterpart of "
+        "_decrypt_window's finish half); the flusher itself never fetches",
 }
 
 #: Vetted jit wrappers: every shape family they compile is bounded (the

@@ -13,8 +13,8 @@ queue under an explicit isolation policy (transform/scheduler.py):
 
 - ``TpuTransformBackend._decrypt_batch`` routes eligible windows here
   (``transform.batch.enabled``); each caller blocks while its rows ride a
-  SHARED packed ``uint8[B, n_bytes + 16]`` launch and gets its own slice
-  of the one output buffer back (results demultiplexed per caller).
+  SHARED packed ``uint8[B, n_bytes + 16]`` launch, then collects its own
+  rows of the one output buffer on its own thread.
   ``transform_windows`` routes encrypt windows through ``submit_encrypt``
   / ``_EncryptHandle.wait`` — async, so ``pipeline.depth`` overlap is
   preserved — and concurrent produces coalesce the same way.
@@ -50,21 +50,40 @@ queue under an explicit isolation policy (transform/scheduler.py):
   keeps byte-identical behavior (including the hot-tier retention hook).
   Background submits always queue, so admission and the watchdog govern
   every background launch.
-- **Per-row error isolation**: decrypt tags are verified per caller after
-  the merged fetch; one forged row fails that one request with
-  ``AuthenticationError``, never its batch-mates. A waiter whose deadline
-  expired before launch fails fast with ``DeadlineExceededException`` and
-  is excluded from the pack (it cannot poison the batch).
-- **Retention**: once woken, a decrypt waiter offers its own verified rows
-  to the hot tier's capture scope on its own thread, as a device copy of
-  those rows made only if the tier admits them (never a view that would
-  pin the whole merged buffer).
+- **The flusher stops at the launch**: it packs a bucket's rows into a
+  buffer of the backend's staging ring, launches, and wakes the waiters;
+  each waiter fetches its own rows of the merged output on its own thread
+  and checks their tags (decrypt: one row at a time, taken on the device
+  by ``gcm.take_rows``, one program per merged shape) or builds the wire
+  chunks (encrypt, ``_EncryptHandle.wait``: a window that fills most of
+  its launch reads the whole output, whose copy back the launch started),
+  while the flusher launches the next due bucket. The staging buffer goes
+  back to the ring when the last waiter leaves, if a waiter's rows came
+  back (the program that read it has run, and no waiter reads an output
+  that may be that buffer). At most ``pipeline_depth`` merged decrypt
+  launches wait to be collected; the flusher waits beyond that, and a
+  queued window whose deadline passes meanwhile fails fast. A merged
+  encrypt launch is collected when its pipeline asks (or never, by a
+  stream that is abandoned), so it counts against neither that cap nor
+  ``_inflight``.
+- **Per-row error isolation**: decrypt tags are verified per caller on
+  the caller's own rows; one forged row fails that one request with
+  ``AuthenticationError``, never its batch-mates, and a failed take fails
+  its own waiter only. A waiter whose deadline expired before launch
+  fails fast with ``DeadlineExceededException`` and is excluded from the
+  pack (it cannot poison the batch).
+- **Retention**: a decrypt waiter offers its own verified rows to the hot
+  tier's capture scope on its own thread, as a device copy of those rows
+  made only if the tier admits them (never a view that would pin the
+  whole merged buffer).
 - **Tracing** (the backend's tracer; nothing with tracing off): the span
-  ``transform.batch_wait`` on a queued waiter from enqueue to woken, and
-  ``transform.batch_flush`` on the flusher around a merged launch's pack,
-  launch, collect and demultiplex, the launch's ``transform.launch`` (and
-  so its ``device.window``) inside it. Exact counts in ``counters()``
-  (``/varz`` ``batcher``).
+  ``transform.batch_wait`` on a queued waiter from enqueue to its rows in
+  hand, with the collect's ``transform.d2h_wait`` (split into
+  ``transform.ready_wait`` / ``transform.collect``) inside it, and
+  ``transform.batch_flush`` on the flusher around a merged launch's pack
+  and launch, the launch's ``transform.launch`` (and so its
+  ``device.window``) inside it. Exact counts in ``counters()`` (``/varz``
+  ``batcher``).
 
 Accounting: the flusher's launches land in the owning backend's
 ``DispatchStats`` (one launch, one staging transfer, one fetch per flush),
@@ -149,7 +168,6 @@ class _PendingWindow:
     work_class: str = LATENCY
     decrypt: bool = True
     event: threading.Event = dataclasses.field(default_factory=threading.Event)
-    result: Optional[list] = None
     error: Optional[BaseException] = None
     batch_id: int = 0
     occupancy: int = 0
@@ -164,9 +182,34 @@ class _PendingWindow:
     aad: Optional[bytes] = None
     #: Distinct keys of the launch the window rode.
     keys: int = 0
-    #: (merged output, first row, row width) of a verified decrypt window,
-    #: for the waiter's hot-tier offer; dropped once offered.
-    offer: Optional[tuple] = None
+    #: The launch the window rode and its first row there, until its waiter
+    #: leaves (set under ``_cond``, and only for a waiter still waiting).
+    launch: Optional["_MergedLaunch"] = None
+    first_row: int = 0
+    #: The waiter has left: rows collected, failed, or given up waiting.
+    left: bool = False
+
+
+@dataclasses.dataclass
+class _MergedLaunch:
+    """One merged flush from its launch until the last of its waiters has
+    left: the shared output and its row width, the staging buffer the
+    program read (back to the ring when the last waiter leaves, if a
+    waiter's rows came back), and the waiters still to collect. Mutated
+    under ``_cond``."""
+
+    out: object
+    n_bytes: int
+    packed: Optional[np.ndarray]
+    started_at: float
+    waiters: int = 0
+    #: A waiter has its rows back: the program that read ``packed`` has run.
+    ran: bool = False
+    #: Counted in ``_uncollected`` and ``_inflight``: a decrypt launch whose
+    #: waiters are collecting.
+    tracked: bool = False
+    #: The timeline's record of the launch less its end (no timeline: None).
+    timeline_record: Optional[dict] = None
 
 
 class _EncryptHandle:
@@ -185,7 +228,8 @@ class _EncryptHandle:
         self._entry = entry
 
     def wait(self) -> list:
-        """Block until this window's wire chunks (IV || ct || tag) exist."""
+        """Block until this window's wire chunks (IV || ct || tag) exist: a
+        queued entry's rows are collected and assembled on this thread."""
         if self._staged is not None:
             return self._batcher._backend._encrypt_finish(self._staged)
         return self._batcher._await_entry(self._entry)
@@ -196,9 +240,10 @@ class WindowBatcher:
     work class per launch.
 
     One daemon flusher thread owns the device queue; submitting threads
-    block on their entry's event. All shared state mutates under the one
-    ``_cond`` (guarded-by checked + runtime-witnessed); the flush itself
-    runs OUTSIDE the lock so staging/launch never serializes submitters.
+    block on their entry's event, then collect their own rows. All shared
+    state mutates under the one ``_cond`` (guarded-by checked +
+    runtime-witnessed); the flush itself runs OUTSIDE the lock so
+    staging/launch never serializes submitters.
     """
 
     #: Flush when the oldest waiter's remaining deadline minus the observed
@@ -280,7 +325,12 @@ class WindowBatcher:
         #: One class + one direction per merged launch, structurally.
         self._buckets: dict[tuple, list[_PendingWindow]] = {}
         self._launch_s: list[float] = []
+        #: Flushes not yet finished: the flusher's own, and each merged
+        #: decrypt launch until its last waiter has collected.
         self._inflight = 0
+        #: Merged decrypt launches whose waiters are still collecting; the
+        #: flusher launches no more than ``pipeline_depth`` of them.
+        self._uncollected = 0
         self._stopped = False
         self._thread: Optional[threading.Thread] = None
         self._tls = threading.local()
@@ -319,6 +369,10 @@ class WindowBatcher:
         self.decrypt_launch_rows = 0
         self.merged_launches = 0
         self.merged_launch_keys = 0
+        #: Merged launches enqueued while an earlier merged decrypt launch
+        #: still had rows being collected, and rows collected by waiters.
+        self.overlapped_launches = 0
+        self.waiter_collected_rows = 0
 
     # --------------------------------------------------------------- lifecycle
     def start(self) -> "WindowBatcher":
@@ -396,7 +450,8 @@ class WindowBatcher:
         """Exact counts (``/varz`` ``batcher``): windows submitted and taken
         by the fast path; decrypt launches and their rows, fast path and
         merged flushes together; merged decrypt launches and the distinct
-        keys they carried."""
+        keys they carried; merged launches enqueued while an earlier one was
+        still being collected, and the rows waiters collected."""
         with self._cond:
             return {
                 "windows_submitted": self.windows_submitted,
@@ -405,6 +460,8 @@ class WindowBatcher:
                 "decrypt_launch_rows": self.decrypt_launch_rows,
                 "merged_launches": self.merged_launches,
                 "merged_launch_keys": self.merged_launch_keys,
+                "overlapped_launches": self.overlapped_launches,
+                "waiter_collected_rows": self.waiter_collected_rows,
             }
 
     def class_queued(self) -> dict[str, int]:
@@ -432,9 +489,10 @@ class WindowBatcher:
         """Decrypt one window, coalescing with concurrent submitters.
 
         Blocks until the window's rows came back from a (possibly shared)
-        launch; returns the plaintext chunks or raises this CALLER's error
-        only (``AuthenticationError`` on its own rows,
-        ``DeadlineExceededException`` when its budget expired in queue).
+        launch, collected on this thread; returns the plaintext chunks or
+        raises this CALLER's error only (``AuthenticationError`` on its own
+        rows, ``DeadlineExceededException`` when its budget expired in
+        queue).
         The work class is the thread's ambient ``work_class_scope``
         (default ``latency`` — the fetch path)."""
         work_class = current_work_class() or LATENCY
@@ -487,16 +545,12 @@ class WindowBatcher:
                 enc, payloads, sizes, ivs, tags, work_class, decrypt=True
             )
             try:
-                plain = self._await_entry(entry)
+                return self._await_entry(entry)
             finally:
                 if span is not None:
                     span.attributes.update(
                         keys=entry.keys, occupancy=entry.occupancy
                     )
-        offer, entry.offer = entry.offer, None
-        if offer is not None:
-            self._backend._offer_rows(*offer, sizes)
-        return plain
 
     def submit_encrypt(self, chunks, opts) -> _EncryptHandle:
         """Encrypt one window, coalescing with CONCURRENT produces.
@@ -592,22 +646,116 @@ class WindowBatcher:
         return entry
 
     def _await_entry(self, entry: _PendingWindow) -> list:
-        """Wait out a queued entry's flush; raises this caller's error
-        only. The timeout is a liveness backstop (deadline expiry is
-        enforced by the flusher's fail-fast) — clamped to the caller's
-        remaining budget plus slack when one exists."""
-        if not entry.event.wait(timeout=self._wait_timeout_s(entry)):
-            raise BatcherStoppedError(
-                "batched window was never flushed (flusher dead?)"
-            )
-        if entry.batch_id:
-            t = self._tls
-            t.windows = getattr(t, "windows", 0) + 1
-            t.occupancy_sum = getattr(t, "occupancy_sum", 0.0) + entry.occupancy
-            t.last_batch_id = entry.batch_id
-        if entry.error is not None:
-            raise entry.error
-        return entry.result
+        """Wait out a queued entry's flush, then collect its rows on this
+        thread (`_collect`); raises this caller's error only. The timeout
+        is a liveness backstop (deadline expiry is enforced by the
+        flusher's fail-fast) — clamped to the caller's remaining budget
+        plus slack when one exists."""
+        try:
+            if not entry.event.wait(timeout=self._wait_timeout_s(entry)):
+                raise BatcherStoppedError(
+                    "batched window was never flushed (flusher dead?)"
+                )
+            if entry.batch_id:
+                t = self._tls
+                t.windows = getattr(t, "windows", 0) + 1
+                t.occupancy_sum = getattr(t, "occupancy_sum", 0.0) + entry.occupancy
+                t.last_batch_id = entry.batch_id
+            if entry.error is not None:
+                raise entry.error
+            return self._collect(entry)
+        finally:
+            self._leave(entry)
+
+    def _collect(self, entry: _PendingWindow) -> list:
+        """This waiter's rows of its merged launch, fetched under
+        `transform.d2h_wait`: a decrypt window's one or two rows each taken
+        alone on the device (`gcm.take_rows`, one program per merged shape,
+        the row an argument), an encrypt window's, which fill most of their
+        launch, read from the whole output. Then the tags checked and the
+        plaintext copied out (decrypt, whose verified rows are offered to
+        the hot tier), or the wire chunks IV || ct || tag built (encrypt)."""
+        from tieredstorage_tpu.ops import gcm as gcm_ops
+        from tieredstorage_tpu.transform.api import AuthenticationError
+
+        launch, backend, sizes = entry.launch, self._backend, entry.sizes
+        n_bytes, first = launch.n_bytes, entry.first_row
+        with backend.tracer.span("transform.d2h_wait") as wait:
+            if entry.decrypt:
+                rows = [
+                    np.asarray(gcm_ops.take_rows(launch.out, first + i, 1))[0]
+                    for i in range(len(sizes))
+                ]
+            else:
+                rows = np.asarray(launch.out)[first : first + len(sizes)]
+        if wait is not None:
+            backend._split_wait(wait, launch.out, shared=True)
+        with self._cond:
+            self.waiter_collected_rows += len(rows)
+            note_mutation("batcher.WindowBatcher.waiter_collected_rows")
+            launch.ran = True
+        backend._note_batched_fetch()
+        if not entry.decrypt:
+            return [
+                b"".join((
+                    entry.ivs[i].tobytes(),
+                    memoryview(row[: sizes[i]]),
+                    memoryview(row[n_bytes:]),
+                ))
+                for i, row in enumerate(rows)
+            ]
+        bad = [
+            i
+            for i, row in enumerate(rows)
+            if not hmac.compare_digest(row[n_bytes:].tobytes(), entry.tags[i])
+        ]
+        if bad:
+            # Per-row error isolation: one forged row fails ITS request;
+            # batch-mates still get their plaintext.
+            raise AuthenticationError(f"GCM tag mismatch on chunks {bad}")
+        backend._offer_rows(launch.out, entry.first_row, n_bytes, sizes)
+        return [row[: sizes[i]].tobytes() for i, row in enumerate(rows)]
+
+    def _leave(self, entry: _PendingWindow) -> None:
+        """The waiter is done with its launch, whatever happened; the last
+        to leave finishes the launch."""
+        with self._cond:
+            entry.left = True
+            launch, entry.launch = entry.launch, None
+            if launch is None:
+                return
+            launch.waiters -= 1
+            if launch.waiters:
+                return
+        self._finish_launch(launch)
+
+    def _finish_launch(self, launch: _MergedLaunch) -> None:
+        """The last waiter of a merged launch has left: its launch time (to
+        rows back) is sampled, it stops counting against the cap, and the
+        timeline records it. Its staging buffer goes back to the ring if a
+        waiter's rows came back: the program that read it has run, and no
+        waiter reads the output, which on a zero-copy placement (the CPU
+        backend's, for an aligned array) is that buffer. One no waiter's
+        rows came back from is dropped: the program may still be reading
+        it."""
+        end_s = self._now()
+        with self._cond:
+            self._launch_s.append(end_s - launch.started_at)
+            if len(self._launch_s) > self.LAUNCH_SAMPLES:
+                del self._launch_s[0]
+            if launch.tracked:
+                self._uncollected -= 1
+                note_mutation("batcher.WindowBatcher._uncollected")
+                self._inflight -= 1
+                note_mutation("batcher.WindowBatcher._inflight")
+                self._cond.notify_all()
+            packed = launch.packed if launch.ran else None
+            launch.packed = launch.out = None
+        if packed is not None:
+            self._backend._release_staging(packed)
+        tl, record = self.timeline, launch.timeline_record
+        if tl is not None and record is not None:
+            tl.record_flush(end_s=end_s, **record)
 
     def _wait_timeout_s(self, entry: _PendingWindow) -> Optional[float]:
         """A queued waiter's liveness backstop: its remaining deadline
@@ -732,16 +880,29 @@ class WindowBatcher:
         flush outside the lock — the one device queue every stream
         shares. Groups flush in scheduler order (latency first)."""
         while True:
+            expired: list = []
             with self._cond:
                 if self._stopped:
                     return
-                due, timeout = self._due_keys_locked(self._now())
-                if not due:
+                now = self._now()
+                due, timeout = self._due_keys_locked(now)
+                if due and self._collects_full_locked():
+                    # Due windows stay queued, and merge with later ones,
+                    # until a merged launch's waiters have collected; one
+                    # whose deadline passes meanwhile fails fast.
+                    expired, timeout = self._take_expired_locked(now)
+                    due = []
+                if not due and not expired:
                     self._cond.wait(timeout)
                     continue
-                groups = [(key, self._take_locked(key)) for key in due]
-                self._inflight += 1
-                note_mutation("batcher.WindowBatcher._inflight")
+                if due:
+                    groups = [(key, self._take_locked(key)) for key in due]
+                    self._inflight += 1
+                    note_mutation("batcher.WindowBatcher._inflight")
+            if expired:
+                for work_class, entries in expired:
+                    self._fail_expired(work_class, entries, now)
+                continue
             try:
                 for key, entries in groups:
                     self._flush_group(key, entries)
@@ -749,6 +910,63 @@ class WindowBatcher:
                 with self._cond:
                     self._inflight -= 1
                     note_mutation("batcher.WindowBatcher._inflight")
+
+    def _take_expired_locked(self, now: float) -> tuple[list, Optional[float]]:
+        """(queued windows whose deadline has passed, taken out of their
+        buckets as ``(work_class, entries)`` pairs; seconds until the next
+        queued deadline) — callers hold ``_cond``."""
+        expired: list = []
+        next_s: Optional[float] = None
+        for key in list(self._buckets):
+            kept, gone = [], []
+            for e in self._buckets[key]:
+                if e.deadline_at is None:
+                    kept.append(e)
+                elif e.deadline_at <= now:
+                    gone.append(e)
+                else:
+                    kept.append(e)
+                    wait_s = e.deadline_at - now
+                    next_s = wait_s if next_s is None else min(next_s, wait_s)
+            if gone:
+                expired.append((key[0], gone))
+                if kept:
+                    self._buckets[key] = kept
+                else:
+                    del self._buckets[key]
+        return expired, next_s
+
+    def _fail_expired(self, work_class: str, entries: list, now: float) -> list:
+        """The entries whose deadline has not passed at ``now``. Each other
+        one fails fast WITHOUT poisoning the batch: it never joins a pack,
+        and its batch-mates launch on time."""
+        from tieredstorage_tpu.utils.deadline import DeadlineExceededException
+
+        live: list[_PendingWindow] = []
+        expired = 0
+        for e in entries:
+            if e.deadline_at is not None and e.deadline_at <= now:
+                e.error = DeadlineExceededException(
+                    "deadline expired while queued for a batched GCM launch"
+                )
+                e.event.set()
+                expired += 1
+            else:
+                live.append(e)
+        if expired:
+            with self._cond:
+                self.expired_windows += expired
+                note_mutation("batcher.WindowBatcher.expired_windows")
+            tl = self.timeline
+            if tl is not None:
+                tl.record_expired(work_class, expired, now)
+        return live
+
+    def _collects_full_locked(self) -> bool:
+        """Whether ``pipeline_depth`` merged decrypt launches are still
+        being collected: no more may launch until one is (callers hold
+        ``_cond``)."""
+        return self._uncollected >= max(1, self._backend.pipeline_depth)
 
     def flush_now(self) -> int:
         """Flush every queued window synchronously on the calling thread
@@ -788,48 +1006,35 @@ class WindowBatcher:
         staged device buffer is donated by the launch. ``device.launch`` is
         the fault-injection seam (keyed by work class). With ``row_keys``
         ``ctx`` is the launch's key table. Returns the device output
-        buffer; the caller owns the sanctioned ``np.asarray``."""
+        buffer. An encrypt launch starts its whole-output copy back, which
+        its handles read; a decrypt launch does not, since each waiter takes
+        only its own rows (the copy of every launch's whole output cost the
+        ten-reader fan-in 39 % of its throughput on the v5e)."""
         faults.fire("device.launch", work_class)
         staged = self._backend._stage_packed(packed, True)
         return self._backend._launch_packed(
-            ctx, staged, True, decrypt=decrypt, row_keys=row_keys
+            ctx, staged, True, decrypt=decrypt, row_keys=row_keys,
+            copy_back=not decrypt,
         )
 
     def _flush_group(self, key: tuple, entries: list) -> None:
         """ONE shared launch for a bucket's queued windows: merge rows into
         a single packed buffer, stage + launch through the owning backend
-        (donation and DispatchStats intact), fetch once, then demultiplex
-        per caller — with per-row tag verification on the decrypt
-        direction, wire assembly (IV || ct || tag) on encrypt. The
-        np.asarray here is the merged flush's ONE sanctioned device->host
-        materialization. The bucket key carries ONE work class and ONE
-        direction, so a failure here wakes that class's waiters only."""
+        (donation and DispatchStats intact), then wake each waiter with
+        the launch and its first row there: the waiter collects its own
+        rows (`_collect`), and the flusher goes on to the next bucket. At
+        most ``pipeline_depth`` merged decrypt launches wait to be
+        collected; beyond that a decrypt flush waits before it packs, and
+        a window whose deadline passed meanwhile fails fast then. The
+        bucket key carries ONE work class and ONE direction, so a failure
+        here wakes that class's waiters only."""
         from tieredstorage_tpu.ops import gcm as gcm_ops
-        from tieredstorage_tpu.transform.api import AuthenticationError
-        from tieredstorage_tpu.utils.deadline import DeadlineExceededException
 
         work_class, decrypt = key[0], key[1]
-        now = self._now()
-        live: list[_PendingWindow] = []
-        expired = 0
-        for e in entries:
-            if e.deadline_at is not None and e.deadline_at <= now:
-                # Fail fast WITHOUT poisoning the batch: the expired waiter
-                # never joins the pack, its batch-mates launch on time.
-                e.error = DeadlineExceededException(
-                    "deadline expired while queued for a batched GCM launch"
-                )
-                e.event.set()
-                expired += 1
-            else:
-                live.append(e)
-        if expired:
+        if decrypt:
             with self._cond:
-                self.expired_windows += expired
-                note_mutation("batcher.WindowBatcher.expired_windows")
-            tl = self.timeline
-            if tl is not None:
-                tl.record_expired(work_class, expired, now)
+                self._cond.wait_for(lambda: not self._collects_full_locked())
+        live = self._fail_expired(work_class, entries, self._now())
         if not live:
             return
 
@@ -885,6 +1090,8 @@ class WindowBatcher:
                     flush_span.attributes.update(rows=rows, bucket_rows=len(packed))
                 ctx = ctxs if keyed else ctxs[0]
                 slots = row_keys if keyed else None
+                with self._cond:
+                    overlapped = self._uncollected > 0
                 t0 = self._now()
                 out = call_with_retry(
                     lambda: self._launch_once(ctx, packed, decrypt, work_class, slots),
@@ -892,11 +1099,6 @@ class WindowBatcher:
                     site="device.launch",
                     on_retry=self._on_launch_retry,
                 )
-                with backend.tracer.span("transform.d2h_wait") as wait:
-                    host = np.asarray(out)
-                if wait is not None:
-                    backend._split_wait(wait, out)
-                launch_s = self._now() - t0
             except BaseException as exc:  # noqa: BLE001 - every waiter must wake
                 with self._cond:
                     self.launch_failures += 1
@@ -907,11 +1109,11 @@ class WindowBatcher:
                     e.error = exc
                     e.event.set()
                 return
-            backend._note_batched_fetch()
             for e in live:
                 backend._note_window(e.n_bytes, len(e.sizes), n_bytes, True)
 
             occupancy = len(live)
+            launch = _MergedLaunch(out, n_bytes, packed, t0)
             with self._cond:
                 self._batch_seq += 1
                 note_mutation("batcher.WindowBatcher._batch_seq")
@@ -924,6 +1126,8 @@ class WindowBatcher:
                 note_mutation("batcher.WindowBatcher.class_launches")
                 self.class_flushed_windows[work_class] += occupancy
                 note_mutation("batcher.WindowBatcher.class_flushed_windows")
+                self.overlapped_launches += overlapped
+                note_mutation("batcher.WindowBatcher.overlapped_launches")
                 if decrypt:
                     self.decrypt_launches += 1
                     note_mutation("batcher.WindowBatcher.decrypt_launches")
@@ -933,58 +1137,30 @@ class WindowBatcher:
                     note_mutation("batcher.WindowBatcher.merged_launches")
                     self.merged_launch_keys += len(table)
                     note_mutation("batcher.WindowBatcher.merged_launch_keys")
-                self._launch_s.append(launch_s)
-                if len(self._launch_s) > self.LAUNCH_SAMPLES:
-                    del self._launch_s[0]
-
-            added_waits: list[float] = []
-            r = 0
-            for e in live:
-                n = len(e.sizes)
-                if decrypt:
-                    bad = [
-                        i
-                        for i in range(n)
-                        if not hmac.compare_digest(
-                            host[r + i, n_bytes:].tobytes(), e.tags[i]
-                        )
-                    ]
-                    if bad:
-                        # Per-row error isolation: one forged row fails ITS
-                        # request; batch-mates still get their plaintext.
-                        e.error = AuthenticationError(
-                            f"GCM tag mismatch on chunks {bad}"
-                        )
-                    else:
-                        e.result = [
-                            host[r + i, : e.sizes[i]].tobytes() for i in range(n)
-                        ]
-                        e.offer = (out, r, n_bytes)
-                else:
-                    # Encrypt demux: the same wire assembly _encrypt_finish
-                    # does — IV || ciphertext || tag per row.
-                    e.result = [
-                        e.ivs[i].tobytes()
-                        + host[r + i, : e.sizes[i]].tobytes()
-                        + host[r + i, n_bytes:].tobytes()
-                        for i in range(n)
-                    ]
-                r += n
-                e.batch_id = batch_id
-                e.occupancy = occupancy
-                e.keys = len(table)
-                e.added_wait_ms = max(0.0, (t0 - e.enqueued_at) * 1000.0)
-                added_waits.append(e.added_wait_ms)
-                e.event.set()
-            backend._release_staging(packed)
-            with self._cond:
-                self.class_added_wait_ms[work_class] += sum(added_waits)
-                note_mutation("batcher.WindowBatcher.class_added_wait_ms")
+                r = 0
+                for e in live:
+                    e.first_row = r
+                    r += len(e.sizes)
+                    e.batch_id = batch_id
+                    e.occupancy = occupancy
+                    e.keys = len(table)
+                    e.added_wait_ms = max(0.0, (t0 - e.enqueued_at) * 1000.0)
+                    if not e.left:  # a waiter that gave up collects nothing
+                        e.launch = launch
+                        launch.waiters += 1
+                unwaited = not launch.waiters
+                launch.tracked = decrypt and not unwaited
+                if launch.tracked:
+                    self._uncollected += 1
+                    note_mutation("batcher.WindowBatcher._uncollected")
+                    self._inflight += 1
+                    note_mutation("batcher.WindowBatcher._inflight")
             tl = self.timeline
             if tl is not None:
                 # Outside _cond by design: the timeline ring has its own lock
                 # and class_queued() re-takes _cond for the depth snapshot.
-                tl.record_flush(
+                # The last waiter to leave records it, with the launch's end.
+                launch.timeline_record = dict(
                     batch_id=batch_id,
                     work_class=work_class,
                     decrypt=decrypt,
@@ -996,10 +1172,17 @@ class WindowBatcher:
                         0.0, (t0 - min(e.enqueued_at for e in live)) * 1000.0
                     ),
                     begin_s=t0,
-                    end_s=t0 + launch_s,
                     queue_depths=self.class_queued(),
                     trace_ids=[e.trace_id for e in live],
                 )
+            added_waits = [e.added_wait_ms for e in live]
+            with self._cond:
+                self.class_added_wait_ms[work_class] += sum(added_waits)
+                note_mutation("batcher.WindowBatcher.class_added_wait_ms")
+            if unwaited:
+                self._finish_launch(launch)
+            for e in live:
+                e.event.set()
             hook = self.on_flush
             if hook is not None:
                 hook(

@@ -77,9 +77,9 @@ class DeviceWatch:
         self._queue: "queue.SimpleQueue[Optional[_Flight]]" = queue.SimpleQueue()
         #: Launched and not yet recorded, in launch order; guarded by `_cond`.
         self._pending: "collections.deque[_Flight]" = collections.deque()
-        #: Flights no finisher has asked about (a merged launch of the
-        #: batcher never will): dropped once their output is gone; guarded
-        #: by `_cond`.
+        #: Flights no finisher has claimed (a merged launch of the batcher,
+        #: whose waiters each ask, never is): dropped once their output is
+        #: gone; guarded by `_cond`.
         self._unclaimed: list = []
         self._last_ready_s = 0.0  # the watch thread's own
         self._thread = threading.Thread(target=self._run, name="device-watch", daemon=True)
@@ -97,15 +97,18 @@ class DeviceWatch:
         self._queue.put(flight)
 
     # ------------------------------------------------------------ the finisher
-    def ready_by(self, out, wait_end_s: float) -> float:
+    def ready_by(self, out, wait_end_s: float, *, claim: bool = True) -> float:
         """The ready stamp of the window whose output is `out`, for the thread
         whose blocking read of it ended at `wait_end_s`: that end is itself
         proof of readiness, so it is the stamp where the watch has set none
-        yet, and the answer is never later. No call here blocks."""
+        yet, and the answer is never later. No call here blocks. `claim`
+        False: one of several waiters of a merged launch asks, and the flight
+        stays for the others until its output is gone."""
         with self._cond:
             for at, flight in enumerate(self._unclaimed):
                 if flight.out_ref() is out:
-                    del self._unclaimed[at]
+                    if claim:
+                        del self._unclaimed[at]
                     return min(self._stamp(flight, wait_end_s), wait_end_s)
         return wait_end_s  # launched before this watch existed
 

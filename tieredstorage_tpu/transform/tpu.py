@@ -352,8 +352,8 @@ class TpuTransformBackend(TransformBackend):
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
     def _note_batched_fetch(self) -> None:
-        """One device→host fetch for a merged flush (shared by every
-        window it coalesced)."""
+        """One device→host fetch of a window that rode a merged flush: its
+        waiter's own rows (transform/batcher.py `_collect`)."""
         with self._stats_lock:
             self.dispatch_stats.d2h_fetches += 1
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
@@ -697,7 +697,8 @@ class TpuTransformBackend(TransformBackend):
         return self.mesh_plan().mesh is None
 
     def _launch_packed(
-        self, ctx, staged, varlen: bool, *, decrypt: bool, row_keys=None
+        self, ctx, staged, varlen: bool, *, decrypt: bool, row_keys=None,
+        copy_back: bool = True,
     ):
         """ONE fused device dispatch for a staged window (keystream → XOR →
         GHASH → tag in a single program, `output || tag` packed into a
@@ -708,7 +709,11 @@ class TpuTransformBackend(TransformBackend):
         steady state regardless of mesh size; a genuinely mismatched
         sharding would be the only reason to skip, and no such case exists
         on this path. Starts the device→host copy immediately so the
-        result streams back while later windows compute. The
+        result streams back while later windows compute, unless
+        `copy_back` is False: the batcher's merged decrypt launch, whose
+        waiters each fetch only their own rows (a whole-output copy of every
+        such launch made the fan-in cell 39 % slower on the v5e; PERF.md §6).
+        The
         `transform.launch` span is the host's enqueue time: placing the
         context's constants on their first use, the jitted call (`traced`
         when it traced a new program: seconds, where a launch is
@@ -742,7 +747,8 @@ class TpuTransformBackend(TransformBackend):
                 if donated:
                     self.dispatch_stats.donated_buffers += 1
                 note_mutation("tpu.TpuTransformBackend.dispatch_stats")
-            out.copy_to_host_async()
+            if copy_back:
+                out.copy_to_host_async()
             if span is not None:
                 span.attributes["traced"] = thread_program_traces() > traces
                 self._watch_window(out, span, varlen, decrypt)
@@ -785,7 +791,7 @@ class TpuTransformBackend(TransformBackend):
             self.dispatch_stats.device_seen_ns += nanoseconds
             note_mutation("tpu.TpuTransformBackend.dispatch_stats")
 
-    def _split_wait(self, wait, out) -> None:
+    def _split_wait(self, wait, out, *, shared: bool = False) -> None:
         """A finished `transform.d2h_wait`, split at the moment the window's
         result was ready (the device watch's stamp, or the wait's own end
         where that came first): `transform.ready_wait` up to it, the program
@@ -793,9 +799,13 @@ class TpuTransformBackend(TransformBackend):
         still paid once the result existed (the rest of the copy back, being
         woken, being given the interpreter, `np.asarray`). The two tile the
         wait; a result that was ready before the wait began leaves no
-        `ready_wait`."""
+        `ready_wait`. `shared`: one of several waiters of a merged launch
+        collecting its own rows of `out`."""
         watch = self.device_watch
-        ready_s = wait.end_s if watch is None else watch.ready_by(out, wait.end_s)
+        ready_s = (
+            wait.end_s if watch is None
+            else watch.ready_by(out, wait.end_s, claim=not shared)
+        )
         if ready_s > wait.start_s:
             self.tracer.record(
                 "transform.ready_wait", wait.start_s, ready_s, parent=wait
