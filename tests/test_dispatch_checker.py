@@ -154,10 +154,9 @@ class TestSeededRegression:
         src = tpu.read_text()
         anchor = "staged = self._dispatch_encrypt_window(chunks, w_opts, frame_buffer)\n"
         assert anchor in src
-        src = src.replace(
-            anchor,
-            anchor + "            _dbg = np.asarray(staged)\n",
-        )
+        line = next(line for line in src.splitlines(keepends=True) if line.endswith(anchor))
+        indent = line[: len(line) - len(anchor)]
+        src = src.replace(line, line + indent + "_dbg = np.asarray(staged)\n")
         tpu.write_text(src)
         report = run(load_project(root))
         assert details(report) == ["materialize:asarray"]

@@ -139,6 +139,10 @@ SANCTIONED_THREAD_SPAWNS = {
         "device watch (one per backend and only under an enabled tracer: "
         "waits for each launched window off every request's path, stopped "
         "via stop from the backend's close)",
+    "tieredstorage_tpu/transform/tpu.py:TpuTransformBackend._end_stream":
+        "staging give-back (one per backend, started where concurrent "
+        "encrypt streams have all ended: trims the staging ring back to one "
+        "stream's bound after a linger, stopped by the backend's close)",
 }
 
 

@@ -23,6 +23,8 @@ import dataclasses
 import functools
 import hmac
 import os
+import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
@@ -135,7 +137,18 @@ class DispatchStats:
     into fresh memory around it: a gathered copy of the input (none: each
     chunk is compressed where it lies) and every frame that ends as an
     owned `bytes` where the native codec's, as views of its frame buffer,
-    are read by the pack and copied nowhere else."""
+    are read by the pack and copied nowhere else.
+
+    Four counts say what concurrent encrypt streams share. A stream is a
+    `transform_windows` call with encryption, from its first dispatch to
+    its last finish (a segment's log, each of its indexes).
+    `encrypt_streams_max` is the most streams in flight at once;
+    `encrypt_stream_launch_sum` adds, at each encrypt launch, the streams
+    then in flight; `launch_queue_sum` adds, at each launch, the unbatched
+    encrypt windows launched before it and not yet finished: the device
+    queue the window joins. `staging_trimmed_bytes` is what the ring let go
+    over its bound as windows handed their buffers back (`_release_staging`);
+    what it gives back once streams end is not counted."""
 
     windows: int = 0
     #: Chunk rows of those windows, before any mesh padding: over `windows`
@@ -183,6 +196,10 @@ class DispatchStats:
     #: time on the windows launched under an enabled tracer. 0 for ever with
     #: tracing off.
     device_seen_ns: int = 0
+    encrypt_streams_max: int = 0
+    encrypt_stream_launch_sum: int = 0
+    launch_queue_sum: int = 0
+    staging_trimmed_bytes: int = 0
 
     @property
     def dispatches_per_window(self) -> float:
@@ -254,6 +271,19 @@ class TpuTransformBackend(TransformBackend):
             collections.OrderedDict()
         )
         self._staging_free_bytes = 0
+        #: Encrypt streams in flight (`transform_windows` with encryption),
+        #: the most of them in flight at once since the ring last gave its
+        #: buffers back, and the unbatched encrypt windows launched and not
+        #: yet finished; when the ring gives the buffers of concurrent
+        #: streams back (a `time.monotonic()`, or None), and the thread that
+        #: does it (`_end_stream`); guarded by `_stats_lock`.
+        self._encrypt_streams = 0
+        self._encrypt_streams_peak = 0
+        self._encrypt_windows_out = 0
+        self._give_back_at: Optional[float] = None
+        self._give_back_thread: Optional[threading.Thread] = None
+        self._give_back_wake = threading.Event()
+        self._closed = False
         #: Cross-request decrypt batcher (transform/batcher.py), built by
         #: `configure()` from `transform.batch.enabled` or explicitly via
         #: `enable_batching()`; None = every window dispatches unbatched.
@@ -375,6 +405,11 @@ class TpuTransformBackend(TransformBackend):
             self.batcher = None
         with self._stats_lock:
             watch, self.device_watch = self.device_watch, None
+            self._closed = True
+            give_back = self._give_back_thread
+        if give_back is not None:
+            self._give_back_wake.set()
+            give_back.join()
         if watch is not None:
             watch.stop()
         if self._pool is not None:
@@ -423,27 +458,100 @@ class TpuTransformBackend(TransformBackend):
             return
         pending: "collections.deque" = collections.deque()
         iv_offset = 0
-        for window in windows:
-            chunks = list(window)
-            # Deterministic IVs (tests) are a flat per-chunk sequence: slice
-            # the window's share so windowed == monolithic byte-for-byte.
-            w_opts = opts
-            if opts.ivs is not None:
-                w_opts = dataclasses.replace(
-                    opts, ivs=opts.ivs[iv_offset : iv_offset + len(chunks)]
-                )
-                iv_offset += len(chunks)
-            staged = None
-            if chunks:
-                frame_buffer = None
-                if opts.compression:
-                    chunks, frame_buffer = self._compress_batch(chunks, w_opts)
-                staged = self._dispatch_encrypt_window(chunks, w_opts, frame_buffer)
-            pending.append(staged)
-            while len(pending) > max(1, self.pipeline_depth):
+        self._begin_stream()
+        try:
+            for window in windows:
+                chunks = list(window)
+                # Deterministic IVs (tests) are a flat per-chunk sequence: slice
+                # the window's share so windowed == monolithic byte-for-byte.
+                w_opts = opts
+                if opts.ivs is not None:
+                    w_opts = dataclasses.replace(
+                        opts, ivs=opts.ivs[iv_offset : iv_offset + len(chunks)]
+                    )
+                    iv_offset += len(chunks)
+                staged = None
+                if chunks:
+                    frame_buffer = None
+                    if opts.compression:
+                        chunks, frame_buffer = self._compress_batch(chunks, w_opts)
+                    staged = self._dispatch_encrypt_window(chunks, w_opts, frame_buffer)
+                pending.append(staged)
+                while len(pending) > max(1, self.pipeline_depth):
+                    yield self._finish_or_empty(pending.popleft())
+            while pending:
                 yield self._finish_or_empty(pending.popleft())
-        while pending:
-            yield self._finish_or_empty(pending.popleft())
+        finally:
+            # Windows the caller stopped before never come back: they leave
+            # the queue with the stream, and keep their buffers.
+            for staged in pending:
+                if isinstance(staged, tuple):
+                    self._drop_window(staged[4])
+            self._end_stream()
+
+    def _begin_stream(self) -> None:
+        with self._stats_lock:
+            self._encrypt_streams += 1
+            self._give_back_at = None
+            self._encrypt_streams_peak = max(self._encrypt_streams_peak, self._encrypt_streams)
+            stats = self.dispatch_stats
+            stats.encrypt_streams_max = max(stats.encrypt_streams_max, self._encrypt_streams)
+            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+
+    #: Seconds the ring keeps the buffers of concurrent streams once the last
+    #: of them has ended: copy threads working through a backlog start their
+    #: next streams together, each once its segment's body has arrived (a
+    #: 256 MiB body beside nine other copies: 0.64-0.68 s p50, 0.86-2.9 s p95
+    #: on a v5e host; PERF.md §5).
+    staging_linger_s = 5.0
+
+    def _end_stream(self) -> None:
+        """One stream fewer. Where the last one ends after streams have run
+        concurrently, the ring keeps their bound for `staging_linger_s` and
+        then, unless a stream has begun meanwhile, gives back what is free
+        over one stream's bound: on the backend's one `staging-give-back`
+        thread, started by the first such end. A lone stream leaves nothing
+        over one stream's bound, and no thread."""
+        with self._stats_lock:
+            self._encrypt_streams -= 1
+            if self._encrypt_streams:
+                return
+            if self._encrypt_streams_peak <= 1:
+                self._encrypt_streams_peak = 0
+                return
+            self._give_back_at = time.monotonic() + self.staging_linger_s
+            thread = self._give_back_thread
+            if thread is None and not self._closed:
+                thread = self._give_back_thread = threading.Thread(
+                    target=self._give_back_loop, name="staging-give-back", daemon=True,
+                )
+                thread.start()
+        self._give_back_wake.set()
+
+    def _give_back_loop(self) -> None:
+        while True:
+            with self._stats_lock:
+                if self._closed:
+                    return
+                due = self._give_back_at
+                now = time.monotonic()
+                if due is not None and now >= due:
+                    self._give_back_at = None
+                    self._encrypt_streams_peak = 0
+                    self._trim_staging(self._staging_bound())
+                    note_mutation("tpu.TpuTransformBackend._staging_free")
+                    due = None
+            self._give_back_wake.wait(None if due is None else due - now)
+            self._give_back_wake.clear()
+
+    def _drop_window(self, staging: list) -> None:
+        """An encrypt window that will not be finished leaves the device
+        queue's count; its buffer is never handed back (the device may
+        still read it)."""
+        with self._stats_lock:
+            if staging:
+                staging.clear()
+                self._encrypt_windows_out -= 1
 
     def _dispatch_encrypt_window(
         self, chunks: list, opts: TransformOptions,
@@ -596,11 +704,17 @@ class TpuTransformBackend(TransformBackend):
         return ctx, n_bytes, varlen
 
     def _staging_bound(self) -> int:
-        """Bytes the ring may hold free: the `pipeline_depth + 1` windows a
-        stream keeps in flight, at the window byte cap, twice over for the
-        other shapes a deployment stages beside its full window (a ragged
-        or one-row window, the index rows)."""
-        return 2 * (self.pipeline_depth + 1) * self.preferred_batch_bytes
+        """Bytes the ring may hold free: for each of the most encrypt
+        streams in flight at once since it last gave buffers back, and for
+        one where none was, the `pipeline_depth + 1` windows a stream keeps
+        in flight, at the window byte cap, twice over for the other buffers
+        a stream takes beside its full windows (the codec's frame buffer, a
+        ragged or one-row window, the index rows). Ten concurrent copies
+        hand back ten streams' buffers; bounded at one stream's, the ring
+        would trim most of them and every later window would pack into
+        fresh memory."""
+        streams = max(1, self._encrypt_streams_peak)
+        return 2 * (self.pipeline_depth + 1) * self.preferred_batch_bytes * streams
 
     def _acquire_staging(self, shape: tuple) -> np.ndarray:
         """A host buffer for one packed window: the ring's, already mapped
@@ -628,17 +742,28 @@ class TpuTransformBackend(TransformBackend):
         CPU backend's is for an aligned array, the staged array IS the
         buffer). Over the bound the shapes returned longest ago give way
         first."""
-        bound = self._staging_bound()
         with self._stats_lock:
             self._staging_free.setdefault(packed.shape, []).append(packed)
             self._staging_free.move_to_end(packed.shape)
             self._staging_free_bytes += packed.nbytes
-            while self._staging_free_bytes > bound:
-                shape, oldest = next(iter(self._staging_free.items()))
-                self._staging_free_bytes -= oldest.pop(0).nbytes
-                if not oldest:
-                    del self._staging_free[shape]
+            self.dispatch_stats.staging_trimmed_bytes += self._trim_staging(
+                self._staging_bound()
+            )
             note_mutation("tpu.TpuTransformBackend._staging_free")
+            note_mutation("tpu.TpuTransformBackend.dispatch_stats")
+
+    def _trim_staging(self, bound: int) -> int:
+        """Let the free buffers returned longest ago go until the ring holds
+        at most `bound` bytes; the bytes let go. Under `_stats_lock`."""
+        trimmed = 0
+        while self._staging_free_bytes > bound:
+            shape, oldest = next(iter(self._staging_free.items()))
+            n_bytes = oldest.pop(0).nbytes
+            self._staging_free_bytes -= n_bytes
+            trimmed += n_bytes
+            if not oldest:
+                del self._staging_free[shape]
+        return trimmed
 
     def _build_packed(
         self, payloads: list, sizes: list[int], ivs: np.ndarray, n_bytes: int,
@@ -719,7 +844,10 @@ class TpuTransformBackend(TransformBackend):
         when it traced a new program: seconds, where a launch is
         milliseconds) and starting the copy back. With `row_keys` the
         window's rows carry different keys: `ctx` is the launch's key table
-        and the keyed varlen program runs (`keyed_launches`)."""
+        and the keyed varlen program runs (`keyed_launches`). The span's
+        `streams` and `queued` are the encrypt streams in flight and the
+        unbatched encrypt windows launched before this one and not yet
+        finished (`DispatchStats.launch_queue_sum`)."""
         mesh = self.mesh_plan().mesh
         with self.tracer.span("transform.launch") as span:
             traces = thread_program_traces()
@@ -742,15 +870,22 @@ class TpuTransformBackend(TransformBackend):
             rt_delta = gcm_ops.thread_hbm_roundtrips() - rt_before
             donated = staged.is_deleted()  # XLA consumed the staged allocation
             with self._stats_lock:
-                self.dispatch_stats.dispatches += delta
-                self.dispatch_stats.hbm_roundtrips += rt_delta
+                stats = self.dispatch_stats
+                stats.dispatches += delta
+                stats.hbm_roundtrips += rt_delta
                 if donated:
-                    self.dispatch_stats.donated_buffers += 1
+                    stats.donated_buffers += 1
+                streams, queued = self._encrypt_streams, self._encrypt_windows_out
+                if not decrypt:
+                    stats.encrypt_stream_launch_sum += streams
+                stats.launch_queue_sum += queued
                 note_mutation("tpu.TpuTransformBackend.dispatch_stats")
             if copy_back:
                 out.copy_to_host_async()
             if span is not None:
                 span.attributes["traced"] = thread_program_traces() > traces
+                span.attributes["streams"] = streams
+                span.attributes["queued"] = queued
                 self._watch_window(out, span, varlen, decrypt)
         return out
 
@@ -828,6 +963,8 @@ class TpuTransformBackend(TransformBackend):
         staged = self._stage_packed(packed, varlen)
         out = self._launch_packed(ctx, staged, varlen, decrypt=False)
         self._note_window(sum(sizes), len(sizes), n_bytes, varlen)
+        with self._stats_lock:
+            self._encrypt_windows_out += 1
         return ivs, sizes, n_bytes, out, [packed]
 
     @_spanned("transform.encrypt_finish", count=lambda staged: len(staged[1]),
@@ -839,8 +976,12 @@ class TpuTransformBackend(TransformBackend):
         payload per chunk. The window is over then, and its host staging
         buffer goes back to the ring."""
         ivs, sizes, n_bytes, out, staging = staged
-        with self.tracer.span("transform.d2h_wait") as wait:
-            host = np.asarray(out)
+        try:
+            with self.tracer.span("transform.d2h_wait") as wait:
+                host = np.asarray(out)
+        except BaseException:
+            self._drop_window(staging)
+            raise
         if wait is not None:
             self._split_wait(wait, out)
         with self._stats_lock:
@@ -855,6 +996,8 @@ class TpuTransformBackend(TransformBackend):
             for i in range(len(sizes))
         ]
         if staging:  # a window finished twice returns its buffer once
+            with self._stats_lock:
+                self._encrypt_windows_out -= 1
             self._release_staging(staging.pop())
         return wire
 
